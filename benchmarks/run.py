@@ -87,7 +87,9 @@ def _sections(smoke: bool):
 def main(smoke: bool = False, json_path: str | None = None,
          suite: str = "all") -> None:
     from benchmarks import common
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     common.reset_metrics()
     failures = 0
     ran = 0

@@ -19,6 +19,12 @@ import jax.numpy as jnp
 from .float_bits import MNT_BITS, jnp_bits, jnp_float, np_bits, np_float
 
 
+def mantissa_top(u, M: int, xp):
+    """Top-M mantissa bits of uint32 words: a LUT row (A) or column (B)
+    index (paper line 8)."""
+    return (u & xp.uint32(0x007F_FFFF)) >> xp.uint32(MNT_BITS - M)
+
+
 def _amsim(ua, ub, lut, M: int, xp, packed: bool = False):
     """Shared Alg. 2 body over uint32 words; xp is numpy or jnp.
 
@@ -27,18 +33,24 @@ def _amsim(ua, ub, lut, M: int, xp, packed: bool = False):
     The unpack is two shifts after the gather, so the gather itself moves
     half the bytes (the VMEM-footprint win for the Pallas kernels).
     """
-    mnt_mask = xp.uint32(0x007F_FFFF)
-    amnt = ua & mnt_mask
-    bmnt = ub & mnt_mask
     # Index = concat(top-M bits of A mantissa, top-M bits of B mantissa)
     # (paper line 8; written shift-then-or so it also works for M=12).
-    idx = ((amnt >> xp.uint32(MNT_BITS - M)) << xp.uint32(M)) | (
-        bmnt >> xp.uint32(MNT_BITS - M)
-    )
+    idx = (mantissa_top(ua, M, xp) << xp.uint32(M)) | mantissa_top(ub, M, xp)
     if xp is np:
         entry = lut[idx]
     else:
         entry = jnp.take(lut, idx.astype(jnp.int32), indices_are_sorted=False)
+    return amsim_from_entry(ua, ub, entry, M, xp, packed)
+
+
+def amsim_from_entry(ua, ub, entry, M: int, xp, packed: bool = False):
+    """Alg. 2 lines 9-19: the product word from the fetched LUT entry.
+
+    Split from the fetch so the Pallas brick can look the entry up in
+    whatever form the backend lowers (kernels/common.py) and still share
+    every bit of the sign/exponent/flush arithmetic with the oracles.
+    """
+    mnt_mask = xp.uint32(0x007F_FFFF)
     if packed:
         entry = entry.astype(xp.uint32)
         entry = ((entry >> xp.uint32(M)) << xp.uint32(MNT_BITS)) | (

@@ -14,7 +14,7 @@ directly).  Size: 2^(2M) * 4 bytes — 64 KiB for M=7, 16 MiB for M=11.
 The generator is fully vectorised (one batched call into the model) and
 results are cached on disk + in process, mirroring the paper's
 "generate once, load at run-time" flow.  The disk cache directory is
-``REPRO_LUT_DIR`` (default ``/tmp/repro_luts``; all REPRO_* knobs:
+``REPRO_LUT_DIR`` (default ``<repo>/.cache/luts``; all REPRO_* knobs:
 docs/configuration.md).
 """
 from __future__ import annotations
@@ -23,6 +23,8 @@ import os
 from pathlib import Path
 
 import numpy as np
+
+from repro.checkout import CACHE
 
 from .float_bits import MNT_BITS, MNT_MASK, np_bits, np_float, np_pack
 from .multipliers import Multiplier, get_multiplier
@@ -134,7 +136,7 @@ def get_packed_lut(name_or_mult, M: int | None = None,
 
 
 def lut_path(name: str, M: int, root: str | os.PathLike | None = None) -> Path:
-    root = Path(root or os.environ.get("REPRO_LUT_DIR", "/tmp/repro_luts"))
+    root = Path(root or os.environ.get("REPRO_LUT_DIR") or CACHE / "luts")
     return root / f"{name}_m{M}.lut.npy"
 
 

@@ -1,8 +1,9 @@
 """Sharded fused-LUT execution: shard_map dispatch for the Pallas kernels.
 
-GSPMD cannot partition a ``pallas_call``: under a mesh it all-gathers the
-operands and replays the full kernel on every device (correct, but the
-mesh buys nothing).  This module makes ``mode="amsim"`` genuinely
+GSPMD cannot partition a ``pallas_call`` (on the chip Mosaic refuses),
+so outside this module a kernel under a mesh runs replicated: operands
+all-gathered, the full kernel on every device (``ops._replicated``;
+correct, but the mesh buys nothing).  This module makes ``mode="amsim"`` genuinely
 parallel by wrapping the three fused kernel families in explicit
 ``shard_map`` dispatch driven by the Megatron/FSDP rules of
 ``distributed/sharding.py``:
@@ -65,8 +66,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AbstractMesh, Mesh, PartitionSpec as P
 
 from repro.core.policy import Numerics, NumericsPolicy
 from repro.kernels.ops import (_conv_bwd, _conv_fwd_impl, _matmul_nograd,
@@ -80,17 +81,15 @@ def env_enabled() -> bool:
     return os.environ.get("REPRO_SHARD_FUSED", "1").lower() not in ("0", "false")
 
 
-def current_mesh() -> Mesh | None:
-    """The ambient ``with mesh:`` context's mesh, or None.
+def current_mesh() -> AbstractMesh | None:
+    """The ambient ``jax.set_mesh`` context's (abstract) mesh, or None.
 
     Read at trace time: launch/train.py, launch/cells.py (via dryrun)
     and serve/engine.py all trace their step functions inside the mesh
     context, which is what routes their model code through this module.
     """
-    from jax._src import mesh as mesh_lib  # no public accessor in 0.4.x
-
-    m = mesh_lib.thread_resources.env.physical_mesh
-    if m is None or m.empty or m.size <= 1:
+    m = jax.sharding.get_abstract_mesh()
+    if m.empty or m.size <= 1:
         return None
     return m
 
@@ -159,7 +158,7 @@ def _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry):
         return jax.lax.psum(dws, daxes) if bentry is not None else dws
 
     return shard_map(dw_body, mesh=mesh, in_specs=(sx, so), out_specs=sw,
-                     check_rep=False)(x, g)
+                     check_vma=False)(x, g)
 
 
 # ================================================================= matmul
@@ -190,7 +189,7 @@ def column_parallel_matmul(x, w, policy: Numerics, mesh: Mesh,
     Forward is collective-free: each shard's LUT kernel computes its
     column block bit-identically to the single-device kernel (k is never
     split).  The custom VJP places the Megatron collectives explicitly —
-    autodiff through a ``check_rep=False`` shard_map would silently drop
+    autodiff through a ``check_vma=False`` shard_map would silently drop
     the psum over unmentioned mesh axes (dw's data-axis reduction).
     ``site`` resolves the per-pass leaves (fwd here, dx/dw in the VJP);
     the collectives themselves are pass-independent.
@@ -210,7 +209,7 @@ def _col_fwd(x, w, policy, mesh, site=None):
     sx, sw, so = _col_specs(mesh, x.ndim, bentry)
     out = shard_map(lambda xs, ws: _matmul_nograd(xs, ws, leaf),
                     mesh=mesh, in_specs=(sx, sw), out_specs=so,
-                    check_rep=False)(x, w)
+                    check_vma=False)(x, w)
     return out, (x, w)
 
 
@@ -227,7 +226,7 @@ def _col_bwd(policy, mesh, site, res, g):
         return jax.lax.psum(_matmul_nograd(gs, _swap(ws), leaf_dx), "model")
 
     dx = shard_map(dx_body, mesh=mesh, in_specs=(so, sw), out_specs=sx,
-                   check_rep=False)(g, w)
+                   check_vma=False)(g, w)
     dw = _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry)
     return dx.reshape(x.shape), dw.reshape(w.shape)
 
@@ -348,7 +347,7 @@ def _row_fwd(x, w, policy, mesh, site=None):
         return jnp.concatenate(outs, axis=-1)
 
     out = shard_map(body, mesh=mesh, in_specs=(sx, sw), out_specs=so,
-                    check_rep=False)(x, w)
+                    check_vma=False)(x, w)
     return out, (x, w)
 
 
@@ -365,7 +364,7 @@ def _row_bwd(policy, mesh, site, res, g):
         return _matmul_nograd(gs, _swap(ws), leaf_dx)
 
     dx = shard_map(dx_body, mesh=mesh, in_specs=(so, sw), out_specs=sx,
-                   check_rep=False)(g, w)
+                   check_vma=False)(g, w)
     dw = _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry)
     return dx.reshape(x.shape), dw.reshape(w.shape)
 
@@ -433,7 +432,7 @@ def sharded_attention(q, k, v, q_pos, k_pos, policy: Numerics, *,
 
     return shard_map(body, mesh=mesh,
                      in_specs=(sq, sq, sq, P(None), P(None)),
-                     out_specs=sq, check_rep=False)(q, k, v, q_pos, k_pos)
+                     out_specs=sq, check_vma=False)(q, k, v, q_pos, k_pos)
 
 
 # ================================================================= conv2d
@@ -466,7 +465,7 @@ def _sconv_fwd(x, w, stride, padding, policy, mesh):
     out = shard_map(lambda xs, ws: _conv_fwd_impl(xs, ws, stride, padding,
                                                   policy),
                     mesh=mesh, in_specs=(sx, sw), out_specs=sx,
-                    check_rep=False)(x, w)
+                    check_vma=False)(x, w)
     return out, (x, w)
 
 
@@ -483,7 +482,7 @@ def _sconv_bwd(stride, padding, policy, mesh, res, g):
         return dxs, dws
 
     return shard_map(body, mesh=mesh, in_specs=(sx, sw, sx),
-                     out_specs=(sx, sw), check_rep=False)(x, w, g)
+                     out_specs=(sx, sw), check_vma=False)(x, w, g)
 
 
 sharded_conv2d.defvjp(_sconv_fwd, _sconv_bwd)

@@ -67,7 +67,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import autotune
 from repro.kernels.common import (_ceil_to, _CompilerParams,
                                   _gather_gemm_tile, _pad_to,
-                                  attention_mask, best_chunk)
+                                  attention_mask, best_chunk, kernel_lut,
+                                  lut_spec, resolve_interpret)
 
 NEG_INF = -1e30          # matches models/attention.py's mask fill
 POS_PAD = -(2 ** 30)     # padding sentinel: same "unwritten" marker as
@@ -118,57 +119,55 @@ def attention_fused_supported(q_shape, k_shape, *, causal: bool = True,
     return resident <= MAX_ATTN_BYTES
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, live_ref, lut_ref, o_ref,
+def _attn_kernel(q_ref, kt_ref, v_ref, mask_ref, live_ref, lut_ref, o_ref,
                  s_scr, *, M: int, bkv: int, chunk_d: int, chunk_t: int,
-                 packed: bool):
+                 kv_heads: int, per_row: bool):
     """One (batch*kv-head, q-block) output tile.
 
-    Grid cell layout: q block (bq, G, dh) flattens to (bq*G, dh) gather
-    rows (q-position major, group-head minor — the einsum oracle's score
-    row order); the whole padded K/V of this batch*kv-head is VMEM
-    resident and streamed in bkv-sized blocks by both LUT passes.
+    Grid cell layout: the q block arrives as (bq*G, dh) gather rows
+    (q-position major, group-head minor — the einsum oracle's score row
+    order); K arrives transposed, (dh, Tp), so a KV block of the score
+    GEMM is a lane slice; the whole padded K/V of this batch*kv-head is
+    VMEM resident and streamed in bkv-sized blocks by both LUT passes.
 
-    The (bq, Tp) mask and the per-KV-block liveness flags arrive
-    precomputed (vectorised once per call by the wrapper — they are
-    identical for every batch*kv-head grid row).  Both LUT passes are
-    static fori_loops whose body is guarded by ``lax.cond`` on the
-    block's flag, so a fully-masked KV block costs a flag test instead
-    of a gather sweep — this is what makes sliding-window decode cost
-    scale with ``window`` instead of the ring-buffer capacity.  (A
-    dynamic-trip-count while_loop over just the live blocks measured
-    strictly worse under interpret-mode state discharge; static bounds
-    keep the loop on the fast scan path.)
+    The (bq*G, Tp) mask (int32, already expanded to gather rows) and the
+    per-KV-block liveness flags (SMEM, the whole (Bm, nq, nkv) array)
+    arrive precomputed — vectorised once per call by the wrapper.  Both
+    LUT passes are static fori_loops whose body is guarded by
+    ``lax.cond`` on the block's flag, so a fully-masked KV block costs a
+    flag test instead of a gather sweep — this is what makes
+    sliding-window decode cost scale with ``window`` instead of the
+    ring-buffer capacity.  (A dynamic-trip-count while_loop over just
+    the live blocks measured strictly worse under interpret-mode state
+    discharge; static bounds keep the loop on the fast scan path.)
     """
-    bq, G, dh = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    Tp = k_ref.shape[1]
-    rows = bq * G
+    rows, dh = q_ref.shape[1], q_ref.shape[2]
+    Tp = kt_ref.shape[2]
     nkv = Tp // bkv
-    q = q_ref[0].reshape(rows, dh)
-    k = k_ref[0]
-    v = v_ref[0]
-    mask = mask_ref[0]
-    live = live_ref[0, 0]
+    mrow = pl.program_id(0) // kv_heads if per_row else 0
+    iq = pl.program_id(1)
+    q = q_ref[0]
     lut = lut_ref[...]
+
+    def block(j):
+        # A single KV block starts at the static offset 0; several start
+        # at lane offsets the chip needs proven 128-aligned (bkv % 128).
+        return pl.ds(pl.multiple_of(j * bkv, bkv) if nkv > 1 else 0, bkv)
 
     # ---- pass 1: masked score tiles -> VMEM scratch (NEG_INF elsewhere)
     def score_step(j, carry):
-        col = j * bkv
-
         def live_tile():
-            kb = jax.lax.dynamic_slice(k, (col, 0), (bkv, dh))
             s = _gather_gemm_tile(
-                q, kb.T, lut, jnp.zeros((rows, bkv), jnp.float32),
-                M=M, chunk=chunk_d, packed=packed)
+                q, kt_ref[0, :, block(j)], lut,
+                jnp.zeros((rows, bkv), jnp.float32), M=M, chunk=chunk_d)
             s = s / jnp.sqrt(float(dh))
-            mb = jax.lax.dynamic_slice(mask, (0, col), (bq, bkv))
-            rmask = jnp.broadcast_to(mb[:, None, :], (bq, G, bkv))
-            return jnp.where(rmask.reshape(rows, bkv), s, NEG_INF)
+            return jnp.where(mask_ref[0, :, block(j)] != 0, s, NEG_INF)
 
         def dead_tile():
             return jnp.full((rows, bkv), NEG_INF, jnp.float32)
 
-        s_scr[:, pl.ds(col, bkv)] = jax.lax.cond(live[j], live_tile,
-                                                 dead_tile)
+        s_scr[:, block(j)] = jax.lax.cond(live_ref[mrow, iq, j] != 0,
+                                          live_tile, dead_tile)
         return carry
 
     jax.lax.fori_loop(0, nkv, score_step, 0)
@@ -183,30 +182,28 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, live_ref, lut_ref, o_ref,
     # ring-buffer capacity, which evicts the earliest queries' own keys
     # — those rows are context-less garbage under every lowering (see
     # the cache-write comment in models/attention.py).  Padding rows
-    # that hit this are cropped by the wrapper.
+    # that hit this are cropped by the wrapper.  The probs overwrite the
+    # scores in the scratch, where the value pass slices them.
     s = s_scr[...]
     m = jnp.max(s, axis=-1, keepdims=True)
     unnorm = jnp.exp(s - m)
-    probs = unnorm / jnp.sum(unnorm, axis=-1, keepdims=True)
+    s_scr[...] = unnorm / jnp.sum(unnorm, axis=-1, keepdims=True)
 
     # ---- pass 2: probs @ V through the LUT over the same live blocks.
     # For any row with at least one valid key, a dead block's probs are
     # exactly 0 and AMSim flushes zero operands to zero, so skipping it
     # contributes nothing — up to the sign of a zero sum.
     def value_step(j, acc):
-        col = j * bkv
-
         def live_acc(acc):
-            p = jax.lax.dynamic_slice(probs, (0, col), (rows, bkv))
-            vb = jax.lax.dynamic_slice(v, (col, 0), (bkv, dh))
-            return _gather_gemm_tile(p, vb, lut, acc, M=M, chunk=chunk_t,
-                                     packed=packed)
+            return _gather_gemm_tile(s_scr[:, block(j)],
+                                     v_ref[0, block(j), :], lut, acc,
+                                     M=M, chunk=chunk_t)
 
-        return jax.lax.cond(live[j], live_acc, lambda a: a, acc)
+        return jax.lax.cond(live_ref[mrow, iq, j] != 0, live_acc,
+                            lambda a: a, acc)
 
-    acc = jax.lax.fori_loop(0, nkv, value_step,
-                            jnp.zeros((rows, dh), jnp.float32))
-    o_ref[0] = acc.reshape(bq, G, dh)
+    o_ref[0] = jax.lax.fori_loop(0, nkv, value_step,
+                                 jnp.zeros((rows, dh), jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -252,8 +249,10 @@ def _attn_impl(q, k, v, q_pos, k_pos, lut, M, *, causal, window, bq, bkv,
         T = T_budget
     Sp = _ceil_to(S, bq)
     Tp = _ceil_to(T, bkv)
-    qg = _pad_to(qg, bq, 1, 1)
-    kt = _pad_to(kt, bkv, 1)
+    # Kernel layouts: q as (BH, Sp*G, dh) gather rows, K transposed to
+    # (BH, dh, Tp) so its KV blocks are lane slices.
+    qg = _pad_to(qg, bq, 1, 1).reshape(BH, Sp * G, dh)
+    kt = _pad_to(kt, bkv, 1).transpose(0, 2, 1)
     vt = _pad_to(vt, bkv, 1)
     # Padded positions take the "unwritten" sentinel so padded K slots
     # are masked and padded q rows never force a KV block live.
@@ -283,33 +282,33 @@ def _attn_impl(q, k, v, q_pos, k_pos, lut, M, *, causal, window, bq, bkv,
         blk_live = jnp.any(mask[0].reshape(nq, bq, nkv, bkv),
                            axis=(1, 3))[None]             # (1, nq, nkv)
         mrow = lambda bh: 0                               # noqa: E731
-    packed = lut.dtype == jnp.uint16
-    grid = (BH, nq)
+    # Expanded to the kernel's gather rows (each q row G times), as int32.
+    mask = jnp.repeat(mask, G, axis=1).astype(jnp.int32)  # (Bm, Sp*G, Tp)
+    rows = bq * G
     out = pl.pallas_call(
         functools.partial(_attn_kernel, M=M, bkv=bkv, chunk_d=chunk_d,
-                          chunk_t=chunk_t, packed=packed),
-        grid=grid,
+                          chunk_t=chunk_t, kv_heads=KV, per_row=per_row),
+        grid=(BH, nq),
         in_specs=[
-            pl.BlockSpec((1, bq, G, dh), lambda bh, iq: (bh, iq, 0, 0)),
+            pl.BlockSpec((1, rows, dh), lambda bh, iq: (bh, iq, 0)),
             # K/V block index is constant along the q-block axis, so the
             # staged copies are reused across every q block of one
             # batch*kv-head; the LUT is broadcast across the whole grid.
+            pl.BlockSpec((1, dh, Tp), lambda bh, iq: (bh, 0, 0)),
             pl.BlockSpec((1, Tp, dh), lambda bh, iq: (bh, 0, 0)),
-            pl.BlockSpec((1, Tp, dh), lambda bh, iq: (bh, 0, 0)),
-            pl.BlockSpec((1, bq, Tp), lambda bh, iq: (mrow(bh), iq, 0)),
-            pl.BlockSpec((1, 1, nkv), lambda bh, iq: (mrow(bh), iq, 0)),
-            pl.BlockSpec((lut.shape[0],), lambda bh, iq: (0,)),
+            pl.BlockSpec((1, rows, Tp), lambda bh, iq: (mrow(bh), iq, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            lut_spec(lut),
         ],
-        out_specs=pl.BlockSpec((1, bq, G, dh),
-                               lambda bh, iq: (bh, iq, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Sp, G, dh), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq * G, Tp), jnp.float32)],
+        out_specs=pl.BlockSpec((1, rows, dh), lambda bh, iq: (bh, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((BH, Sp * G, dh), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((rows, Tp), jnp.float32)],
         compiler_params=_CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
-    )(qg, kt, vt, mask, blk_live, lut)
-    return (out[:, :S].reshape(B, KV, S, G, dh)
+    )(qg, kt, vt, mask, blk_live.astype(jnp.int32), lut)
+    return (out.reshape(BH, Sp, G, dh)[:, :S].reshape(B, KV, S, G, dh)
             .transpose(0, 2, 1, 3, 4).reshape(B, S, H, dh))
 
 
@@ -365,10 +364,8 @@ def approx_attention_fused(
         and k_pos.shape == q_pos.shape[:-1] + (T,), \
         (q_pos.shape, k_pos.shape, q.shape, k.shape)
     _TRACES[0] += 1
-    lut = jnp.asarray(lut)
-    lut = lut if lut.dtype == jnp.uint16 else lut.astype(jnp.uint32)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
+    lut = kernel_lut(lut, M, interpret)
     if None in (bq, bkv, chunk):
         cfg = autotune.get_attn_config(B * KV, S, T, H // KV, dh, M,
                                        mult=mult)

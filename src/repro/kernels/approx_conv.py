@@ -47,7 +47,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune
 from repro.kernels.common import (_ceil_to, _CompilerParams,
-                                  _gather_gemm_tile, best_chunk)
+                                  _gather_gemm_tile, best_chunk, kernel_lut,
+                                  lut_spec, resolve_interpret)
 
 # Static-unroll / VMEM guards for the fused path (see fused_supported).
 MAX_TAPS = 64                      # kh*kw positions unrolled in-kernel
@@ -102,9 +103,15 @@ def fused_supported(x_shape, w_shape, stride: int = 1) -> bool:
 
 
 # ------------------------------------------------------------------ forward
+def _window(x_ref, r0, c0, rows: int, cols: int, stride: int):
+    """The strided (rows, cols, C) input window of one kernel position,
+    read straight from the VMEM-resident padded image ref."""
+    return x_ref[0, pl.ds(r0, rows, stride=stride),
+                 pl.ds(c0, cols, stride=stride), :]
+
+
 def _amconv_kernel(x_ref, w_ref, lut_ref, o_ref, *,
-                   M: int, stride: int, kh: int, kw: int,
-                   chunk: int, packed: bool):
+                   M: int, stride: int, kh: int, kw: int, chunk: int):
     """One (batch, row-block, out-channel-block) output tile.
 
     The full contraction (kh*kw taps x C channels) runs inside a single
@@ -112,23 +119,17 @@ def _amconv_kernel(x_ref, w_ref, lut_ref, o_ref, *,
     strided input window from the VMEM-resident padded image and feeding
     the (br*ow, C) x (C, bo) gather-GEMM brick.
     """
-    img = x_ref[0]                     # (HP, WP, C) padded image
     lut = lut_ref[...]
     br, ow, bo = o_ref.shape[1], o_ref.shape[2], o_ref.shape[3]
-    c = img.shape[-1]
-    span_r = (br - 1) * stride + 1
-    span_c = (ow - 1) * stride + 1
+    c = x_ref.shape[-1]
     r0 = pl.program_id(1) * (br * stride)
     acc = jnp.zeros((br * ow, bo), jnp.float32)
     for ki in range(kh):
         for kj in range(kw):
-            patch = jax.lax.dynamic_slice(
-                img, (r0 + ki, kj, 0), (span_r, span_c, c))
-            if stride > 1:
-                patch = patch[::stride, ::stride, :]
+            patch = _window(x_ref, r0 + ki, kj, br, ow, stride)
             acc = _gather_gemm_tile(
                 patch.reshape(br * ow, c), w_ref[ki, kj], lut, acc,
-                M=M, chunk=chunk, packed=packed)
+                M=M, chunk=chunk)
     o_ref[0] = acc.reshape(br, ow, bo)
 
 
@@ -152,16 +153,15 @@ def _fused_impl(x, w, lut, M, *, stride, pads, br, bo, chunk, interpret):
     xpad = xpad[:, :hp, :wp, :]
     wpad = jnp.pad(w.astype(jnp.float32),
                    ((0, 0), (0, 0), (0, 0), (0, op - o)))
-    packed = lut.dtype == jnp.uint16
     grid = (n, ohp // br, op // bo)
     out = pl.pallas_call(
         functools.partial(_amconv_kernel, M=M, stride=stride, kh=kh, kw=kw,
-                          chunk=chunk, packed=packed),
+                          chunk=chunk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, hp, wp, c), lambda nn, rr, oo: (nn, 0, 0, 0)),
             pl.BlockSpec((kh, kw, c, bo), lambda nn, rr, oo: (0, 0, 0, oo)),
-            pl.BlockSpec((lut.shape[0],), lambda nn, rr, oo: (0,)),
+            lut_spec(lut),
         ],
         out_specs=pl.BlockSpec((1, br, ow, bo),
                                lambda nn, rr, oo: (nn, rr, 0, oo)),
@@ -199,12 +199,10 @@ def approx_conv2d_fused(
     n, h, wid, c = x.shape
     kh, kw, cw, o = w.shape
     assert c == cw, (x.shape, w.shape)
-    lut = jnp.asarray(lut)
-    lut = lut if lut.dtype == jnp.uint16 else lut.astype(jnp.uint32)
     pads = conv_pads(h, wid, kh, kw, stride, padding)
     oh, _ = conv_out_shape(h, wid, kh, kw, stride, pads)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
+    lut = kernel_lut(lut, M, interpret)
     if None in (br, bo, chunk):
         cfg = autotune.get_conv_config(n, h, wid, c, kh, kw, o, stride,
                                        padding, M, mult=mult)
@@ -223,32 +221,24 @@ def approx_conv2d_fused(
 
 # ----------------------------------------------------------- weight gradient
 def _amconv_dw_kernel(x_ref, g_ref, lut_ref, o_ref, acc_ref, *,
-                      M: int, stride: int, kw: int, chunk: int,
-                      packed: bool):
+                      M: int, stride: int, kw: int, chunk: int):
     """One kernel-position (ki, kj) slice of dw, accumulated over the
     batch (grid axis 1, "arbitrary"): dw[ki,kj] += patch^T @ g."""
-    img = x_ref[0]                     # (HP, WP, C) padded image
     g = g_ref[0]                       # (OH, OW, O) upstream error
     lut = lut_ref[...]
-    c = img.shape[-1]
+    c = x_ref.shape[-1]
     oh, ow, o = g.shape
     kp = pl.program_id(0)
-    ki = kp // kw
-    kj = kp % kw
-    span_r = (oh - 1) * stride + 1
-    span_c = (ow - 1) * stride + 1
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    patch = jax.lax.dynamic_slice(img, (ki, kj, 0), (span_r, span_c, c))
-    if stride > 1:
-        patch = patch[::stride, ::stride, :]
+    patch = _window(x_ref, kp // kw, kp % kw, oh, ow, stride)
     cols_t = jnp.transpose(patch.reshape(oh * ow, c))    # (C, P)
     acc_ref[...] = _gather_gemm_tile(
         cols_t, g.reshape(oh * ow, o), lut, acc_ref[...],
-        M=M, chunk=chunk, packed=packed)
+        M=M, chunk=chunk)
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _flush():
@@ -268,16 +258,15 @@ def _dw_impl(x, g, lut, M, *, stride, pads, kh, kw, chunk, interpret):
                     (pl_, max(0, wp - wid - pl_)), (0, 0)))
     xpad = xpad[:, :hp, :wp, :]
     g = g.astype(jnp.float32)
-    packed = lut.dtype == jnp.uint16
     grid = (kh * kw, n)
     out = pl.pallas_call(
         functools.partial(_amconv_dw_kernel, M=M, stride=stride, kw=kw,
-                          chunk=chunk, packed=packed),
+                          chunk=chunk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, hp, wp, c), lambda kp, nn: (nn, 0, 0, 0)),
             pl.BlockSpec((1, oh, ow, o), lambda kp, nn: (nn, 0, 0, 0)),
-            pl.BlockSpec((lut.shape[0],), lambda kp, nn: (0,)),
+            lut_spec(lut),
         ],
         out_specs=pl.BlockSpec((1, c, o), lambda kp, nn: (kp, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((kh * kw, c, o), jnp.float32),
@@ -313,11 +302,9 @@ def approx_conv2d_dw(
     """
     n, h, wid, c = x.shape
     assert g.shape[0] == n, (x.shape, g.shape)
-    lut = jnp.asarray(lut)
-    lut = lut if lut.dtype == jnp.uint16 else lut.astype(jnp.uint32)
     pads = conv_pads(h, wid, kh, kw, stride, padding)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
+    lut = kernel_lut(lut, M, interpret)
     if chunk is None:
         o = g.shape[-1]
         cfg = autotune.get_conv_config(n, h, wid, c, kh, kw, o, stride,

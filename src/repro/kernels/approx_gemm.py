@@ -51,18 +51,19 @@ from repro.kernels import autotune
 # Shared bricks live in kernels/common.py (consumed by all three kernel
 # families); re-exported here for backward compatibility.
 from repro.kernels.common import (_ceil128, _CompilerParams,  # noqa: F401
-                                  _gather_gemm_tile, _pad_to, best_chunk)
+                                  _gather_gemm_tile, _pad_to, best_chunk,
+                                  kernel_lut, lut_spec, resolve_interpret)
 
 
 def _amsim_kernel(a_ref, b_ref, lut_ref, o_ref, acc_ref, *,
-                  M: int, chunk: int, packed: bool):
+                  M: int, chunk: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] = _gather_gemm_tile(
         a_ref[...], b_ref[...], lut_ref[...], acc_ref[...],
-        M=M, chunk=chunk, packed=packed)
+        M=M, chunk=chunk)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _flush():
@@ -70,7 +71,7 @@ def _amsim_kernel(a_ref, b_ref, lut_ref, o_ref, acc_ref, *,
 
 
 def _amsim_kernel_batched(a_ref, b_ref, lut_ref, o_ref, acc_ref, *,
-                          M: int, chunk: int, packed: bool):
+                          M: int, chunk: int):
     # Block shapes carry a leading singleton batch axis; k is grid dim 3.
     @pl.when(pl.program_id(3) == 0)
     def _init():
@@ -78,7 +79,7 @@ def _amsim_kernel_batched(a_ref, b_ref, lut_ref, o_ref, acc_ref, *,
 
     acc_ref[...] = _gather_gemm_tile(
         a_ref[0], b_ref[0], lut_ref[...], acc_ref[...],
-        M=M, chunk=chunk, packed=packed)
+        M=M, chunk=chunk)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _flush():
@@ -97,8 +98,7 @@ def _resolve(kind, m, k, n, M, batch, bm, bn, bk, chunk, interpret,
     otherwise, and a cached chunk must never silently degrade toward
     chunk=1 when bk has no smaller divisor nearby).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if None in (bm, bn, bk, chunk):
         cfg = autotune.get_block_config(kind, m, k, n, M, batch=batch,
                                         mult=mult)
@@ -119,15 +119,14 @@ def _approx_gemm_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret):
     b = _pad_to(b.astype(jnp.float32), bk, bn)
     mp, kp = a.shape
     np_ = b.shape[1]
-    packed = lut.dtype == jnp.uint16
     grid = (mp // bm, np_ // bn, kp // bk)
     out = pl.pallas_call(
-        functools.partial(_amsim_kernel, M=M, chunk=chunk, packed=packed),
+        functools.partial(_amsim_kernel, M=M, chunk=chunk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((lut.shape[0],), lambda i, j, kk: (0,)),
+            lut_spec(lut),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
@@ -166,10 +165,9 @@ def approx_gemm(
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
-    lut = jnp.asarray(lut)
-    lut = lut if lut.dtype == jnp.uint16 else lut.astype(jnp.uint32)
     bm, bn, bk, chunk, interpret = _resolve(
         "gemm2d", m, k, n, M, 0, bm, bn, bk, chunk, interpret, mult)
+    lut = kernel_lut(lut, M, interpret)
     return _approx_gemm_impl(a, b, lut, M, bm=bm, bn=bn, bk=bk,
                              chunk=chunk, interpret=interpret)
 
@@ -184,18 +182,16 @@ def _approx_gemm_batched_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret):
     b = _pad_to(b.astype(jnp.float32), bk, bn)
     mp, kp = a.shape[1:]
     np_ = b.shape[2]
-    packed = lut.dtype == jnp.uint16
     grid = (B, mp // bm, np_ // bn, kp // bk)
     out = pl.pallas_call(
-        functools.partial(_amsim_kernel_batched, M=M, chunk=chunk,
-                          packed=packed),
+        functools.partial(_amsim_kernel_batched, M=M, chunk=chunk),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda bb, i, j, kk: (bb, i, kk)),
             pl.BlockSpec((1, bk, bn), lambda bb, i, j, kk: (bb, kk, j)),
             # LUT block index is constant: one VMEM-resident table is
             # broadcast across the whole batch grid axis.
-            pl.BlockSpec((lut.shape[0],), lambda bb, i, j, kk: (0,)),
+            lut_spec(lut),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, mp, np_), jnp.float32),
@@ -233,9 +229,8 @@ def approx_gemm_batched(
     B, m, k = a.shape
     B2, k2, n = b.shape
     assert B == B2 and k == k2, (a.shape, b.shape)
-    lut = jnp.asarray(lut)
-    lut = lut if lut.dtype == jnp.uint16 else lut.astype(jnp.uint32)
     bm, bn, bk, chunk, interpret = _resolve(
         "gemm3d", m, k, n, M, B, bm, bn, bk, chunk, interpret, mult)
+    lut = kernel_lut(lut, M, interpret)
     return _approx_gemm_batched_impl(a, b, lut, M, bm=bm, bn=bn, bk=bk,
                                      chunk=chunk, interpret=interpret)

@@ -29,7 +29,7 @@ itself only runs when :func:`autotune` / :func:`autotune_conv` /
 ``benchmarks/bench_attention.py --autotune``).
 
 Cache file schema (``REPRO_AUTOTUNE_CACHE``, default
-``/tmp/repro_autotune/gemm_blocks.json`` — every REPRO_* knob is
+``<repo>/.cache/autotune/gemm_blocks.json`` — every REPRO_* knob is
 catalogued in docs/configuration.md)::
 
     {
@@ -57,6 +57,8 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+
+from repro.checkout import CACHE
 
 SCHEMA_VERSION = 1
 
@@ -185,8 +187,8 @@ _MEM: dict[str, BlockConfig | ConvBlockConfig] | None = None  # file mirror
 
 # ------------------------------------------------------------------ cache IO
 def cache_path() -> Path:
-    return Path(os.environ.get(
-        "REPRO_AUTOTUNE_CACHE", "/tmp/repro_autotune/gemm_blocks.json"))
+    return Path(os.environ.get("REPRO_AUTOTUNE_CACHE")
+                or CACHE / "autotune" / "gemm_blocks.json")
 
 
 def _parse_entry(e) -> BlockConfig | ConvBlockConfig | AttnBlockConfig | None:
@@ -400,6 +402,30 @@ def _time_call(fn, *args, iters: int = 2) -> float:
     return ts[len(ts) // 2]
 
 
+def _sweep(candidates, run, iters: int):
+    """Time ``run(cfg)`` for every candidate; (winner, its time).
+
+    A candidate that fails to lower (e.g. VMEM overflow on TPU) is
+    skipped.  When every candidate fails, the first failure is raised: a
+    sweep that times nothing must not hand back a default as if it had
+    won.
+    """
+    best, best_t, first_err = None, float("inf"), None
+    for cfg in candidates:
+        try:
+            t = _time_call(lambda: run(cfg), iters=iters)
+        except Exception as e:  # noqa: BLE001 — re-raised below if alone
+            first_err = first_err or e
+            continue
+        if t < best_t:
+            best, best_t = cfg, t
+    if best is None:
+        if first_err is None:
+            raise ValueError("autotune: no candidate to time")
+        raise first_err
+    return best, best_t
+
+
 def autotune(kind: str, a, b, lut, M: int, *, candidates=None,
              interpret: bool | None = None, iters: int = 2,
              save: bool = True, mult: str | None = None) -> BlockConfig:
@@ -408,7 +434,7 @@ def autotune(kind: str, a, b, lut, M: int, *, candidates=None,
     ``a``/``b`` are representative operands: (m, k)/(k, n) for ``gemm2d``,
     (B, m, k)/(B, k, n) for ``gemm3d``.  Candidates that fail to lower
     (e.g. VMEM overflow on TPU) are skipped; if every candidate fails the
-    default config is returned untouched.
+    first failure is raised.
     """
     from repro.kernels.approx_gemm import approx_gemm, approx_gemm_batched
 
@@ -429,16 +455,7 @@ def autotune(kind: str, a, b, lut, M: int, *, candidates=None,
             a, b, lut, M, bm=cfg.bm, bn=cfg.bn, bk=cfg.bk, chunk=cfg.chunk,
             interpret=interpret)
 
-    best, best_t = None, float("inf")
-    for cfg in candidates:
-        try:
-            t = _time_call(lambda: run(cfg), iters=iters)
-        except Exception:
-            continue
-        if t < best_t:
-            best, best_t = cfg, t
-    if best is None:
-        return DEFAULT_BATCHED if batched else DEFAULT_2D
+    best, best_t = _sweep(candidates, run, iters)
     if save:
         _save_entry(cache_key(kind, m, k, n, M, B, mult=mult), best,
                     best_t * 1e6)
@@ -452,7 +469,7 @@ def autotune_conv(x, w, lut, M: int, *, stride: int = 1, padding="SAME",
     """Sweep fused-conv tilings (forward + weight-gradient timed
     together, since one cache entry serves both); cache + return the
     winner.  Candidates that fail to lower are skipped; if every
-    candidate fails DEFAULT_CONV is returned untouched.
+    candidate fails the first failure is raised.
     """
     from repro.kernels.approx_conv import (approx_conv2d_dw,
                                            approx_conv2d_fused)
@@ -470,16 +487,7 @@ def autotune_conv(x, w, lut, M: int, *, stride: int = 1, padding="SAME",
                                 padding=padding, chunk=cfg.dw_chunk,
                                 interpret=interpret)
 
-    best, best_t = None, float("inf")
-    for cfg in candidates:
-        try:
-            t = _time_call(lambda: run(cfg), iters=iters)
-        except Exception:
-            continue
-        if t < best_t:
-            best, best_t = cfg, t
-    if best is None:
-        return DEFAULT_CONV
+    best, best_t = _sweep(candidates, run, iters)
     if save:
         _save_entry(conv_cache_key(n, h, wid, c, kh, kw, o, stride,
                                    padding, M, mult=mult), best,
@@ -495,8 +503,8 @@ def autotune_attention(q, k, v, q_pos, k_pos, lut, M: int, *,
     """Sweep fused-attention tilings with the real kernel; cache + return
     the winner.  ``q`` is (B, S, H, dh), ``k``/``v`` (B, T, KV, dh) —
     representative operands for the bucket.  Candidates that fail to
-    lower are skipped; if every candidate fails DEFAULT_ATTN is returned
-    untouched.
+    lower are skipped; if every candidate fails the first failure is
+    raised.
     """
     from repro.kernels.approx_attention import approx_attention_fused
 
@@ -511,16 +519,7 @@ def autotune_attention(q, k, v, q_pos, k_pos, lut, M: int, *,
             q, k, v, q_pos, k_pos, lut, M, causal=causal, window=window,
             bq=cfg.bq, bkv=cfg.bkv, chunk=cfg.chunk, interpret=interpret)
 
-    best, best_t = None, float("inf")
-    for cfg in candidates:
-        try:
-            t = _time_call(lambda: run(cfg), iters=iters)
-        except Exception:
-            continue
-        if t < best_t:
-            best, best_t = cfg, t
-    if best is None:
-        return DEFAULT_ATTN
+    best, best_t = _sweep(candidates, run, iters)
     if save:
         _save_entry(attn_cache_key(B * KV, S, T, G, dh, M, mult=mult), best,
                     best_t * 1e6)
@@ -541,8 +540,8 @@ def autotune_decode_chain(x, attn, g1, g2, wq, wk, wv, wo, wg, wu, wd,
     Candidates whose streamed blocks overrun the VMEM budget model
     (kernels/vmem.py) are pruned before timing — the tuner never times
     a config the dispatch guard would refuse.  Candidates that fail to
-    lower are skipped; if every candidate fails DEFAULT_DECODE_CHAIN is
-    returned untouched.
+    lower are skipped; if every candidate fails the first failure is
+    raised.
     """
     from repro.kernels import vmem  # lazy: vmem imports this module
     from repro.kernels.decode_chain import fused_out_mlp, fused_qkv_norm
@@ -565,16 +564,7 @@ def autotune_decode_chain(x, attn, g1, g2, wq, wk, wv, wo, wg, wu, wd,
                             mult=mult)
         return q, kk, vv, out
 
-    best, best_t = None, float("inf")
-    for cfg in candidates:
-        try:
-            t = _time_call(lambda: run(cfg), iters=iters)
-        except Exception:
-            continue
-        if t < best_t:
-            best, best_t = cfg, t
-    if best is None:
-        return DEFAULT_DECODE_CHAIN
+    best, best_t = _sweep(candidates, run, iters)
     if save:
         _save_entry(decode_chain_cache_key(rows, d, k_attn, d_ff, M,
                                            mult=mult), best, best_t * 1e6)

@@ -54,7 +54,8 @@ from repro.kernels import autotune, vmem
 from repro.kernels.approx_attention import NEG_INF, POS_PAD
 from repro.kernels.common import (_ceil128, _ceil_to, _CompilerParams,
                                   _gather_gemm_tile, attention_mask,
-                                  best_chunk)
+                                  best_chunk, kernel_lut, lut_spec,
+                                  resolve_interpret, rmsnorm_expr)
 # The fold derivation and the VMEM budget live in kernels/vmem.py (the
 # budget model also prices the MoE and attention-fused launch variants);
 # re-exported here because this module defined them historically.
@@ -89,12 +90,6 @@ def _snap_cols(want: int, n: int) -> tuple[int, int]:
     return bn, _ceil_to(n, bn)
 
 
-def _rmsnorm_expr(x, g, eps: float):
-    # models/layers.rmsnorm verbatim (bit-for-bit, same backend).
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps)) * g
-
-
 # =====================================================================
 # Launch 1: rmsnorm(n1) -> q|k|v projections
 # =====================================================================
@@ -102,16 +97,17 @@ def _rmsnorm_expr(x, g, eps: float):
 def _qkv_kernel(x_ref, g_ref, wq_ref, wk_ref, wv_ref, lut_ref,
                 oq_ref, ok_ref, ov_ref, h_scr, *,
                 M: int, eps: float, chunk: int, nq: int, nk: int, nv: int,
-                dp: int, packed: bool):
+                dp: int):
     j = pl.program_id(0)
 
     @pl.when(j == 0)
     def _norm():
-        h = _rmsnorm_expr(x_ref[...], g_ref[...], eps)
+        h = rmsnorm_expr(x_ref[...], g_ref[...], eps)
         # Zero-pad to the oracle's padded contraction extent: the pad
         # chunks contribute the same exact +0.0 terms, in the same fold
         # positions, as the unfused engine's _pad_to.
-        h_scr[...] = jnp.pad(h, ((0, 0), (0, dp - h.shape[1])))
+        h_scr[...] = jnp.zeros_like(h_scr)
+        h_scr[:, :h.shape[1]] = h
 
     h = h_scr[...]
     rows = h.shape[0]
@@ -120,7 +116,7 @@ def _qkv_kernel(x_ref, g_ref, wq_ref, wk_ref, wv_ref, lut_ref,
         o_ref[...] = _gather_gemm_tile(
             h, w_ref[...], lut_ref[...],
             jnp.zeros((rows, w_ref.shape[1]), jnp.float32),
-            M=M, chunk=chunk, packed=packed)
+            M=M, chunk=chunk)
 
     @pl.when(j < nq)
     def _q():
@@ -141,13 +137,12 @@ def _fused_qkv_impl(x, g1, wq, wk, wv, lut, M, *, eps, bn, chunk, dp,
                     interpret):
     rows, d = x.shape
     nq, nk, nv = (w.shape[1] // bn for w in (wq, wk, wv))
-    packed = lut.dtype == jnp.uint16
     cq = lambda j: jnp.clip(j, 0, nq - 1)
     ck = lambda j: jnp.clip(j - nq, 0, nk - 1)
     cv = lambda j: jnp.clip(j - nq - nk, 0, nv - 1)
     outs = pl.pallas_call(
         functools.partial(_qkv_kernel, M=M, eps=eps, chunk=chunk,
-                          nq=nq, nk=nk, nv=nv, dp=dp, packed=packed),
+                          nq=nq, nk=nk, nv=nv, dp=dp),
         grid=(nq + nk + nv,),
         in_specs=[
             pl.BlockSpec((rows, d), lambda j: (0, 0)),
@@ -158,7 +153,7 @@ def _fused_qkv_impl(x, g1, wq, wk, wv, lut, M, *, eps, bn, chunk, dp,
             pl.BlockSpec((dp, bn), lambda j: (0, cq(j))),
             pl.BlockSpec((dp, bn), lambda j: (0, ck(j))),
             pl.BlockSpec((dp, bn), lambda j: (0, cv(j))),
-            pl.BlockSpec((lut.shape[0],), lambda j: (0,)),
+            lut_spec(lut),
         ],
         out_specs=[
             pl.BlockSpec((rows, bn), lambda j: (0, cq(j))),
@@ -186,8 +181,7 @@ def fused_qkv_norm(x, g1, wq, wk, wv, lut, M: int, *, eps: float,
     """
     rows, d = x.shape
     _TRACES[0] += 1
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if bn is None:
         bn = autotune.get_decode_chain_config(
             rows, d, wq.shape[1], 0, M, mult=mult).bn
@@ -201,8 +195,9 @@ def fused_qkv_norm(x, g1, wq, wk, wv, lut, M: int, *, eps: float,
                   ((0, dp - d), (0, _ceil_to(w.shape[1], bn) - w.shape[1])))
           for w in (wq, wk, wv)]
     q, k, v = _fused_qkv_impl(x, g1.astype(jnp.float32), *wp,
-                              jnp.asarray(lut), M, eps=float(eps), bn=bn,
-                              chunk=chunk, dp=dp, interpret=interpret)
+                              kernel_lut(lut, M, interpret), M,
+                              eps=float(eps), bn=bn, chunk=chunk, dp=dp,
+                              interpret=interpret)
     return q[:, :wq.shape[1]], k[:, :wk.shape[1]], v[:, :wv.shape[1]]
 
 
@@ -212,8 +207,7 @@ def fused_qkv_norm(x, g1, wq, wk, wv, lut, M: int, *, eps: float,
 
 def _out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
                     chunk_o: int, chunk_g: int, chunk_d: int,
-                    d: int, dp2: int, has_bo: bool, has_bd: bool,
-                    packed: bool):
+                    d: int, dp2: int, has_bo: bool, has_bd: bool):
     # Epilogue biases (wo / wd) are *statically* optional operands: a
     # bias-free call must not add an unconditional +0.0 (it would flip
     # the sign of exact -0.0 sums and break the bitwise contract), so
@@ -238,7 +232,7 @@ def _out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
     def _wo():
         y_scr[...] = _gather_gemm_tile(
             attn_ref[...], wo_ref[...], lut, y_scr[...],
-            M=M, chunk=chunk_o, packed=packed)
+            M=M, chunk=chunk_o)
 
     # -- phase boundary: residual + rmsnorm(n2), all in VMEM ------------
     @pl.when(t == n_wo - 1)
@@ -250,8 +244,9 @@ def _out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
             y = y + bo_ref[...]
         x1 = xres_ref[...] + y
         x1_scr[...] = x1
-        h = _rmsnorm_expr(x1, g_ref[...], eps)
-        h_scr[...] = jnp.pad(h, ((0, 0), (0, dp2 - d)))
+        h = rmsnorm_expr(x1, g_ref[...], eps)
+        h_scr[...] = jnp.zeros_like(h_scr)
+        h_scr[:, :d] = h
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # -- phase B: stream wg/wu/wd d_ff-blocks, accumulate the FFN -------
@@ -261,13 +256,13 @@ def _out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
         bf = wg_ref.shape[1]
         zero = jnp.zeros((rows, bf), jnp.float32)
         g = _gather_gemm_tile(h, wg_ref[...], lut, zero,
-                              M=M, chunk=chunk_g, packed=packed)
+                              M=M, chunk=chunk_g)
         u = _gather_gemm_tile(h, wu_ref[...], lut, zero,
-                              M=M, chunk=chunk_g, packed=packed)
+                              M=M, chunk=chunk_g)
         a = jax.nn.silu(g) * u
         acc_scr[...] = _gather_gemm_tile(
             a, wd_ref[...], lut, acc_scr[...],
-            M=M, chunk=chunk_d, packed=packed)
+            M=M, chunk=chunk_d)
 
     @pl.when(t == n_wo + n_ff - 1)
     def _flush():
@@ -287,7 +282,6 @@ def _fused_out_mlp_impl(xres, attn, g2, wo, wg, wu, wd, biases, lut, M, *,
     kp = attn.shape[1]
     n_wo = kp // bko
     n_ff = wg.shape[1] // bf
-    packed = lut.dtype == jnp.uint16
     co = lambda t: jnp.clip(t, 0, n_wo - 1)
     cf = lambda t: jnp.clip(t - n_wo, 0, n_ff - 1)
     bias_specs = [pl.BlockSpec((d,), lambda t: (0,)) for _ in biases]
@@ -295,7 +289,7 @@ def _fused_out_mlp_impl(xres, attn, g2, wo, wg, wu, wd, biases, lut, M, *,
         functools.partial(_out_mlp_kernel, M=M, eps=eps, n_wo=n_wo,
                           n_ff=n_ff, chunk_o=chunk_o, chunk_g=chunk_g,
                           chunk_d=chunk_d, d=d, dp2=dp2, has_bo=has_bo,
-                          has_bd=has_bd, packed=packed),
+                          has_bd=has_bd),
         grid=(n_wo + n_ff,),
         in_specs=[
             pl.BlockSpec((rows, d), lambda t: (0, 0)),
@@ -306,7 +300,7 @@ def _fused_out_mlp_impl(xres, attn, g2, wo, wg, wu, wd, biases, lut, M, *,
             pl.BlockSpec((dp2, bf), lambda t: (0, cf(t))),
             pl.BlockSpec((bf, d), lambda t: (cf(t), 0)),
             *bias_specs,
-            pl.BlockSpec((lut.shape[0],), lambda t: (0,)),
+            lut_spec(lut),
         ],
         out_specs=pl.BlockSpec((rows, d), lambda t: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
@@ -342,8 +336,7 @@ def fused_out_mlp(xres, attn, g2, wo, wg, wu, wd, lut, M: int, *,
     K = attn.shape[1]
     F = wg.shape[1]
     _TRACES[0] += 1
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     dc = autotune.get_decode_chain_config(rows, d, K, F, M, mult=mult)
     bko = dc.bko if bko is None else bko
     bf = dc.bf if bf is None else bf
@@ -365,7 +358,7 @@ def fused_out_mlp(xres, attn, g2, wo, wg, wu, wd, lut, M: int, *,
     biases = tuple(b.astype(f32) for b in (bo, bd) if b is not None)
     return _fused_out_mlp_impl(
         xres.astype(f32), attn, g2.astype(f32), wo, wg, wu, wd, biases,
-        jnp.asarray(lut), M, eps=float(eps), bko=bko, bf=bf,
+        kernel_lut(lut, M, interpret), M, eps=float(eps), bko=bko, bf=bf,
         chunk_o=chunk_o, chunk_g=chunk_g, chunk_d=chunk_d, dp2=dp2,
         has_bo=bo is not None, has_bd=bd is not None, interpret=interpret)
 
@@ -379,7 +372,7 @@ def fused_out_mlp(xres, attn, g2, wo, wg, wu, wd, lut, M: int, *,
 def _attn_out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
                          chunk_qk: int, chunk_t: int, chunk_o: int,
                          chunk_g: int, chunk_d: int, d: int, dp2: int,
-                         has_bo: bool, has_bd: bool, packed: bool):
+                         has_bo: bool, has_bd: bool):
     """fused_out_mlp's phases prefixed by an in-kernel attention core.
 
     At t == 0 (program order runs before phase A's first wo block) the
@@ -403,7 +396,7 @@ def _attn_out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
     t = pl.program_id(0)
     rows = xres_ref.shape[0]
     B, KV, G, dh = qg_ref.shape
-    Tp = kt_ref.shape[2]
+    Tp = kt_ref.shape[3]
     Bm = mask_ref.shape[0]
     bko = wo_ref.shape[0]
     lut = lut_ref[...]
@@ -414,26 +407,18 @@ def _attn_out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
         # attention output (exact +0.0 fold terms in phase A).
         attn_scr[...] = jnp.zeros_like(attn_scr)
         y_scr[...] = jnp.zeros_like(y_scr)
-        qa, ka, va = qg_ref[...], kt_ref[...], vt_ref[...]
-        ma, la = mask_ref[...], live_ref[...]
-
         def cell(c, carry):
             b, kv = c // KV, c % KV
             mrow = b if Bm > 1 else 0
-            qc = jax.lax.dynamic_slice(
-                qa, (b, kv, 0, 0), (1, 1, G, dh)).reshape(G, dh)
-            kc = jax.lax.dynamic_slice(
-                ka, (b, kv, 0, 0), (1, 1, Tp, dh)).reshape(Tp, dh)
-            vc = jax.lax.dynamic_slice(
-                va, (b, kv, 0, 0), (1, 1, Tp, dh)).reshape(Tp, dh)
-            mc = jax.lax.dynamic_slice(ma, (mrow, 0), (1, Tp))
-            lv = jax.lax.dynamic_slice(la, (mrow, 0), (1, 1))[0, 0]
+            lv = live_ref[mrow, 0] != 0
+            qc = qg_ref[b, kv]                                  # (G, dh)
 
             def live_tile():
                 s = _gather_gemm_tile(
-                    qc, kc.T, lut, jnp.zeros((G, Tp), jnp.float32),
-                    M=M, chunk=chunk_qk, packed=packed)
+                    qc, kt_ref[b, kv], lut, jnp.zeros((G, Tp), jnp.float32),
+                    M=M, chunk=chunk_qk)
                 s = s / jnp.sqrt(float(dh))
+                mc = mask_ref[pl.ds(mrow, 1), :] != 0
                 return jnp.where(jnp.broadcast_to(mc, (G, Tp)), s, NEG_INF)
 
             s = jax.lax.cond(
@@ -445,11 +430,14 @@ def _attn_out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
             acc = jax.lax.cond(
                 lv,
                 lambda: _gather_gemm_tile(
-                    probs, vc, lut, jnp.zeros((G, dh), jnp.float32),
-                    M=M, chunk=chunk_t, packed=packed),
+                    probs, vt_ref[b, kv], lut,
+                    jnp.zeros((G, dh), jnp.float32), M=M, chunk=chunk_t),
                 lambda: jnp.zeros((G, dh), jnp.float32))
-            attn_scr[pl.ds(b, 1), pl.ds(kv * (G * dh), G * dh)] = \
-                acc.reshape(1, G * dh)
+            # Head-major row of this (batch, kv-head): G*dh lanes at a
+            # G*dh-aligned offset.
+            row = jnp.concatenate([acc[g:g + 1] for g in range(G)], axis=1)
+            attn_scr[pl.ds(b, 1),
+                     pl.ds(pl.multiple_of(kv * (G * dh), G * dh), G * dh)] = row
             return carry
 
         jax.lax.fori_loop(0, B * KV, cell, 0)
@@ -459,10 +447,10 @@ def _attn_out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
     @pl.when(t < n_wo)
     def _wo():
         col = jnp.minimum(t, n_wo - 1) * bko
-        ab = jax.lax.dynamic_slice(attn_scr[...], (0, col), (rows, bko))
+        ab = attn_scr[:, pl.ds(pl.multiple_of(col, bko), bko)]
         y_scr[...] = _gather_gemm_tile(
             ab, wo_ref[...], lut, y_scr[...],
-            M=M, chunk=chunk_o, packed=packed)
+            M=M, chunk=chunk_o)
 
     @pl.when(t == n_wo - 1)
     def _norm():
@@ -471,8 +459,9 @@ def _attn_out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
             y = y + bo_ref[...]
         x1 = xres_ref[...] + y
         x1_scr[...] = x1
-        h = _rmsnorm_expr(x1, g_ref[...], eps)
-        h_scr[...] = jnp.pad(h, ((0, 0), (0, dp2 - d)))
+        h = rmsnorm_expr(x1, g_ref[...], eps)
+        h_scr[...] = jnp.zeros_like(h_scr)
+        h_scr[:, :d] = h
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     @pl.when(t >= n_wo)
@@ -481,13 +470,13 @@ def _attn_out_mlp_kernel(*refs, M: int, eps: float, n_wo: int, n_ff: int,
         bf = wg_ref.shape[1]
         zero = jnp.zeros((rows, bf), jnp.float32)
         g = _gather_gemm_tile(h, wg_ref[...], lut, zero,
-                              M=M, chunk=chunk_g, packed=packed)
+                              M=M, chunk=chunk_g)
         u = _gather_gemm_tile(h, wu_ref[...], lut, zero,
-                              M=M, chunk=chunk_g, packed=packed)
+                              M=M, chunk=chunk_g)
         a = jax.nn.silu(g) * u
         acc_scr[...] = _gather_gemm_tile(
             a, wd_ref[...], lut, acc_scr[...],
-            M=M, chunk=chunk_d, packed=packed)
+            M=M, chunk=chunk_d)
 
     @pl.when(t == n_wo + n_ff - 1)
     def _flush():
@@ -506,11 +495,10 @@ def _fused_attn_out_mlp_impl(xres, qg, kt, vt, mask, live, g2, wo, wg, wu,
                              has_bo, has_bd, interpret):
     rows, d = xres.shape
     B, KV, G, dh = qg.shape
-    Tp = kt.shape[2]
+    Tp = kt.shape[3]
     Bm = mask.shape[0]
     n_wo = kp // bko
     n_ff = wg.shape[1] // bf
-    packed = lut.dtype == jnp.uint16
     co = lambda t: jnp.clip(t, 0, n_wo - 1)
     cf = lambda t: jnp.clip(t - n_wo, 0, n_ff - 1)
     bias_specs = [pl.BlockSpec((d,), lambda t: (0,)) for _ in biases]
@@ -518,25 +506,24 @@ def _fused_attn_out_mlp_impl(xres, qg, kt, vt, mask, live, g2, wo, wg, wu,
         functools.partial(_attn_out_mlp_kernel, M=M, eps=eps, n_wo=n_wo,
                           n_ff=n_ff, chunk_qk=chunk_qk, chunk_t=chunk_t,
                           chunk_o=chunk_o, chunk_g=chunk_g, chunk_d=chunk_d,
-                          d=d, dp2=dp2, has_bo=has_bo, has_bd=has_bd,
-                          packed=packed),
+                          d=d, dp2=dp2, has_bo=has_bo, has_bd=has_bd),
         grid=(n_wo + n_ff,),
         in_specs=[
             pl.BlockSpec((rows, d), lambda t: (0, 0)),
             # q and the whole padded K/V views are pinned for the launch
             # (priced by vmem.attn_view_bytes); only wo/wg/wu/wd stream.
             pl.BlockSpec((B, KV, G, dh), lambda t: (0, 0, 0, 0)),
-            pl.BlockSpec((B, KV, Tp, dh), lambda t: (0, 0, 0, 0)),
+            pl.BlockSpec((B, KV, dh, Tp), lambda t: (0, 0, 0, 0)),
             pl.BlockSpec((B, KV, Tp, dh), lambda t: (0, 0, 0, 0)),
             pl.BlockSpec((Bm, Tp), lambda t: (0, 0)),
-            pl.BlockSpec((Bm, 1), lambda t: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((d,), lambda t: (0,)),
             pl.BlockSpec((bko, d), lambda t: (co(t), 0)),
             pl.BlockSpec((dp2, bf), lambda t: (0, cf(t))),
             pl.BlockSpec((dp2, bf), lambda t: (0, cf(t))),
             pl.BlockSpec((bf, d), lambda t: (cf(t), 0)),
             *bias_specs,
-            pl.BlockSpec((lut.shape[0],), lambda t: (0,)),
+            lut_spec(lut),
         ],
         out_specs=pl.BlockSpec((rows, d), lambda t: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
@@ -583,8 +570,7 @@ def fused_attn_out_mlp(xres, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd,
     assert S == 1 and rows == B, (q.shape, xres.shape)
     assert k.shape == v.shape and k.shape[0] == B, (q.shape, k.shape)
     _TRACES[0] += 1
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     # Attention tiling: the standalone wrapper's derivation verbatim.
     acfg = autotune.get_attn_config(B * KV, S, T, G, dh, M, mult=mult)
     bkv = max(1, min(min(acfg.bkv, 256), T))
@@ -595,8 +581,9 @@ def fused_attn_out_mlp(xres, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd,
     chunk_t = best_chunk(acfg.chunk, bkv)
     f32 = jnp.float32
     qg = q.astype(f32).reshape(B, KV, G, dh)
-    kt = jnp.pad(k.astype(f32).transpose(0, 2, 1, 3),
-                 ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
+    # K transposed, (B, KV, dh, Tp): the score GEMM's B operand.
+    kt = jnp.pad(k.astype(f32).transpose(0, 2, 3, 1),
+                 ((0, 0), (0, 0), (0, 0), (0, Tp - T)))
     vt = jnp.pad(v.astype(f32).transpose(0, 2, 1, 3),
                  ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
     # Mask/liveness: _attn_impl's construction at S == 1 (Sp == bq == 1
@@ -613,6 +600,7 @@ def fused_attn_out_mlp(xres, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd,
         mask = (attention_mask(qp, kpos, causal=causal, window=int(window))
                 & (qp >= 0)[:, None])                       # (1, Tp)
     live = jnp.any(mask, axis=-1, keepdims=True)            # (Bm, 1)
+    mask, live = mask.astype(jnp.int32), live.astype(jnp.int32)
     # Back-half folds: fused_out_mlp's derivation verbatim.
     dc = autotune.get_decode_chain_config(rows, d, K, F, M, mult=mult)
     bko = dc.bko if bko is None else bko
@@ -627,8 +615,7 @@ def fused_attn_out_mlp(xres, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd,
     wu = jnp.pad(wu.astype(f32), ((0, dp2 - d), (0, fp - F)))
     wd = jnp.pad(wd.astype(f32), ((0, fp - F), (0, 0)))
     biases = tuple(b.astype(f32) for b in (bo, bd) if b is not None)
-    lut = jnp.asarray(lut)
-    lut = lut if lut.dtype == jnp.uint16 else lut.astype(jnp.uint32)
+    lut = kernel_lut(lut, M, interpret)
     return _fused_attn_out_mlp_impl(
         xres.astype(f32), qg, kt, vt, mask, live, g2.astype(f32),
         wo, wg, wu, wd, biases, lut, M, eps=float(eps), bko=bko, bf=bf,
@@ -644,7 +631,7 @@ def fused_attn_out_mlp(xres, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd,
 # =====================================================================
 
 def _wo_norm_kernel(*refs, M: int, eps: float, n_wo: int, chunk_o: int,
-                    has_bo: bool, packed: bool):
+                    has_bo: bool):
     it = iter(refs)
     xres_ref, attn_ref, g_ref, wo_ref = next(it), next(it), next(it), next(it)
     bo_ref = next(it) if has_bo else None
@@ -658,7 +645,7 @@ def _wo_norm_kernel(*refs, M: int, eps: float, n_wo: int, chunk_o: int,
 
     y_scr[...] = _gather_gemm_tile(
         attn_ref[...], wo_ref[...], lut_ref[...], y_scr[...],
-        M=M, chunk=chunk_o, packed=packed)
+        M=M, chunk=chunk_o)
 
     @pl.when(t == n_wo - 1)
     def _norm():
@@ -667,7 +654,7 @@ def _wo_norm_kernel(*refs, M: int, eps: float, n_wo: int, chunk_o: int,
             y = y + bo_ref[...]
         x1 = xres_ref[...] + y
         x1_ref[...] = x1
-        h_ref[...] = _rmsnorm_expr(x1, g_ref[...], eps)
+        h_ref[...] = rmsnorm_expr(x1, g_ref[...], eps)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -676,12 +663,11 @@ def _fused_wo_norm_impl(xres, attn, g2, wo, biases, lut, M, *, eps, bko,
                         chunk_o, has_bo, interpret):
     rows, d = xres.shape
     n_wo = attn.shape[1] // bko
-    packed = lut.dtype == jnp.uint16
     co = lambda t: jnp.clip(t, 0, n_wo - 1)
     bias_specs = [pl.BlockSpec((d,), lambda t: (0,)) for _ in biases]
     x1, h = pl.pallas_call(
         functools.partial(_wo_norm_kernel, M=M, eps=eps, n_wo=n_wo,
-                          chunk_o=chunk_o, has_bo=has_bo, packed=packed),
+                          chunk_o=chunk_o, has_bo=has_bo),
         grid=(n_wo,),
         in_specs=[
             pl.BlockSpec((rows, d), lambda t: (0, 0)),
@@ -689,7 +675,7 @@ def _fused_wo_norm_impl(xres, attn, g2, wo, biases, lut, M, *, eps, bko,
             pl.BlockSpec((d,), lambda t: (0,)),
             pl.BlockSpec((bko, d), lambda t: (co(t), 0)),
             *bias_specs,
-            pl.BlockSpec((lut.shape[0],), lambda t: (0,)),
+            lut_spec(lut),
         ],
         out_specs=[pl.BlockSpec((rows, d), lambda t: (0, 0)),
                    pl.BlockSpec((rows, d), lambda t: (0, 0))],
@@ -719,8 +705,7 @@ def fused_wo_norm(xres, attn, g2, wo, lut, M: int, *, eps: float, bo=None,
     rows, d = xres.shape
     K = attn.shape[1]
     _TRACES[0] += 1
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if bko is None:
         bko = autotune.get_decode_chain_config(rows, d, K, 0, M,
                                                mult=mult).bko
@@ -732,13 +717,12 @@ def fused_wo_norm(xres, attn, g2, wo, lut, M: int, *, eps: float, bo=None,
     biases = tuple(b.astype(f32) for b in (bo,) if b is not None)
     return _fused_wo_norm_impl(
         xres.astype(f32), attn, g2.astype(f32), wo, biases,
-        jnp.asarray(lut), M, eps=float(eps), bko=bko, chunk_o=chunk_o,
-        has_bo=bo is not None, interpret=interpret)
+        kernel_lut(lut, M, interpret), M, eps=float(eps), bko=bko,
+        chunk_o=chunk_o, has_bo=bo is not None, interpret=interpret)
 
 
 def _moe_ffn_kernel(h_ref, wg_ref, wu_ref, wd_ref, lut_ref, o_ref, acc_scr,
-                    *, M: int, n_ff: int, chunk_g: int, chunk_d: int,
-                    packed: bool):
+                    *, M: int, n_ff: int, chunk_g: int, chunk_d: int):
     f = pl.program_id(1)
     lut = lut_ref[...]
     h = h_ref[0]
@@ -751,12 +735,12 @@ def _moe_ffn_kernel(h_ref, wg_ref, wu_ref, wd_ref, lut_ref, o_ref, acc_scr,
 
     zero = jnp.zeros((rows, bf), jnp.float32)
     g = _gather_gemm_tile(h, wg_ref[0], lut, zero,
-                          M=M, chunk=chunk_g, packed=packed)
+                          M=M, chunk=chunk_g)
     u = _gather_gemm_tile(h, wu_ref[0], lut, zero,
-                          M=M, chunk=chunk_g, packed=packed)
+                          M=M, chunk=chunk_g)
     a = jax.nn.silu(g) * u
     acc_scr[...] = _gather_gemm_tile(
-        a, wd_ref[0], lut, acc_scr[...], M=M, chunk=chunk_d, packed=packed)
+        a, wd_ref[0], lut, acc_scr[...], M=M, chunk=chunk_d)
 
     @pl.when(f == n_ff - 1)
     def _flush():
@@ -770,10 +754,9 @@ def _fused_moe_ffn_impl(h, wg, wu, wd, lut, M, *, bf, chunk_g, chunk_d,
     E, C, dgp = h.shape
     d = wd.shape[2]
     n_ff = wg.shape[2] // bf
-    packed = lut.dtype == jnp.uint16
     out = pl.pallas_call(
         functools.partial(_moe_ffn_kernel, M=M, n_ff=n_ff, chunk_g=chunk_g,
-                          chunk_d=chunk_d, packed=packed),
+                          chunk_d=chunk_d),
         grid=(E, n_ff),
         in_specs=[
             # One expert's capacity block is resident per outer grid
@@ -783,7 +766,7 @@ def _fused_moe_ffn_impl(h, wg, wu, wd, lut, M, *, bf, chunk_g, chunk_d,
             pl.BlockSpec((1, dgp, bf), lambda e, f: (e, 0, f)),
             pl.BlockSpec((1, dgp, bf), lambda e, f: (e, 0, f)),
             pl.BlockSpec((1, bf, d), lambda e, f: (e, f, 0)),
-            pl.BlockSpec((lut.shape[0],), lambda e, f: (0,)),
+            lut_spec(lut),
         ],
         out_specs=pl.BlockSpec((1, C, d), lambda e, f: (e, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((E, C, d), jnp.float32),
@@ -814,8 +797,7 @@ def fused_moe_ffn(h, wg, wu, wd, lut, M: int, *, bf: int | None = None,
     E, C, d = h.shape
     F = wg.shape[2]
     _TRACES[0] += 1
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if bf is None:
         bf = autotune.get_decode_chain_config(C, d, d, F, M, mult=mult).bf
     bk_g, chunk_g, dgp = oracle_fold(C, d, F, M, mult,
@@ -828,8 +810,8 @@ def fused_moe_ffn(h, wg, wu, wd, lut, M: int, *, bf: int | None = None,
     wg = jnp.pad(wg.astype(f32), ((0, 0), (0, dgp - d), (0, fp - F)))
     wu = jnp.pad(wu.astype(f32), ((0, 0), (0, dgp - d), (0, fp - F)))
     wd = jnp.pad(wd.astype(f32), ((0, 0), (0, fp - F), (0, 0)))
-    return _fused_moe_ffn_impl(h, wg, wu, wd, jnp.asarray(lut), M, bf=bf,
-                               chunk_g=chunk_g, chunk_d=chunk_d,
+    return _fused_moe_ffn_impl(h, wg, wu, wd, kernel_lut(lut, M, interpret),
+                               M, bf=bf, chunk_g=chunk_g, chunk_d=chunk_d,
                                interpret=interpret)
 
 

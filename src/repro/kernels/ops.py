@@ -33,9 +33,10 @@ approximate multipliers in both forward and backpropagation).
 
 Accumulation is always f32 (paper §VII).
 
-Distribution: these wrappers are single-logical-device ops — GSPMD
-cannot partition a pallas_call, so under a mesh it replicates the
-kernel.  The mesh-aware dispatch lives one layer up in
+Distribution: these wrappers are single-logical-device ops.  GSPMD
+cannot partition a pallas_call (on the chip Mosaic refuses outright), so
+under a mesh each kernel call runs replicated inside a shard_map
+(``_replicated``).  The mesh-aware dispatch lives one layer up in
 ``distributed/shard_fused`` (shard_map around these same kernels,
 collectives outside); model layers call it with their Megatron role.
 Kill switches REPRO_CONV_FUSED / REPRO_ATTN_FUSED below and
@@ -44,11 +45,13 @@ REPRO_SHARD_FUSED up there are all documented in docs/configuration.md.
 from __future__ import annotations
 
 import os
+import warnings
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.core import faults
 from repro.core.float_bits import jnp_truncate_mantissa, jnp_round_mantissa
@@ -57,7 +60,7 @@ from repro.core.multipliers import get_multiplier
 from repro.core.policy import PASSES, Numerics, NumericsPolicy
 from repro.kernels.approx_attention import (NEG_INF, approx_attention_fused,
                                             attention_fused_supported)
-from repro.kernels.common import attention_mask, best_chunk
+from repro.kernels.common import attention_mask, best_chunk, rms_norm
 from repro.kernels.approx_conv import (approx_conv2d_dw, approx_conv2d_fused,
                                        conv_pads, fused_supported)
 from repro.kernels.approx_gemm import approx_gemm, approx_gemm_batched
@@ -67,6 +70,23 @@ from repro.kernels.ref import ref_amsim_gemm, ref_direct_gemm, ref_im2col
 # =====================================================================
 # GEMM dispatch (2-D and stacked-batch 3-D)
 # =====================================================================
+
+def _replicated(fn, *args, **kw):
+    """``fn(*args, **kw)`` — one Pallas kernel call — run whole on every
+    device of the ambient mesh, outside a shard_map body.
+
+    Mosaic kernels cannot be partitioned automatically ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map"), so a kernel the sharded dispatch
+    (distributed/shard_fused) does not take runs on replicated operands,
+    bitwise as on one device.  Interpret mode lowers the same way."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size <= 1 or all(
+            t == AxisType.Manual for t in mesh.axis_types):
+        return fn(*args, **kw)
+    return jax.shard_map(partial(fn, **kw), mesh=mesh, in_specs=P(),
+                         out_specs=P(), check_vma=False)(*args)
+
 
 def _amsim_lut(mult):
     """Kernel LUT for ``mult``: packed uint16 when the table allows it
@@ -103,8 +123,9 @@ def _oracle_lut(mult):
 # to ``impl(a, b, mult, kernel)``; ``kernel`` is the engine's amsim
 # kernel, with the resolved multiplier name keying the autotune cache.
 _GEMM_MODES = {
-    "amsim": lambda a, b, mult, kernel: kernel(
-        a, b, _amsim_lut(mult), mult.mantissa_bits, mult=mult.name),
+    "amsim": lambda a, b, mult, kernel: _replicated(
+        lambda a_, b_: kernel(a_, b_, _amsim_lut(mult), mult.mantissa_bits,
+                              mult=mult.name), a, b),
     "amsim_jnp": lambda a, b, mult, kernel: ref_amsim_gemm(
         a, b, jnp.asarray(_oracle_lut(mult)), mult.mantissa_bits),
     "direct": lambda a, b, mult, kernel: ref_direct_gemm(a, b, mult),
@@ -339,13 +360,26 @@ def policy_einsum(spec: str, a, b, policy: Numerics, site: str | None = None):
 _conv_pads = conv_pads
 
 
+def _say_unfused(what: str, shape) -> bool:
+    """Warn (once per shape, by the warnings registry) that an amsim site
+    on the chip lowers to its unfused form (per-GEMM LUT kernels), so a
+    slow path never passes for the fused one in silence.  Returns False
+    for the guard."""
+    if jax.default_backend() == "tpu":
+        warnings.warn(f"amsim {what} {shape} exceeds the fused kernel's "
+                      f"VMEM guard on this TPU: unfused per-GEMM LUT "
+                      f"kernels instead", stacklevel=3)
+    return False
+
+
 def _conv_use_fused(x_shape, w_shape, stride, leaf: NumericsPolicy) -> bool:
     """``leaf`` is an already-resolved (per-pass) policy."""
     if leaf.mode != "amsim" or leaf.is_native:
         return False
     if os.environ.get("REPRO_CONV_FUSED", "1").lower() in ("0", "false"):
         return False
-    return fused_supported(x_shape, w_shape, stride)
+    return (fused_supported(x_shape, w_shape, stride)
+            or _say_unfused("conv2d", (x_shape, w_shape)))
 
 
 def conv2d_im2col(x, w, stride, padding, policy):
@@ -366,9 +400,10 @@ def _conv_fwd_impl(x, w, stride, padding, policy):
     leaf = policy.resolve("conv")
     if _conv_use_fused(x.shape, w.shape, stride, leaf):
         mult = get_multiplier(leaf.multiplier)
-        return approx_conv2d_fused(
-            x, w, _amsim_lut(mult), mult.mantissa_bits,
-            stride=stride, padding=padding, mult=mult.name)
+        return _replicated(
+            lambda x_, w_: approx_conv2d_fused(
+                x_, w_, _amsim_lut(mult), mult.mantissa_bits,
+                stride=stride, padding=padding, mult=mult.name), x, w)
     return conv2d_im2col(x, w, stride, padding, policy)
 
 
@@ -404,9 +439,10 @@ def _conv_bwd(stride, padding, policy, res, g):
     # strided patch slicing inside either lowering.
     if _conv_use_fused(x.shape, w.shape, stride, leaf_dw):
         mw = get_multiplier(leaf_dw.multiplier)
-        dw = approx_conv2d_dw(x, g, _amsim_lut(mw), mw.mantissa_bits,
-                              kh=kh, kw=kw, stride=stride, padding=padding,
-                              mult=mw.name)
+        dw = _replicated(
+            lambda x_, g_: approx_conv2d_dw(
+                x_, g_, _amsim_lut(mw), mw.mantissa_bits, kh=kh, kw=kw,
+                stride=stride, padding=padding, mult=mw.name), x, g)
     else:
         g2 = g.reshape(n * oh * ow, o).astype(jnp.float32)
         cols = ref_im2col(x, kh, kw, stride, pad)    # (N*OH*OW, KH*KW*C)
@@ -433,9 +469,10 @@ def _conv_bwd(stride, padding, policy, res, g):
         # Transposed conv IS a conv: the same fused forward kernel runs
         # the stride-1 correlation under the explicit asymmetric pads.
         mx = get_multiplier(leaf_dx.multiplier)
-        dx = approx_conv2d_fused(gd, wrt4, _amsim_lut(mx), mx.mantissa_bits,
-                                 stride=1, padding=(pt, pb, pl_, pr),
-                                 mult=mx.name)
+        dx = _replicated(
+            lambda g_, w_: approx_conv2d_fused(
+                g_, w_, _amsim_lut(mx), mx.mantissa_bits, stride=1,
+                padding=(pt, pb, pl_, pr), mult=mx.name), gd, wrt4)
     else:
         gcols = ref_im2col(gd, kh, kw, 1, (pt, pb, pl_, pr))  # (N*H*W, KH*KW*O)
         dx = _matmul_nograd(gcols, wrt4.reshape(-1, c), leaf_dx).reshape(
@@ -521,16 +558,19 @@ def fused_attention_enabled(policy: Numerics, q_shape, k_shape, *,
         return False
     if os.environ.get("REPRO_ATTN_FUSED", "1").lower() in ("0", "false"):
         return False
-    return attention_fused_supported(q_shape, k_shape, causal=causal,
-                                     window=window, per_row=per_row)
+    return (attention_fused_supported(q_shape, k_shape, causal=causal,
+                                      window=window, per_row=per_row)
+            or _say_unfused("attention", (q_shape, k_shape)))
 
 
 def _attention_fwd_impl(q, k, v, q_pos, k_pos, policy, causal, window):
     mult = get_multiplier(attention_fused_leaf(policy).multiplier)
-    return approx_attention_fused(
+    return _replicated(
+        lambda q_, k_, v_, qp, kp: approx_attention_fused(
+            q_, k_, v_, qp, kp, _amsim_lut(mult), mult.mantissa_bits,
+            causal=causal, window=window, mult=mult.name),
         q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
-        q_pos, k_pos, _amsim_lut(mult), mult.mantissa_bits,
-        causal=causal, window=window, mult=mult.name)
+        q_pos, k_pos)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -708,8 +748,7 @@ def decode_qkv_oracle(x, g1, wq, wk, wv, policy: Numerics, eps: float):
     per-op projections, exactly what models/layers runs when the chain
     is off.  The fused forward is bit-tested against this, and the
     fused VJP recomputes through it."""
-    from repro.kernels.decode_chain import _rmsnorm_expr
-    h = _rmsnorm_expr(x.astype(jnp.float32), g1, eps)
+    h = rms_norm(x.astype(jnp.float32), g1, eps)
     return (policy_matmul(h, wq, policy, "qkv"),
             policy_matmul(h, wk, policy, "qkv"),
             policy_matmul(h, wv, policy, "qkv"))
@@ -721,12 +760,11 @@ def decode_out_mlp_oracle(x, attn, g2, wo, wg, wu, wd, policy: Numerics,
     residual + rmsnorm + swiglu FFN + residual, per-op.  Optional wo/wd
     epilogue biases are added before the residual, matching
     models/layers.linear's op order."""
-    from repro.kernels.decode_chain import _rmsnorm_expr
     yo = policy_matmul(attn.astype(jnp.float32), wo, policy, "wo")
     if bo is not None:
         yo = yo + bo
     x1 = x.astype(jnp.float32) + yo
-    h = _rmsnorm_expr(x1, g2, eps)
+    h = rms_norm(x1, g2, eps)
     y = policy_matmul(
         jax.nn.silu(policy_matmul(h, wg, policy, "wg"))
         * policy_matmul(h, wu, policy, "wu"),
@@ -739,12 +777,11 @@ def decode_out_mlp_oracle(x, attn, g2, wo, wg, wu, wd, policy: Numerics,
 def decode_wo_norm_oracle(x, attn, g2, wo, bo, policy: Numerics, eps: float):
     """Unfused reference for the MoE back half's shared prefix:
     x1 = x + (attn @ wo [+ bo]); h = rmsnorm(x1).  Returns (x1, h)."""
-    from repro.kernels.decode_chain import _rmsnorm_expr
     yo = policy_matmul(attn.astype(jnp.float32), wo, policy, "wo")
     if bo is not None:
         yo = yo + bo
     x1 = x.astype(jnp.float32) + yo
-    return x1, _rmsnorm_expr(x1, g2, eps)
+    return x1, rms_norm(x1, g2, eps)
 
 
 def decode_moe_ffn_oracle(buf, wg, wu, wd, policy: Numerics):
@@ -774,8 +811,10 @@ def decode_qkv(x, g1, wq, wk, wv, policy: Numerics, eps: float):
 def _decode_qkv_fwd_impl(x, g1, wq, wk, wv, policy, eps):
     from repro.kernels.decode_chain import fused_qkv_norm
     mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return fused_qkv_norm(x, g1, wq, wk, wv, _amsim_lut(mult),
-                          mult.mantissa_bits, eps=eps, mult=mult.name)
+    return _replicated(
+        lambda *a: fused_qkv_norm(*a, _amsim_lut(mult), mult.mantissa_bits,
+                                  eps=eps, mult=mult.name),
+        x, g1, wq, wk, wv)
 
 
 def _decode_qkv_fwd(x, g1, wq, wk, wv, policy, eps):
@@ -809,8 +848,10 @@ def decode_out_mlp(x, attn, g2, wo, wg, wu, wd, policy: Numerics,
 def _decode_out_mlp_fwd_impl(x, attn, g2, wo, wg, wu, wd, policy, eps):
     from repro.kernels.decode_chain import fused_out_mlp
     mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return fused_out_mlp(x, attn, g2, wo, wg, wu, wd, _amsim_lut(mult),
-                         mult.mantissa_bits, eps=eps, mult=mult.name)
+    return _replicated(
+        lambda *a: fused_out_mlp(*a, _amsim_lut(mult), mult.mantissa_bits,
+                                 eps=eps, mult=mult.name),
+        x, attn, g2, wo, wg, wu, wd)
 
 
 def _decode_out_mlp_fwd(x, attn, g2, wo, wg, wu, wd, policy, eps):
@@ -846,9 +887,11 @@ def _decode_out_mlp_b_fwd_impl(x, attn, g2, wo, wg, wu, wd, bo, bd,
                                policy, eps):
     from repro.kernels.decode_chain import fused_out_mlp
     mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return fused_out_mlp(x, attn, g2, wo, wg, wu, wd, _amsim_lut(mult),
-                         mult.mantissa_bits, eps=eps, bo=bo, bd=bd,
-                         mult=mult.name)
+    return _replicated(
+        lambda *a, bo, bd: fused_out_mlp(
+            *a, _amsim_lut(mult), mult.mantissa_bits, eps=eps, bo=bo, bd=bd,
+            mult=mult.name),
+        x, attn, g2, wo, wg, wu, wd, bo=bo, bd=bd)
 
 
 def _decode_out_mlp_b_fwd(x, attn, g2, wo, wg, wu, wd, bo, bd, policy, eps):
@@ -886,9 +929,10 @@ def decode_wo_norm(x, attn, g2, wo, bo, policy: Numerics, eps: float):
 def _decode_wo_norm_fwd_impl(x, attn, g2, wo, bo, policy, eps):
     from repro.kernels.decode_chain import fused_wo_norm
     mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return fused_wo_norm(x, attn, g2, wo, _amsim_lut(mult),
-                         mult.mantissa_bits, eps=eps, bo=bo,
-                         mult=mult.name)
+    return _replicated(
+        lambda *a, bo: fused_wo_norm(*a, _amsim_lut(mult), mult.mantissa_bits,
+                                     eps=eps, bo=bo, mult=mult.name),
+        x, attn, g2, wo, bo=bo)
 
 
 def _decode_wo_norm_fwd(x, attn, g2, wo, bo, policy, eps):
@@ -923,8 +967,10 @@ def decode_moe_ffn(buf, wg, wu, wd, policy: Numerics):
 def _decode_moe_ffn_fwd_impl(buf, wg, wu, wd, policy):
     from repro.kernels.decode_chain import fused_moe_ffn
     mult = get_multiplier(moe_ffn_leaf(policy).multiplier)
-    return fused_moe_ffn(buf, wg, wu, wd, _amsim_lut(mult),
-                         mult.mantissa_bits, mult=mult.name)
+    return _replicated(
+        lambda *a: fused_moe_ffn(*a, _amsim_lut(mult), mult.mantissa_bits,
+                                 mult=mult.name),
+        buf, wg, wu, wd)
 
 
 def _decode_moe_ffn_fwd(buf, wg, wu, wd, policy):
@@ -1000,10 +1046,11 @@ def _decode_attn_out_mlp_fwd_impl(x, q, k, v, q_pos, k_pos, g2, wo, wg, wu,
                                   wd, bo, bd, policy, eps, causal, window):
     from repro.kernels.decode_chain import fused_attn_out_mlp
     mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return fused_attn_out_mlp(x, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd,
-                              _amsim_lut(mult), mult.mantissa_bits, eps=eps,
-                              causal=causal, window=int(window), bo=bo,
-                              bd=bd, mult=mult.name)
+    return _replicated(
+        lambda *a, bo, bd: fused_attn_out_mlp(
+            *a, _amsim_lut(mult), mult.mantissa_bits, eps=eps, causal=causal,
+            window=int(window), bo=bo, bd=bd, mult=mult.name),
+        x, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd, bo=bo, bd=bd)
 
 
 def _decode_attn_out_mlp_fwd(x, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd,

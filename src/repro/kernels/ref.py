@@ -15,34 +15,39 @@ import jax.numpy as jnp
 from repro.core.amsim import amsim_multiply
 from repro.core.multipliers import Multiplier
 
-# Contraction-chunk size for elementwise-simulated GEMMs: bounds the
-# (m, chunk, n) intermediate to keep the oracle runnable at LeNet scale.
+# Contraction-chunk size of the oracle's FP32 fold (see _elementwise_gemm).
 _K_CHUNK = 128
 
 
 def _elementwise_gemm(a, b, mul):
     """Shared oracle body: (..., m, k) @ (..., k, n) with ``mul`` as the
-    scalar product.  Equal leading batch dims; k is chunked so the
-    (..., m, chunk, n) intermediate stays bounded at LeNet scale."""
+    scalar product.  Equal leading batch dims.
+
+    The FP32 fold is explicit, the same on every backend and the same as
+    the Pallas brick's (kernels/common.py): each chunk of ``_K_CHUNK``
+    consecutive k (all of k when it is not a multiple above one chunk)
+    sums its products in order from +0.0, and the chunk sums are added
+    to the accumulator in order.  A ``jnp.sum`` would leave the order to
+    the compiler, which reassociates it differently per backend and per
+    fusion.
+    """
     k = a.shape[-1]
     assert b.shape[-2] == k and a.shape[:-2] == b.shape[:-2], (a.shape, b.shape)
     m, n = a.shape[-2], b.shape[-1]
-    batch = a.shape[:-2]
+    zero = jnp.zeros(a.shape[:-2] + (m, n), jnp.float32)
 
-    def chunk(acc, idx):
-        ac = jax.lax.dynamic_slice_in_dim(a, idx, _K_CHUNK, axis=a.ndim - 1)
-        bc = jax.lax.dynamic_slice_in_dim(b, idx, _K_CHUNK, axis=b.ndim - 2)
-        prod = mul(ac[..., :, :, None], bc[..., None, :, :])
-        return acc + jnp.sum(prod, axis=-2, dtype=jnp.float32), None
+    def fold(lo, size):
+        def step(j, s):
+            aj = jax.lax.dynamic_slice_in_dim(a, lo + j, 1, axis=a.ndim - 1)
+            bj = jax.lax.dynamic_slice_in_dim(b, lo + j, 1, axis=b.ndim - 2)
+            return s + mul(aj, bj)
+        return jax.lax.fori_loop(0, size, step, zero)
 
     if k % _K_CHUNK == 0 and k > _K_CHUNK:
-        acc = jnp.zeros(batch + (m, n), jnp.float32)
-        acc, _ = jax.lax.scan(
-            chunk, acc, jnp.arange(0, k, _K_CHUNK, dtype=jnp.int32)
-        )
-        return acc
-    prod = mul(a[..., :, :, None], b[..., None, :, :])
-    return jnp.sum(prod, axis=-2, dtype=jnp.float32)
+        return jax.lax.fori_loop(
+            0, k // _K_CHUNK,
+            lambda i, acc: acc + fold(i * _K_CHUNK, _K_CHUNK), zero)
+    return fold(0, k)
 
 
 def ref_amsim_gemm(a, b, lut, M: int):

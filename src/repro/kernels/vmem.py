@@ -26,10 +26,14 @@ about flops.  This module is the one place that working set is priced:
     candidates whose streamed blocks fit, so the tuner never times a
     config the guard would refuse at dispatch time.
 
-Budget constants are conservative against the ~16 MiB/core hardware
-VMEM (same philosophy as ``attention_fused_supported``); the estimators
-deliberately sum both chain launches even though they run sequentially,
-keeping the historical guard's conservatism.
+Budget constants are conservative: the chip's compiler reports 128 MiB
+of VMEM on a TPU v5e core (a kernel asking for 129 MiB of scratch is
+refused with "would exceed memory (size=134217728)"; 100 MiB compiles),
+far above the 10 MiB budget, which has not been calibrated on the chip.
+The margin also covers what the budget does not itemise: the LUT brick's
+scoped operand copies (kernels/common.py).  The estimators deliberately
+sum both chain launches even though they run sequentially, keeping the
+historical guard's conservatism.
 """
 from __future__ import annotations
 

@@ -44,7 +44,7 @@ def _compile_costs(cfg, shape, mesh, policy, microbatches, chips):
     """lower+compile one cell config; return (compiled, costs dict)."""
     kw = {"microbatches": microbatches} if shape.kind == "train" else {}
     cell = build_cell(cfg, shape, mesh, policy, **kw)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                           out_shardings=cell.out_shardings).lower(*cell.args)
         compiled = lowered.compile()

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.configs import get_arch, reduced
 from repro.core.policy import MODES, NumericsPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.serve.engine import ServingEngine
 from repro.serve.scheduler import ContinuousBatchingEngine
@@ -53,8 +54,8 @@ def parse_tiers(spec: str) -> dict:
 
 
 def run_stream(args, cfg, params, mesh):
-    """Replay a synthetic timed stream through the paged scheduler and
-    report total + per-tier throughput."""
+    """Replay a synthetic timed stream through the paged scheduler,
+    report total + per-tier throughput, and return the engine."""
     tiers = parse_tiers(args.tiers)
     max_len = args.prompt_len + args.new_tokens + 1
     engine = ContinuousBatchingEngine(
@@ -75,14 +76,18 @@ def run_stream(args, cfg, params, mesh):
     total = sum(len(r.out) for r in engine.finished.values())
     print(f"stream: {args.stream} requests, {total} tokens in {dt:.2f}s "
           f"({total / dt:.1f} tok/s)")
+    busy = engine.busy_seconds
     for name in names:
         n = sum(len(r.out) for r in engine.finished.values()
                 if r.tier == name)
-        print(f"  tier {name}: {n} tokens")
+        print(f"  tier {name}: {n} tokens in {busy[name]:.2f}s of calls "
+              f"({n / busy[name]:.1f} tok/s, informational: includes "
+              f"compilation)")
     print(f"decode traces: {engine.decode_trace_counts} "
           f"(expect 1 per tier)")
     for name, count in engine.decode_trace_counts.items():
         assert count == 1, f"tier {name} retraced decode ({count}x)"
+    return engine
 
 
 def main():
@@ -115,6 +120,7 @@ def main():
                          "(--stream)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

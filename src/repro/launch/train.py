@@ -45,7 +45,9 @@ from repro.core.policy import (MODES, NumericsPolicy, PolicyTable,
                                table_from_assignments, table_from_json)
 from repro.data.pipeline import lm_batch
 from repro.distributed import shard_fused
-from repro.distributed.sharding import lm_param_pspecs, opt_state_pspecs
+from repro.distributed.sharding import (lm_param_pspecs, opt_state_pspecs,
+                                        to_shardings)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models import encdec as encdec_mod
 from repro.models.transformer import init_lm, lm_loss
@@ -120,6 +122,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -147,8 +150,22 @@ def main():
     else:
         mesh = None
     print(_describe_numerics(policy, mesh))
+    state = train(cfg, policy, shape, steps=args.steps, lr=args.lr,
+                  seed=args.seed, mesh=mesh, microbatches=args.microbatches,
+                  ckpt_dir=args.ckpt_dir)
+    print(f"done at step {state.step}; stragglers flagged: "
+          f"{len(state.stragglers)}")
 
-    key = jax.random.PRNGKey(args.seed)
+
+def train(cfg, policy, shape, *, steps: int, lr: float = 3e-4,
+          seed: int = 0, mesh=None, microbatches: int = 1,
+          ckpt_dir=None, log_every=None) -> TrainerState:
+    """Build the params, optimizer and jitted step the way this driver
+    does, and run them through the :class:`Trainer` for ``steps`` steps.
+    Under ``mesh`` the state is placed with the sharding rules and the
+    step traces inside the mesh context.  The returned state carries the
+    logged metrics in ``history``."""
+    key = jax.random.PRNGKey(seed)
     if cfg.family == "encdec":
         params = encdec_mod.init_encdec(key, cfg)
         loss_fn = lambda p, b: encdec_mod.encdec_loss(p, b, cfg, policy)
@@ -156,42 +173,37 @@ def main():
         params = init_lm(key, cfg)
         loss_fn = lambda p, b: lm_loss(p, b, cfg, policy)
 
-    opt = make_optimizer(cfg.optimizer, cosine_schedule(args.lr, 10, args.steps))
+    opt = make_optimizer(cfg.optimizer, cosine_schedule(lr, 10, steps))
     opt_state = opt.init(params)
-    step_fn = make_train_step(loss_fn, opt, microbatches=args.microbatches)
+    step_fn = make_train_step(loss_fn, opt, microbatches=microbatches)
+    run = lambda fn, p, o, shardings=None: run_train(
+        fn, cfg, shape, p, o, steps=steps, ckpt_dir=ckpt_dir,
+        log_every=log_every, shardings=shardings)
 
-    if mesh is not None:
-        from jax.sharding import NamedSharding
-        pspecs = lm_param_pspecs(params, cfg, mesh)
-        ospecs = opt_state_pspecs(cfg.optimizer, pspecs)
-        psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
-                           is_leaf=lambda x: hasattr(x, "_normalized_spec_for_aval") or type(x).__name__ == "PartitionSpec")
-        osh = jax.tree.map(lambda s: NamedSharding(mesh, s), ospecs,
-                           is_leaf=lambda x: type(x).__name__ == "PartitionSpec")
-        params = jax.device_put(params, psh)
-        opt_state = jax.device_put(opt_state, osh)
-        # Trace INSIDE the mesh context: shard_fused reads the ambient
-        # mesh at trace time — this is what routes mode="amsim" through
-        # the per-shard fused kernels instead of GSPMD's replicated
-        # pallas_call lowering.
-        with mesh:
-            step_fn = jax.jit(step_fn)
-            run_train(step_fn, cfg, shape, params, opt_state, args,
-                      shardings={"params": psh, "opt": osh})
-    else:
-        step_fn = jax.jit(step_fn)
-        run_train(step_fn, cfg, shape, params, opt_state, args)
+    if mesh is None:
+        return run(jax.jit(step_fn), params, opt_state)
+    pspecs = lm_param_pspecs(params, cfg, mesh)
+    psh = to_shardings(pspecs, mesh)
+    osh = to_shardings(opt_state_pspecs(cfg.optimizer, pspecs), mesh)
+    params = jax.device_put(params, psh)
+    opt_state = jax.device_put(opt_state, osh)
+    # Trace INSIDE the mesh context: shard_fused reads the ambient
+    # mesh at trace time — this is what routes mode="amsim" through
+    # the per-shard fused kernels instead of GSPMD's replicated
+    # pallas_call lowering.
+    with jax.set_mesh(mesh):
+        return run(jax.jit(step_fn), params, opt_state,
+                   shardings={"params": psh, "opt": osh})
 
 
-def run_train(step_fn, cfg, shape, params, opt_state, args, shardings=None):
+def run_train(step_fn, cfg, shape, params, opt_state, *, steps: int,
+              ckpt_dir=None, log_every=None, shardings=None):
     batch_fn = lambda s: lm_batch(cfg, shape, s)
     trainer = Trainer(step_fn, batch_fn, TrainerConfig(
-        total_steps=args.steps, ckpt_dir=args.ckpt_dir,
-        ckpt_every=max(args.steps // 5, 1),
-        log_every=max(args.steps // 10, 1)), shardings=shardings)
-    state = trainer.run(TrainerState(params, opt_state))
-    print(f"done at step {state.step}; stragglers flagged: "
-          f"{len(state.stragglers)}")
+        total_steps=steps, ckpt_dir=ckpt_dir,
+        ckpt_every=max(steps // 5, 1),
+        log_every=log_every or max(steps // 10, 1)), shardings=shardings)
+    return trainer.run(TrainerState(params, opt_state))
 
 
 if __name__ == "__main__":

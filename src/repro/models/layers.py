@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core.policy import Numerics, NumericsPolicy
 from repro.distributed.shard_fused import parallel_matmul
+from repro.kernels.common import rms_norm
 
 
 def init_linear(key, d_in: int, d_out: int, bias: bool = False, scale=None):
@@ -63,8 +64,7 @@ def init_rmsnorm(d: int):
 
 
 def rmsnorm(p, x, eps: float = 1e-5):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps)) * p["g"]
+    return rms_norm(x, p["g"], eps)
 
 
 def init_layernorm(d: int):

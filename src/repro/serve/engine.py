@@ -99,7 +99,8 @@ class ServingEngine:
         """Mesh context for tracing/executing: inside it, mode="amsim"
         dispatches to the sharded fused kernels (shard_fused reads the
         ambient mesh at trace time)."""
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
 
     def _shard_caches(self, caches, batch: int):
         from repro.distributed.sharding import cache_pspecs, to_shardings

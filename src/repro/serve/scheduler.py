@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import time
 from collections import deque
 from typing import Optional
 
@@ -182,6 +183,7 @@ class _Lane:
         self.slot_seq = [0] * engine.capacity  # admission order, for victim pick
         self.decode_traces = [0]
         self.prefill_traces = [0]
+        self.busy_s = 0.0  # host seconds in this lane's prefill/decode calls
         self.caches = None  # allocated lazily (possibly sharded) by engine
         donate = () if jax.default_backend() == "cpu" else (5,)
         self.step = jax.jit(
@@ -384,7 +386,8 @@ class ContinuousBatchingEngine:
 
     # ------------------------------------------------------------ internals
     def _ctx(self):
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        return (jax.set_mesh(self.mesh) if self.mesh is not None
+                else contextlib.nullcontext())
 
     def _resolve_faults(self, lane: _Lane) -> None:
         """Ensure every live slot owns the page its next decode write
@@ -500,16 +503,19 @@ class ContinuousBatchingEngine:
             P = _bucket(m)
             toks = np.zeros((1, P), np.int32)
             toks[0, :m] = cur
+            t0 = time.perf_counter()
             nxt, ok, lane.caches = lane.prefill(
                 self.params, jnp.asarray(toks),
                 jnp.asarray([m], dtype=jnp.int32),
                 jnp.asarray(ctrl.ptab[slot:slot + 1]), lane.caches)
+            nxt, ok = np.asarray(nxt), np.asarray(ok)
+            lane.busy_s += time.perf_counter() - t0
             lane.slot_req[slot] = req
-            if not bool(np.asarray(ok)[0]):
+            if not bool(ok[0]):
                 self._release_slot(lane, slot)
                 self._quarantine(req, finished)
                 continue
-            tok = int(np.asarray(nxt)[0, 0])
+            tok = int(nxt[0, 0])
             req.out.append(tok)
             self._seq += 1
             lane.slot_seq[slot] = self._seq
@@ -526,15 +532,11 @@ class ContinuousBatchingEngine:
         ctrl = lane.ctrl
         if not ctrl.live.any():
             return
-        nxt, ok, lane.caches = lane.step(
-            self.params,
-            jnp.asarray(ctrl.last_tok[:, None]),
-            jnp.asarray(ctrl.live),
-            jnp.asarray(ctrl.start),
-            jnp.asarray(ctrl.ptab),
-            lane.caches)
+        t0 = time.perf_counter()
+        nxt, ok, lane.caches = lane.step(self.params, *self._decode_args(lane))
         nxt = np.asarray(nxt)[:, 0]
         ok = np.asarray(ok)
+        lane.busy_s += time.perf_counter() - t0
         for slot in range(self.capacity):
             if not ctrl.live[slot]:
                 continue
@@ -552,6 +554,19 @@ class ContinuousBatchingEngine:
                 finished.append(req)
             else:
                 self._maybe_release_stale(lane, slot)
+
+    @staticmethod
+    def _decode_args(lane: _Lane) -> tuple:
+        ctrl = lane.ctrl
+        return (jnp.asarray(ctrl.last_tok[:, None]), jnp.asarray(ctrl.live),
+                jnp.asarray(ctrl.start), jnp.asarray(ctrl.ptab), lane.caches)
+
+    def lower_decode(self, tier: str):
+        """The tier's decode step lowered at its lane's shapes
+        (``jax.stages.Lowered``): what one decode tick compiles to."""
+        lane = self._lanes[tier]
+        with self._ctx():
+            return lane.step.lower(self.params, *self._decode_args(lane))
 
     def _maybe_release_stale(self, lane: _Lane, slot: int) -> None:
         """Release leading pages whose every key has slid out of the
@@ -573,6 +588,13 @@ class ContinuousBatchingEngine:
         """Tier name -> number of times its decode step was traced
         (steady-state contract: exactly 1)."""
         return {n: lane.decode_traces[0] for n, lane in self._lanes.items()}
+
+    @property
+    def busy_seconds(self) -> dict:
+        """Tier name -> host seconds spent in its prefill and decode
+        calls, each waited to completion (first calls include
+        compilation)."""
+        return {n: lane.busy_s for n, lane in self._lanes.items()}
 
     @property
     def prefill_trace_counts(self) -> dict:

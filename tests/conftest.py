@@ -12,9 +12,9 @@ import pytest
 if not os.environ.get("REPRO_NO_JAX_CACHE"):
     import jax
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                                     "/tmp/repro_jax_cache"))
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 

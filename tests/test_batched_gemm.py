@@ -181,6 +181,23 @@ def test_autotune_cache_roundtrip(tuned_env):
                                rtol=1e-5, atol=1e-5)
 
 
+def test_autotune_raises_when_every_candidate_fails(tuned_env,
+                                                   monkeypatch):
+    """A sweep that times nothing raises its first failure instead of
+    handing back a default as if it had won; nothing is cached."""
+    errors = iter([RuntimeError("first"), ValueError("second")])
+
+    def fail(fn, iters):
+        raise next(errors)
+
+    monkeypatch.setattr(autotune, "_time_call", fail)
+    with pytest.raises(RuntimeError, match="first"):
+        autotune.autotune("gemm3d", tuned_env["a"], tuned_env["b"],
+                          tuned_env["lut"], 7, candidates=_TINY_CANDIDATES,
+                          iters=1, interpret=True)
+    assert not tuned_env["path"].exists()
+
+
 def test_autotune_corrupt_cache_is_safe(tuned_env):
     tuned_env["path"].write_text("{ not json !!")
     autotune.reload_cache()

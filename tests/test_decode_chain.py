@@ -409,7 +409,8 @@ def test_decode_chain_under_mesh():
     from repro.serve.engine import make_prefill, make_serve_step
 
     cfg = reduced(get_arch("granite-3-2b"), n_layers=1)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh()
     params = init_lm(jax.random.PRNGKey(0), cfg)
     K = cfg.n_heads * cfg.head_dim
 
@@ -417,7 +418,8 @@ def test_decode_chain_under_mesh():
         toks = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 1,
                                   cfg.vocab)
         caches = init_lm_caches(cfg, 2, 32)
-        ctx = mesh_ctx if mesh_ctx is not None else contextlib.nullcontext()
+        ctx = (jax.set_mesh(mesh_ctx) if mesh_ctx is not None
+               else contextlib.nullcontext())
         outs = []
         with ctx:
             nxt, caches = jax.jit(make_prefill(cfg, pol, 32))(
@@ -430,7 +432,7 @@ def test_decode_chain_under_mesh():
 
     pol = NumericsPolicy(mode="amsim", multiplier="mitchell8")
     # guard: under the mesh the sharded per-op dispatch wins...
-    with mesh:
+    with jax.set_mesh(mesh):
         assert not ops.decode_chain_enabled(pol, 2, cfg.d_model, K,
                                             cfg.d_ff)
         # ...until the shard dispatch is killed, then the chain engages.
@@ -481,7 +483,8 @@ def test_overlap_psum_settings():
     from repro.core.policy import NumericsPolicy
     from repro.distributed import shard_fused as sf
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh()
     rng = np.random.default_rng(0)
     pol = NumericsPolicy(mode="amsim", multiplier="mitchell8")
     x = jnp.asarray(rng.standard_normal((4, 8, 256)), jnp.float32)
@@ -490,7 +493,7 @@ def test_overlap_psum_settings():
     def run():
         # fresh closure per call: the overlap setting is read at trace
         # time, so a cached jit would mask the env change.
-        with mesh:
+        with jax.set_mesh(mesh):
             return jax.jit(lambda a, b: sf.row_parallel_matmul(
                 a, b, pol, mesh))(x, w)
 

@@ -46,7 +46,8 @@ def test_param_pspecs_divisibility_all_archs():
     from repro.configs import get_arch
     from repro.distributed.sharding import lm_param_pspecs
     from repro.launch.cells import _params_shapes
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh(2, 4)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     for name in ["granite-3-2b", "qwen1.5-110b", "granite-moe-3b-a800m",
                  "mamba2-780m", "whisper-base", "zamba2-1.2b"]:
@@ -92,13 +93,14 @@ def test_dp_tp_training_matches_single_device():
 
     (l1, g1) = jax.jit(vg)(params, batch)
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh()
     pspecs = lm_param_pspecs(params, cfg, mesh)
     psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                        is_leaf=lambda x: isinstance(x, P))
     params_d = jax.device_put(params, psh)
     batch_d = jax.device_put(batch, NamedSharding(mesh, P("data")))
-    with mesh:
+    with jax.set_mesh(mesh):
         (l2, g2) = jax.jit(vg)(params_d, batch_d)
     np.testing.assert_allclose(float(l1), float(l2), rtol=5e-5)
     # gradient direction identical: normed difference tiny
@@ -130,7 +132,8 @@ def test_paged_pool_sharding_token_parity():
 
     cfg = reduced(get_arch("granite-3-2b"), n_layers=1)
     params = init_lm(jax.random.PRNGKey(7), cfg)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh()
 
     caches = init_paged_lm_caches(cfg, n_pages=9, page_size=4)
     specs = cache_pspecs(caches, mesh, 2)
@@ -168,7 +171,8 @@ def test_compressed_psum_error_feedback():
     from jax.sharding import PartitionSpec as P
     from repro.distributed.compression import compressed_psum, init_ef_state
 
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 1024)) * 0.1
     true_mean = jnp.mean(g, 0)
 

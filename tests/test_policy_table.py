@@ -296,7 +296,8 @@ def test_uniform_table_bit_identical_on_mesh():
     from repro.core.policy import NumericsPolicy, PolicyRule, PolicyTable
     from repro.distributed import shard_fused as sf
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh()
     rng = np.random.default_rng(0)
     bitwise = lambda a, b: bool(jnp.all(a == b))
 
@@ -306,7 +307,7 @@ def test_uniform_table_bit_identical_on_mesh():
         x = jnp.asarray(rng.standard_normal((8, 16, 128)), jnp.float32)
         w1 = jnp.asarray(rng.standard_normal((128, 256)) * 0.1, jnp.float32)
         w2 = jnp.asarray(rng.standard_normal((256, 128)) * 0.1, jnp.float32)
-        with mesh:
+        with jax.set_mesh(mesh):
             of = jax.jit(lambda a, b: sf.column_parallel_matmul(
                 a, b, flat, mesh))(x, w1)
             ou = jax.jit(lambda a, b: sf.column_parallel_matmul(

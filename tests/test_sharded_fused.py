@@ -43,7 +43,8 @@ from repro.core.policy import NumericsPolicy
 from repro.distributed import shard_fused as sf
 from repro.kernels.ops import policy_matmul, policy_attention, approx_conv2d
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_debug_mesh
+mesh = make_debug_mesh()
 rng = np.random.default_rng(0)
 
 def bitwise(a, b):
@@ -73,14 +74,14 @@ def test_sharded_ops_bit_identity_and_pair_vjp():
 
         # ---- column-parallel forward: bitwise
         ref = policy_matmul(x, w1, pol)
-        with mesh:
+        with jax.set_mesh(mesh):
             out = jax.jit(
                 lambda a, b: sf.column_parallel_matmul(a, b, pol, mesh))(x, w1)
         assert bitwise(out, ref), f"{mult}: column fwd not bitwise"
 
         # ---- row-parallel forward: bitwise vs the k-split oracle
         y = policy_matmul(x, w1, pol)
-        with mesh:
+        with jax.set_mesh(mesh):
             out2 = jax.jit(
                 lambda a, b: sf.row_parallel_matmul(a, b, pol, mesh))(y, w2)
         half = y.shape[-1] // 2
@@ -98,7 +99,7 @@ def test_sharded_ops_bit_identity_and_pair_vjp():
         v = jnp.asarray(rng.standard_normal((B, S, KV, dh)), jnp.float32)
         pos = jnp.arange(S, dtype=jnp.int32)
         aref = policy_attention(q, k, v, pos, pos, pol, True, 0)
-        with mesh:
+        with jax.set_mesh(mesh):
             assert sf.attention_supported(pol, mesh, q.shape, k.shape,
                                           causal=True, window=0)
             aout = jax.jit(lambda a, b, c: sf.sharded_attention(
@@ -108,7 +109,7 @@ def test_sharded_ops_bit_identity_and_pair_vjp():
         loss_r = lambda t: jnp.sum(
             policy_attention(*t, pos, pos, pol, True, 0) ** 2)
         gref = jax.jit(jax.grad(loss_r))((q, k, v))
-        with mesh:
+        with jax.set_mesh(mesh):
             gsh = jax.jit(jax.grad(lambda t: jnp.sum(sf.sharded_attention(
                 *t, pos, pos, pol, causal=True, window=0,
                 mesh=mesh) ** 2)))((q, k, v))
@@ -120,13 +121,13 @@ def test_sharded_ops_bit_identity_and_pair_vjp():
         wc = jnp.asarray(rng.standard_normal((3, 3, 16, 32)) * 0.1,
                          jnp.float32)
         cref = approx_conv2d(xc, wc, 1, "SAME", pol)
-        with mesh:
+        with jax.set_mesh(mesh):
             cout = jax.jit(lambda a, b: sf.sharded_conv2d(
                 a, b, 1, "SAME", pol, mesh))(xc, wc)
         assert bitwise(cout, cref), f"{mult}: conv fwd not bitwise"
         closs = lambda t: jnp.sum(approx_conv2d(*t, 1, "SAME", pol) ** 2)
         gcr = jax.jit(jax.grad(closs))((xc, wc))
-        with mesh:
+        with jax.set_mesh(mesh):
             gcs = jax.jit(jax.grad(lambda t: jnp.sum(sf.sharded_conv2d(
                 *t, 1, "SAME", pol, mesh) ** 2)))((xc, wc))
         assert bitwise(gcr[0], gcs[0]), f"{mult}: conv dx not bitwise"
@@ -149,7 +150,7 @@ def test_sharded_ops_bit_identity_and_pair_vjp():
         def pair_ref(x_, w1_, w2_):
             h = policy_matmul(x_, w1_, pol)
             return jnp.sum(policy_matmul(h, w2_, pol) ** 2)
-        with mesh:
+        with jax.set_mesh(mesh):
             gx, g1, g2 = jax.jit(
                 jax.grad(pair_sh, argnums=(0, 1, 2)))(xs, w1, w2)
         rx, r1, r2 = jax.jit(
@@ -178,7 +179,7 @@ def test_kill_switch_and_dispatch_fallback():
     pol = NumericsPolicy(mode="amsim", multiplier="mitchell8")
     q_s, k_s = (8, 16, 4, 32), (8, 16, 2, 32)
     assert sf.active_mesh(pol) is None  # no ambient mesh
-    with mesh:
+    with jax.set_mesh(mesh):
         assert sf.active_mesh(pol) is not None
         assert _derive_dispatch(pol, q_s, k_s, causal=True, window=0) \\
             == "sharded"
@@ -222,10 +223,10 @@ def test_kill_switch_and_dispatch_fallback():
     params_d = jax.device_put(params, to_shardings(
         lm_param_pspecs(params, cfg, mesh), mesh))
     batch_d = jax.device_put(batch, NamedSharding(mesh, P("data")))
-    with mesh:
+    with jax.set_mesh(mesh):
         l_sharded = float(jax.jit(loss)(params_d, batch_d))
     os.environ["REPRO_SHARD_FUSED"] = "0"
-    with mesh:
+    with jax.set_mesh(mesh):
         l_killed = float(jax.jit(loss)(params_d, batch_d))
     assert abs(l_sharded - l_killed) / abs(l_sharded) < 1e-5, \\
         (l_sharded, l_killed)
@@ -270,7 +271,8 @@ def test_train_steps_mesh_loss_parity():
                 opt_state_pspecs(cfg.optimizer, pspecs), mesh))
         fn = jax.jit(step)
         losses = []
-        ctx = mesh if mesh is not None else contextlib.nullcontext()
+        ctx = (jax.set_mesh(mesh) if mesh is not None
+               else contextlib.nullcontext())
         with ctx:
             for s in range(steps):
                 batch = lm_batch(cfg, shape, s)
@@ -282,7 +284,8 @@ def test_train_steps_mesh_loss_parity():
         return losses
 
     l1 = run(2)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh()
     l2 = run(2, mesh)
     print("unsharded", l1)
     print("sharded  ", l2)
@@ -354,6 +357,7 @@ def test_serving_engine_mesh_matches_single():
                                  cfg.vocab, jnp.int32)
     single = ServingEngine(cfg, pol, params, max_len=48)
     toks1 = np.asarray(single.generate(prompts, max_new_tokens=12))
+    from repro.launch.mesh import make_debug_mesh
     mesh = make_debug_mesh(2, 2)
     sharded = ServingEngine(cfg, pol, params, max_len=48, mesh=mesh)
     toks2 = np.asarray(sharded.generate(prompts, max_new_tokens=12))
@@ -374,7 +378,7 @@ def test_sharded_bit_identity_packed_and_afm():
         x = jnp.asarray(rng.standard_normal((8, 16, 128)), jnp.float32)
         w = jnp.asarray(rng.standard_normal((128, 256)) * 0.1, jnp.float32)
         ref = policy_matmul(x, w, pol)
-        with mesh:
+        with jax.set_mesh(mesh):
             out = jax.jit(
                 lambda a, b: sf.column_parallel_matmul(a, b, pol, mesh))(x, w)
         assert bitwise(out, ref), mult
@@ -384,14 +388,14 @@ def test_sharded_bit_identity_packed_and_afm():
         v = jnp.asarray(rng.standard_normal((B, S, KV, dh)), jnp.float32)
         pos = jnp.arange(S, dtype=jnp.int32)
         aref = policy_attention(q, k, v, pos, pos, pol, True, 0)
-        with mesh:
+        with jax.set_mesh(mesh):
             aout = jax.jit(lambda a, b, c: sf.sharded_attention(
                 a, b, c, pos, pos, pol, causal=True, window=0,
                 mesh=mesh))(q, k, v)
         assert bitwise(aout, aref), mult
         gref = jax.jit(jax.grad(lambda t: jnp.sum(
             policy_attention(*t, pos, pos, pol, True, 0) ** 2)))((q, k, v))
-        with mesh:
+        with jax.set_mesh(mesh):
             gsh = jax.jit(jax.grad(lambda t: jnp.sum(sf.sharded_attention(
                 *t, pos, pos, pol, causal=True, window=0,
                 mesh=mesh) ** 2)))((q, k, v))

@@ -183,3 +183,24 @@ def test_serving_engine_greedy_matches_full_forward():
     logits, _, _ = lm_forward(params, prompts, cfg, POL)
     first = jnp.argmax(logits[:, -1], -1)
     np.testing.assert_array_equal(np.asarray(out[:, 0]), np.asarray(first))
+
+
+# ------------------------------------------------------- compile cache
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code;
+    otherwise the cache is the fixed in-checkout ``.jax_cache``."""
+    from repro.checkout import ROOT
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/jax")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/jax"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = str(ROOT / ".jax_cache")
+    assert compile_cache.cache_dir() == want
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
