@@ -65,7 +65,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune
-from repro.kernels.common import (_ceil_to, _CompilerParams,
+from repro.kernels.common import (_ceil_to,
                                   _gather_gemm_tile, _pad_to,
                                   attention_mask, best_chunk, kernel_lut,
                                   lut_spec, resolve_interpret)
@@ -303,7 +303,7 @@ def _attn_impl(q, k, v, q_pos, k_pos, lut, M, *, causal, window, bq, bkv,
         out_specs=pl.BlockSpec((1, rows, dh), lambda bh, iq: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sp * G, dh), jnp.float32),
         scratch_shapes=[pltpu.VMEM((rows, Tp), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

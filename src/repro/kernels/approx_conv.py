@@ -46,7 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune
-from repro.kernels.common import (_ceil_to, _CompilerParams,
+from repro.kernels.common import (_ceil_to,
                                   _gather_gemm_tile, best_chunk, kernel_lut,
                                   lut_spec, resolve_interpret)
 
@@ -166,7 +166,7 @@ def _fused_impl(x, w, lut, M, *, stride, pads, br, bo, chunk, interpret):
         out_specs=pl.BlockSpec((1, br, ow, bo),
                                lambda nn, rr, oo: (nn, rr, 0, oo)),
         out_shape=jax.ShapeDtypeStruct((n, ohp, ow, op), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
@@ -271,7 +271,7 @@ def _dw_impl(x, g, lut, M, *, stride, pads, kh, kw, chunk, interpret):
         out_specs=pl.BlockSpec((1, c, o), lambda kp, nn: (kp, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((kh * kw, c, o), jnp.float32),
         scratch_shapes=[pltpu.VMEM((c, o), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

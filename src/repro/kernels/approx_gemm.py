@@ -48,11 +48,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune
-# Shared bricks live in kernels/common.py (consumed by all three kernel
-# families); re-exported here for backward compatibility.
-from repro.kernels.common import (_ceil128, _CompilerParams,  # noqa: F401
-                                  _gather_gemm_tile, _pad_to, best_chunk,
-                                  kernel_lut, lut_spec, resolve_interpret)
+from repro.kernels.common import (_ceil128, _gather_gemm_tile, _pad_to,
+                                  best_chunk, kernel_lut, lut_spec,
+                                  resolve_interpret)
 
 
 def _amsim_kernel(a_ref, b_ref, lut_ref, o_ref, acc_ref, *,
@@ -131,7 +129,7 @@ def _approx_gemm_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -196,7 +194,7 @@ def _approx_gemm_batched_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret):
         out_specs=pl.BlockSpec((1, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -41,8 +41,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.amsim import amsim_from_entry, mantissa_top
 from repro.core.float_bits import jnp_float
 
-_CompilerParams = pltpu.CompilerParams
-
 LANES = 128
 SUBLANES = 8
 # Widest table whose 2^M x 2^M matrix fits one 128-lane row per A value.

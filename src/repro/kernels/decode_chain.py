@@ -52,7 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import autotune, vmem
 from repro.kernels.approx_attention import NEG_INF, POS_PAD
-from repro.kernels.common import (_ceil128, _ceil_to, _CompilerParams,
+from repro.kernels.common import (_ceil128, _ceil_to,
                                   _gather_gemm_tile, attention_mask,
                                   best_chunk, kernel_lut, lut_spec,
                                   resolve_interpret, rmsnorm_expr)
@@ -163,7 +163,7 @@ def _fused_qkv_impl(x, g1, wq, wk, wv, lut, M, *, eps, bn, chunk, dp,
         out_shape=[jax.ShapeDtypeStruct((rows, w.shape[1]), jnp.float32)
                    for w in (wq, wk, wv)],
         scratch_shapes=[pltpu.VMEM((rows, dp), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, g1, wq, wk, wv, lut)
@@ -308,7 +308,7 @@ def _fused_out_mlp_impl(xres, attn, g2, wo, wg, wu, wd, biases, lut, M, *,
                         pltpu.VMEM((rows, d), jnp.float32),
                         pltpu.VMEM((rows, dp2), jnp.float32),
                         pltpu.VMEM((rows, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xres, attn, g2, wo, wg, wu, wd, *biases, lut)
@@ -532,7 +532,7 @@ def _fused_attn_out_mlp_impl(xres, qg, kt, vt, mask, live, g2, wo, wg, wu,
                         pltpu.VMEM((rows, d), jnp.float32),
                         pltpu.VMEM((rows, dp2), jnp.float32),
                         pltpu.VMEM((rows, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xres, qg, kt, vt, mask, live, g2, wo, wg, wu, wd, *biases, lut)
@@ -682,7 +682,7 @@ def _fused_wo_norm_impl(xres, attn, g2, wo, biases, lut, M, *, eps, bko,
         out_shape=[jax.ShapeDtypeStruct((rows, d), jnp.float32),
                    jax.ShapeDtypeStruct((rows, d), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xres, attn, g2, wo, *biases, lut)
@@ -774,7 +774,7 @@ def _fused_moe_ffn_impl(h, wg, wu, wd, lut, M, *, bf, chunk_g, chunk_d,
         # Both axes sequential: the accumulator scratch is re-zeroed at
         # each expert's first slice, which requires the row-major
         # (expert-outer) iteration order.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(h, wg, wu, wd, lut)
