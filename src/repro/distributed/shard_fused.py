@@ -71,7 +71,8 @@ from jax.sharding import AbstractMesh, Mesh, PartitionSpec as P
 
 from repro.core.policy import Numerics, NumericsPolicy
 from repro.kernels.ops import (_conv_bwd, _conv_fwd_impl, _matmul_nograd,
-                               fused_attention_enabled, policy_attention)
+                               fused_attention_enabled, gemm_tag,
+                               policy_attention)
 
 _KINDS = ("column", "row")
 
@@ -144,7 +145,7 @@ def _swap(x):
     return jnp.swapaxes(x, -1, -2)
 
 
-def _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry):
+def _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry, site):
     """Weight gradient shared by both matmul roles: fold every batch row
     into the contraction (dw = x_flat^T @ g_flat, ops._mm_bwd's weight
     formula) per shard under the resolved ``dw`` leaf, psum over the
@@ -154,7 +155,8 @@ def _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry):
 
     def dw_body(xs, gs):
         k, n = xs.shape[-1], gs.shape[-1]
-        dws = _matmul_nograd(xs.reshape(-1, k).T, gs.reshape(-1, n), leaf_dw)
+        dws = _matmul_nograd(xs.reshape(-1, k).T, gs.reshape(-1, n), leaf_dw,
+                             gemm_tag(site, "dw"))
         return jax.lax.psum(dws, daxes) if bentry is not None else dws
 
     return shard_map(dw_body, mesh=mesh, in_specs=(sx, so), out_specs=sw,
@@ -207,7 +209,8 @@ def _col_fwd(x, w, policy, mesh, site=None):
     leaf = policy.resolve(site)
     bentry = _batch_entry(mesh, x.shape[0]) if x.ndim > 2 else None
     sx, sw, so = _col_specs(mesh, x.ndim, bentry)
-    out = shard_map(lambda xs, ws: _matmul_nograd(xs, ws, leaf),
+    tag = gemm_tag(site, "fwd")
+    out = shard_map(lambda xs, ws: _matmul_nograd(xs, ws, leaf, tag),
                     mesh=mesh, in_specs=(sx, sw), out_specs=so,
                     check_vma=False)(x, w)
     return out, (x, w)
@@ -223,11 +226,13 @@ def _col_bwd(policy, mesh, site, res, g):
 
     def dx_body(gs, ws):
         # contraction over the model-sharded n: partial per shard -> psum
-        return jax.lax.psum(_matmul_nograd(gs, _swap(ws), leaf_dx), "model")
+        return jax.lax.psum(
+            _matmul_nograd(gs, _swap(ws), leaf_dx, gemm_tag(site, "dx")),
+            "model")
 
     dx = shard_map(dx_body, mesh=mesh, in_specs=(so, sw), out_specs=sx,
                    check_vma=False)(g, w)
-    dw = _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry)
+    dw = _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry, site)
     return dx.reshape(x.shape), dw.reshape(w.shape)
 
 
@@ -327,12 +332,13 @@ def _row_fwd(x, w, policy, mesh, site=None):
     bentry = _batch_entry(mesh, x.shape[0]) if x.ndim > 2 else None
     sx, sw, so = _row_specs(mesh, x.ndim, bentry)
     overlap = _overlap_setting(w.shape[-1])
+    tag = gemm_tag(site, "fwd")
 
     def body(xs, ws):
         if overlap == "ring":
-            return _ring_psum(_matmul_nograd(xs, ws, leaf), _msize(mesh))
+            return _ring_psum(_matmul_nograd(xs, ws, leaf, tag), _msize(mesh))
         if overlap == 1:
-            return jax.lax.psum(_matmul_nograd(xs, ws, leaf), "model")
+            return jax.lax.psum(_matmul_nograd(xs, ws, leaf, tag), "model")
         # Chunked psum: GEMM chunk i's reduce is issued as soon as its
         # columns finish, so XLA's async collectives overlap chunk i's
         # wire time with chunk i+1's compute (and, across layers, the
@@ -340,7 +346,8 @@ def _row_fwd(x, w, policy, mesh, site=None):
         step = ws.shape[-1] // overlap
         outs = [
             jax.lax.psum(
-                _matmul_nograd(xs, ws[..., i * step:(i + 1) * step], leaf),
+                _matmul_nograd(xs, ws[..., i * step:(i + 1) * step], leaf,
+                               tag),
                 "model")
             for i in range(overlap)
         ]
@@ -361,11 +368,11 @@ def _row_bwd(policy, mesh, site, res, g):
 
     def dx_body(gs, ws):
         # w's k rows live on this shard: dx block is shard-local, exact
-        return _matmul_nograd(gs, _swap(ws), leaf_dx)
+        return _matmul_nograd(gs, _swap(ws), leaf_dx, gemm_tag(site, "dx"))
 
     dx = shard_map(dx_body, mesh=mesh, in_specs=(so, sw), out_specs=sx,
                    check_vma=False)(g, w)
-    dw = _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry)
+    dw = _dw_psum(x, g, leaf_dw, mesh, sx, so, sw, bentry, site)
     return dx.reshape(x.shape), dw.reshape(w.shape)
 
 

@@ -68,7 +68,8 @@ from repro.kernels import autotune
 from repro.kernels.common import (_ceil_to,
                                   _gather_gemm_tile, _pad_to,
                                   attention_mask, best_chunk, kernel_lut,
-                                  lut_spec, resolve_interpret)
+                                  lut_spec, resolve_interpret,
+                                  tagged_pallas_call)
 
 NEG_INF = -1e30          # matches models/attention.py's mask fill
 POS_PAD = -(2 ** 30)     # padding sentinel: same "unwritten" marker as
@@ -285,7 +286,9 @@ def _attn_impl(q, k, v, q_pos, k_pos, lut, M, *, causal, window, bq, bkv,
     # Expanded to the kernel's gather rows (each q row G times), as int32.
     mask = jnp.repeat(mask, G, axis=1).astype(jnp.int32)  # (Bm, Sp*G, Tp)
     rows = bq * G
-    out = pl.pallas_call(
+    # Tagged as the forward: the backward recomputes through the einsum.
+    out = tagged_pallas_call(
+        "_attn_impl", ("attn", "fwd"),
         functools.partial(_attn_kernel, M=M, bkv=bkv, chunk_d=chunk_d,
                           chunk_t=chunk_t, kv_heads=KV, per_row=per_row),
         grid=(BH, nq),

@@ -48,7 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import autotune
 from repro.kernels.common import (_ceil_to,
                                   _gather_gemm_tile, best_chunk, kernel_lut,
-                                  lut_spec, resolve_interpret)
+                                  lut_spec, resolve_interpret,
+                                  tagged_pallas_call)
 
 # Static-unroll / VMEM guards for the fused path (see fused_supported).
 MAX_TAPS = 64                      # kh*kw positions unrolled in-kernel
@@ -134,8 +135,9 @@ def _amconv_kernel(x_ref, w_ref, lut_ref, o_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "M", "stride", "pads", "br", "bo", "chunk", "interpret"))
-def _fused_impl(x, w, lut, M, *, stride, pads, br, bo, chunk, interpret):
+    "M", "stride", "pads", "br", "bo", "chunk", "interpret", "pass_"))
+def _fused_impl(x, w, lut, M, *, stride, pads, br, bo, chunk, interpret,
+                pass_="fwd"):
     n, h, wid, c = x.shape
     kh, kw, _, o = w.shape
     pt, pb, pl_, pr = pads
@@ -154,7 +156,8 @@ def _fused_impl(x, w, lut, M, *, stride, pads, br, bo, chunk, interpret):
     wpad = jnp.pad(w.astype(jnp.float32),
                    ((0, 0), (0, 0), (0, 0), (0, op - o)))
     grid = (n, ohp // br, op // bo)
-    out = pl.pallas_call(
+    out = tagged_pallas_call(
+        "_fused_impl", ("conv", pass_),
         functools.partial(_amconv_kernel, M=M, stride=stride, kh=kh, kw=kw,
                           chunk=chunk),
         grid=grid,
@@ -187,6 +190,7 @@ def approx_conv2d_fused(
     chunk: int | None = None,
     interpret: bool | None = None,
     mult: str | None = None,
+    pass_: str = "fwd",
 ):
     """Implicit-GEMM LUT-simulated conv2d: x (N,H,W,C), w (KH,KW,C,O) ->
     (N,OH,OW,O), NHWC, FP32 accumulate.
@@ -194,7 +198,8 @@ def approx_conv2d_fused(
     ``padding`` is "SAME"/"VALID" or an explicit (top, bottom, left,
     right) tuple (the data-gradient pass uses the latter).  ``lut`` may
     be canonical uint32 or packed uint16 (dtype-detected).  Unset
-    br/bo/chunk come from the autotuner's conv2d namespace.
+    br/bo/chunk come from the autotuner's conv2d namespace.  ``pass_``
+    ("fwd" or "dx") tags the launch (``common.tagged_pallas_call``).
     """
     n, h, wid, c = x.shape
     kh, kw, cw, o = w.shape
@@ -216,7 +221,7 @@ def approx_conv2d_fused(
     bo = max(1, min(bo, o))
     chunk = best_chunk(chunk, c)
     return _fused_impl(x, w, lut, M, stride=stride, pads=pads, br=br,
-                       bo=bo, chunk=chunk, interpret=interpret)
+                       bo=bo, chunk=chunk, interpret=interpret, pass_=pass_)
 
 
 # ----------------------------------------------------------- weight gradient
@@ -259,7 +264,8 @@ def _dw_impl(x, g, lut, M, *, stride, pads, kh, kw, chunk, interpret):
     xpad = xpad[:, :hp, :wp, :]
     g = g.astype(jnp.float32)
     grid = (kh * kw, n)
-    out = pl.pallas_call(
+    out = tagged_pallas_call(
+        "_dw_impl", ("conv", "dw"),
         functools.partial(_amconv_dw_kernel, M=M, stride=stride, kw=kw,
                           chunk=chunk),
         grid=grid,

@@ -50,7 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import autotune
 from repro.kernels.common import (_ceil128, _gather_gemm_tile, _pad_to,
                                   best_chunk, kernel_lut, lut_spec,
-                                  resolve_interpret)
+                                  resolve_interpret, tagged_pallas_call)
 
 
 def _amsim_kernel(a_ref, b_ref, lut_ref, o_ref, acc_ref, *,
@@ -108,9 +108,10 @@ def _resolve(kind, m, k, n, M, batch, bm, bn, bk, chunk, interpret,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("M", "bm", "bn", "bk", "chunk", "interpret")
-)
-def _approx_gemm_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret):
+    jax.jit,
+    static_argnames=("M", "bm", "bn", "bk", "chunk", "interpret", "tag"))
+def _approx_gemm_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret,
+                      tag=None):
     m, k = a.shape
     n = b.shape[1]
     a = _pad_to(a.astype(jnp.float32), bm, bk)
@@ -118,7 +119,8 @@ def _approx_gemm_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret):
     mp, kp = a.shape
     np_ = b.shape[1]
     grid = (mp // bm, np_ // bn, kp // bk)
-    out = pl.pallas_call(
+    out = tagged_pallas_call(
+        "_approx_gemm_impl", tag,
         functools.partial(_amsim_kernel, M=M, chunk=chunk),
         grid=grid,
         in_specs=[
@@ -149,11 +151,15 @@ def approx_gemm(
     chunk: int | None = None,
     interpret: bool | None = None,
     mult: str | None = None,
+    tag: tuple[str, str] | None = None,
 ):
     """LUT-simulated GEMM: (m, k) @ (k, n) -> (m, n), FP32 accumulate.
 
     ``mult`` is the resolved multiplier name, used only to key the
     autotune cache (per-multiplier tilings under mixed policy tables).
+    ``tag`` is the launch's ``(site, pass)`` (``common.tagged_pallas_call``):
+    a static argument, so same-shaped calls at two sites trace apart and
+    each keeps its own tag.
 
     ``lut`` may be the canonical uint32 table or the packed uint16 one
     (detected by dtype).  Zero padding is safe: AMSim flushes
@@ -167,13 +173,14 @@ def approx_gemm(
         "gemm2d", m, k, n, M, 0, bm, bn, bk, chunk, interpret, mult)
     lut = kernel_lut(lut, M, interpret)
     return _approx_gemm_impl(a, b, lut, M, bm=bm, bn=bn, bk=bk,
-                             chunk=chunk, interpret=interpret)
+                             chunk=chunk, interpret=interpret, tag=tag)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("M", "bm", "bn", "bk", "chunk", "interpret")
-)
-def _approx_gemm_batched_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret):
+    jax.jit,
+    static_argnames=("M", "bm", "bn", "bk", "chunk", "interpret", "tag"))
+def _approx_gemm_batched_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret,
+                              tag=None):
     B, m, k = a.shape
     n = b.shape[2]
     a = _pad_to(a.astype(jnp.float32), bm, bk)
@@ -181,7 +188,8 @@ def _approx_gemm_batched_impl(a, b, lut, M, *, bm, bn, bk, chunk, interpret):
     mp, kp = a.shape[1:]
     np_ = b.shape[2]
     grid = (B, mp // bm, np_ // bn, kp // bk)
-    out = pl.pallas_call(
+    out = tagged_pallas_call(
+        "_approx_gemm_batched_impl", tag,
         functools.partial(_amsim_kernel_batched, M=M, chunk=chunk),
         grid=grid,
         in_specs=[
@@ -215,6 +223,7 @@ def approx_gemm_batched(
     chunk: int | None = None,
     interpret: bool | None = None,
     mult: str | None = None,
+    tag: tuple[str, str] | None = None,
 ):
     """Batched LUT-simulated GEMM: (B, m, k) @ (B, k, n) -> (B, m, n).
 
@@ -222,6 +231,7 @@ def approx_gemm_batched(
     dimension with the LUT broadcast across it, replacing the
     vmap-over-pallas_call / lax.map fallbacks.  Accepts uint32 or packed
     uint16 LUTs (dtype-detected); accumulation is FP32 (paper §VII).
+    ``tag`` as for :func:`approx_gemm`.
     """
     assert a.ndim == 3 and b.ndim == 3, (a.shape, b.shape)
     B, m, k = a.shape
@@ -231,4 +241,5 @@ def approx_gemm_batched(
         "gemm3d", m, k, n, M, B, bm, bn, bk, chunk, interpret, mult)
     lut = kernel_lut(lut, M, interpret)
     return _approx_gemm_batched_impl(a, b, lut, M, bm=bm, bn=bn, bk=bk,
-                                     chunk=chunk, interpret=interpret)
+                                     chunk=chunk, interpret=interpret,
+                                     tag=tag)

@@ -98,6 +98,33 @@ def kernel_lut(lut, M: int, interpret: bool):
     return lut
 
 
+def tagged_pallas_call(name: str, tag, kernel, **kwargs):
+    """``pl.pallas_call(kernel, **kwargs)`` for a launch tagged
+    ``(site, pass)``: the GEMM site it computes ("qkv", "head", "attn",
+    ...) and its pass ("fwd", "dx" or "dw"); ``name`` is the jitted impl
+    that launches it.  ``tag`` None launches untagged.
+
+    The tag travels two ways.  ``metadata`` puts it in the custom call's
+    ``frontend_attributes={kernel_metadata={...}}``, which the profiler's
+    op text carries.  The call runs in the name scope
+    ``<name>.<site>.<pass>``: XLA names the custom call after its
+    innermost scope, and names a ``kind=kCustom`` fusion built around the
+    call (a scanned layer's weight gradient written in place into the
+    stacked gradient) after the call, and such a fusion keeps no
+    ``frontend_attributes``.  The name's base, what a trace names the
+    kernel by, stays ``name``."""
+    if tag is None:
+        return pl.pallas_call(kernel, **kwargs)
+    site, pass_ = tag
+    call = pl.pallas_call(kernel, metadata={"site": site, "pass": pass_},
+                          **kwargs)
+
+    def launch(*args):
+        with jax.named_scope(f"{name}.{site}.{pass_}"):
+            return call(*args)
+    return launch
+
+
 def lut_spec(lut) -> pl.BlockSpec:
     """Whole-LUT BlockSpec with a constant index map: one VMEM-resident
     table broadcast across any grid."""

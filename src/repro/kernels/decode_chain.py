@@ -55,7 +55,8 @@ from repro.kernels.approx_attention import NEG_INF, POS_PAD
 from repro.kernels.common import (_ceil128, _ceil_to,
                                   _gather_gemm_tile, attention_mask,
                                   best_chunk, kernel_lut, lut_spec,
-                                  resolve_interpret, rmsnorm_expr)
+                                  resolve_interpret, rmsnorm_expr,
+                                  tagged_pallas_call)
 # The fold derivation and the VMEM budget live in kernels/vmem.py (the
 # budget model also prices the MoE and attention-fused launch variants);
 # re-exported here because this module defined them historically.
@@ -140,7 +141,8 @@ def _fused_qkv_impl(x, g1, wq, wk, wv, lut, M, *, eps, bn, chunk, dp,
     cq = lambda j: jnp.clip(j, 0, nq - 1)
     ck = lambda j: jnp.clip(j - nq, 0, nk - 1)
     cv = lambda j: jnp.clip(j - nq - nk, 0, nv - 1)
-    outs = pl.pallas_call(
+    outs = tagged_pallas_call(
+        "_fused_qkv_impl", ("qkv_norm", "fwd"),
         functools.partial(_qkv_kernel, M=M, eps=eps, chunk=chunk,
                           nq=nq, nk=nk, nv=nv, dp=dp),
         grid=(nq + nk + nv,),
@@ -285,7 +287,8 @@ def _fused_out_mlp_impl(xres, attn, g2, wo, wg, wu, wd, biases, lut, M, *,
     co = lambda t: jnp.clip(t, 0, n_wo - 1)
     cf = lambda t: jnp.clip(t - n_wo, 0, n_ff - 1)
     bias_specs = [pl.BlockSpec((d,), lambda t: (0,)) for _ in biases]
-    out = pl.pallas_call(
+    out = tagged_pallas_call(
+        "_fused_out_mlp_impl", ("out_mlp", "fwd"),
         functools.partial(_out_mlp_kernel, M=M, eps=eps, n_wo=n_wo,
                           n_ff=n_ff, chunk_o=chunk_o, chunk_g=chunk_g,
                           chunk_d=chunk_d, d=d, dp2=dp2, has_bo=has_bo,
@@ -502,7 +505,8 @@ def _fused_attn_out_mlp_impl(xres, qg, kt, vt, mask, live, g2, wo, wg, wu,
     co = lambda t: jnp.clip(t, 0, n_wo - 1)
     cf = lambda t: jnp.clip(t - n_wo, 0, n_ff - 1)
     bias_specs = [pl.BlockSpec((d,), lambda t: (0,)) for _ in biases]
-    out = pl.pallas_call(
+    out = tagged_pallas_call(
+        "_fused_attn_out_mlp_impl", ("attn_out_mlp", "fwd"),
         functools.partial(_attn_out_mlp_kernel, M=M, eps=eps, n_wo=n_wo,
                           n_ff=n_ff, chunk_qk=chunk_qk, chunk_t=chunk_t,
                           chunk_o=chunk_o, chunk_g=chunk_g, chunk_d=chunk_d,
@@ -665,7 +669,8 @@ def _fused_wo_norm_impl(xres, attn, g2, wo, biases, lut, M, *, eps, bko,
     n_wo = attn.shape[1] // bko
     co = lambda t: jnp.clip(t, 0, n_wo - 1)
     bias_specs = [pl.BlockSpec((d,), lambda t: (0,)) for _ in biases]
-    x1, h = pl.pallas_call(
+    x1, h = tagged_pallas_call(
+        "_fused_wo_norm_impl", ("wo_norm", "fwd"),
         functools.partial(_wo_norm_kernel, M=M, eps=eps, n_wo=n_wo,
                           chunk_o=chunk_o, has_bo=has_bo),
         grid=(n_wo,),
@@ -754,7 +759,8 @@ def _fused_moe_ffn_impl(h, wg, wu, wd, lut, M, *, bf, chunk_g, chunk_d,
     E, C, dgp = h.shape
     d = wd.shape[2]
     n_ff = wg.shape[2] // bf
-    out = pl.pallas_call(
+    out = tagged_pallas_call(
+        "_fused_moe_ffn_impl", ("moe_ffn", "fwd"),
         functools.partial(_moe_ffn_kernel, M=M, n_ff=n_ff, chunk_g=chunk_g,
                           chunk_d=chunk_d),
         grid=(E, n_ff),

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, PartitionSpec as P
 
+from repro import obs
 from repro.core import faults
 from repro.core.float_bits import jnp_truncate_mantissa, jnp_round_mantissa
 from repro.core.lutgen import get_lut, get_packed_lut
@@ -117,22 +118,30 @@ def _oracle_lut(mult):
                               packed=False, mult=mult.name)
 
 
+def gemm_tag(site: str | None, pass_: str) -> tuple[str, str]:
+    """The ``(site, pass)`` a GEMM's kernel launch is tagged with
+    (``common.tagged_pallas_call``); an unlabelled call resolves the
+    policy's default rule and is tagged "default"."""
+    return ("default" if site is None else site, pass_)
+
+
 # One mode-routing table shared by the 2-D and batched engines (the two
 # differ only in which Pallas kernel ``amsim`` lowers to — the jnp
 # oracle modes are batch-generalised already).  Each entry maps a mode
-# to ``impl(a, b, mult, kernel)``; ``kernel`` is the engine's amsim
-# kernel, with the resolved multiplier name keying the autotune cache.
+# to ``impl(a, b, mult, kernel, tag)``; ``kernel`` is the engine's amsim
+# kernel, with the resolved multiplier name keying the autotune cache
+# and ``tag`` its launch's (site, pass).
 _GEMM_MODES = {
-    "amsim": lambda a, b, mult, kernel: _replicated(
+    "amsim": lambda a, b, mult, kernel, tag: _replicated(
         lambda a_, b_: kernel(a_, b_, _amsim_lut(mult), mult.mantissa_bits,
-                              mult=mult.name), a, b),
-    "amsim_jnp": lambda a, b, mult, kernel: ref_amsim_gemm(
+                              mult=mult.name, tag=tag), a, b),
+    "amsim_jnp": lambda a, b, mult, kernel, tag: ref_amsim_gemm(
         a, b, jnp.asarray(_oracle_lut(mult)), mult.mantissa_bits),
-    "direct": lambda a, b, mult, kernel: ref_direct_gemm(a, b, mult),
+    "direct": lambda a, b, mult, kernel, tag: ref_direct_gemm(a, b, mult),
 }
 
 
-def _gemm_dispatch(a, b, policy: NumericsPolicy, kernel):
+def _gemm_dispatch(a, b, policy: NumericsPolicy, kernel, tag):
     """Route one GEMM through the mode table under a *leaf* policy."""
     mode = policy.mode
     if mode == "native" or policy.is_native:
@@ -140,15 +149,15 @@ def _gemm_dispatch(a, b, policy: NumericsPolicy, kernel):
     impl = _GEMM_MODES.get(mode)
     if impl is None:
         raise ValueError(f"unknown mode {mode!r}")
-    return impl(a, b, get_multiplier(policy.multiplier), kernel)
+    return impl(a, b, get_multiplier(policy.multiplier), kernel, tag)
 
 
-def _gemm2d(a, b, policy: NumericsPolicy):
+def _gemm2d(a, b, policy: NumericsPolicy, tag=None):
     """(m, k) @ (k, n) -> (m, n) under the policy's numerics. f32 accumulate."""
-    return _gemm_dispatch(a, b, policy, approx_gemm)
+    return _gemm_dispatch(a, b, policy, approx_gemm, tag)
 
 
-def _gemm_batched(a, b, policy: NumericsPolicy):
+def _gemm_batched(a, b, policy: NumericsPolicy, tag=None):
     """(B, m, k) @ (B, k, n) -> (B, m, n): the batched engine.
 
     ``amsim`` lowers to the single 4-D-grid Pallas kernel (LUT broadcast
@@ -157,11 +166,12 @@ def _gemm_batched(a, b, policy: NumericsPolicy):
     kernel launch covers the whole batch in every attention score/value
     contraction, MoE expert stack, and decode step.
     """
-    return _gemm_dispatch(a, b, policy, approx_gemm_batched)
+    return _gemm_dispatch(a, b, policy, approx_gemm_batched, tag)
 
 
-def _matmul_nograd(a, b, policy: NumericsPolicy):
+def _matmul_nograd(a, b, policy: NumericsPolicy, tag=None):
     """Batched matmul (..., m, k) @ broadcastable (..., k, n), no custom grad.
+    ``tag`` is the ``(site, pass)`` its kernel launches carry (``gemm_tag``).
 
     Three supported layouts (covering every call site in models/):
       * b is 2-D (weight matmul): fold a's batch into m — single GEMM.
@@ -192,11 +202,11 @@ def _matmul_nograd(a, b, policy: NumericsPolicy):
         return jnp.matmul(rnd(a, ma), rnd(b, mb),
                           preferred_element_type=jnp.float32)
     if a.ndim == 2 and b.ndim == 2:
-        return _gemm2d(a, b, policy)
+        return _gemm2d(a, b, policy, tag)
     if b.ndim == 2:
         batch = a.shape[:-2]
         m, k = a.shape[-2:]
-        out = _gemm2d(a.reshape(-1, k), b, policy)
+        out = _gemm2d(a.reshape(-1, k), b, policy, tag)
         return out.reshape(*batch, m, b.shape[-1])
     if a.shape[:-2] == b.shape[:-2]:
         # Equal batch dims (attention scores/values, MoE expert stacks):
@@ -207,13 +217,13 @@ def _matmul_nograd(a, b, policy: NumericsPolicy):
         n = b.shape[-1]
         af = a.reshape((-1, m, k))
         bf = b.reshape((-1, k, n))
-        out = _gemm_batched(af, bf, policy)
+        out = _gemm_batched(af, bf, policy, tag)
         return out.reshape(*batch, m, n)
     # General broadcasting: broadcast batch dims then recurse.
     batch = jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
     a = jnp.broadcast_to(a, batch + a.shape[-2:])
     b = jnp.broadcast_to(b, batch + b.shape[-2:])
-    return _matmul_nograd(a, b, policy)
+    return _matmul_nograd(a, b, policy, tag)
 
 
 # =====================================================================
@@ -225,11 +235,12 @@ def policy_matmul(a, b, policy: Numerics, site: str | None = None):
     """Differentiable batched matmul under the numerics ``policy``
     resolves at ``site`` (flat policy or per-site table): forward under
     the ``fwd`` leaf, backward GEMMs under the ``dx``/``dw`` leaves."""
-    return _matmul_nograd(a, b, policy.resolve(site))
+    return _matmul_nograd(a, b, policy.resolve(site), gemm_tag(site, "fwd"))
 
 
 def _mm_fwd(a, b, policy, site=None):
-    return _matmul_nograd(a, b, policy.resolve(site)), (a, b)
+    return (_matmul_nograd(a, b, policy.resolve(site), gemm_tag(site, "fwd")),
+            (a, b))
 
 
 # Sites whose second operand is a *parameter* even when it is a stacked
@@ -251,7 +262,7 @@ def _mm_bwd(policy, site, res, g):
     g = g.astype(jnp.float32)
     swap = lambda x: jnp.swapaxes(x, -1, -2)
     # dA = g @ B^T  — same batch layout as forward.
-    da = _matmul_nograd(g, swap(b), leaf_dx)
+    da = _matmul_nograd(g, swap(b), leaf_dx, gemm_tag(site, "dx"))
     extra = da.ndim - a.ndim
     if extra > 0:
         da = da.sum(axis=tuple(range(extra)))
@@ -260,12 +271,14 @@ def _mm_bwd(policy, site, res, g):
         # dB = A_flat^T @ g_flat, one large GEMM (paper Fig. 8(b)).
         k = a.shape[-1]
         n = g.shape[-1]
-        db = _matmul_nograd(a.reshape(-1, k).T, g.reshape(-1, n), leaf_dw)
+        db = _matmul_nograd(a.reshape(-1, k).T, g.reshape(-1, n), leaf_dw,
+                            gemm_tag(site, "dw"))
     else:
         # b is batched: an activation (attention-style contraction, dx)
         # unless the site stacks its weights 3-D (MoE expert banks, dw).
-        leaf_db = leaf_dw if site in _STACKED_WEIGHT_SITES else leaf_dx
-        db = _matmul_nograd(swap(a), g, leaf_db)
+        pass_db = "dw" if site in _STACKED_WEIGHT_SITES else "dx"
+        leaf_db = leaf_dw if pass_db == "dw" else leaf_dx
+        db = _matmul_nograd(swap(a), g, leaf_db, gemm_tag(site, pass_db))
         # Sum over broadcasted batch dims of b.
         extra = db.ndim - b.ndim
         if extra > 0:
@@ -446,7 +459,8 @@ def _conv_bwd(stride, padding, policy, res, g):
     else:
         g2 = g.reshape(n * oh * ow, o).astype(jnp.float32)
         cols = ref_im2col(x, kh, kw, stride, pad)    # (N*OH*OW, KH*KW*C)
-        dw = _matmul_nograd(cols.T, g2, leaf_dw).reshape(kh, kw, c, o)
+        dw = _matmul_nograd(cols.T, g2, leaf_dw,
+                            gemm_tag("conv", "dw")).reshape(kh, kw, c, o)
 
     # --- preceding-layer gradient (Fig. 8c): full correlation of the
     # dilated+padded error with the reversed-transposed weights.
@@ -472,11 +486,12 @@ def _conv_bwd(stride, padding, policy, res, g):
         dx = _replicated(
             lambda g_, w_: approx_conv2d_fused(
                 g_, w_, _amsim_lut(mx), mx.mantissa_bits, stride=1,
-                padding=(pt, pb, pl_, pr), mult=mx.name), gd, wrt4)
+                padding=(pt, pb, pl_, pr), mult=mx.name, pass_="dx"),
+            gd, wrt4)
     else:
         gcols = ref_im2col(gd, kh, kw, 1, (pt, pb, pl_, pr))  # (N*H*W, KH*KW*O)
-        dx = _matmul_nograd(gcols, wrt4.reshape(-1, c), leaf_dx).reshape(
-            n, h, wid, c)
+        dx = _matmul_nograd(gcols, wrt4.reshape(-1, c), leaf_dx,
+                            gemm_tag("conv", "dx")).reshape(n, h, wid, c)
     return dx, dw
 
 
@@ -689,22 +704,25 @@ def decode_chain_enabled(policy: Numerics, rows: int, d: int,
     pass the VMEM budget model (kernels/vmem.py).  ``moe=True`` prices
     the MoE back half (qkv + wo->norm launches; the expert-bank FFN
     launch has its own guard, :func:`decode_moe_ffn_enabled`) instead of
-    the dense out-mlp launch."""
+    the dense out-mlp launch.  Each decision is recorded, with its
+    reason, in ``obs.routes`` under "decode_chain.*"."""
     leaf = decode_chain_leaf(policy)
     if leaf is None or leaf.mode != "amsim" or leaf.is_native:
-        return False
+        return obs.route("decode_chain", "refused.policy")
     if os.environ.get("REPRO_DECODE_FUSED", "1").lower() in ("0", "false"):
-        return False
+        return obs.route("decode_chain", "refused.env")
     from repro.distributed import shard_fused  # lazy: circular import
     if shard_fused.active_mesh(leaf) is not None:
-        return False
+        return obs.route("decode_chain", "refused.mesh")
     from repro.kernels import vmem
     mult = get_multiplier(leaf.multiplier)
     if moe:
-        return vmem.moe_chain_fits(rows, d, k_attn, mult.mantissa_bits,
+        fits = vmem.moe_chain_fits(rows, d, k_attn, mult.mantissa_bits,
                                    mult=mult.name)
-    return vmem.chain_fits(rows, d, k_attn, d_ff,
-                           mult.mantissa_bits, mult=mult.name)
+    else:
+        fits = vmem.chain_fits(rows, d, k_attn, d_ff,
+                               mult.mantissa_bits, mult=mult.name)
+    return obs.route("decode_chain", "taken" if fits else "refused.vmem")
 
 
 _MOE_FFN_SITES = ("wg", "wu", "wd")

@@ -38,11 +38,22 @@ Scheduling (deterministic, greedy)
 Prefill runs per admission at bucketed (power-of-two) padded length with
 the true length as a *traced* argument, so ragged prompts cost at most
 one trace per bucket, not one per length.
+
+Observation (docs/serving.md, "Observing the engine")
+    Each tick is an ``engine.tick`` span holding ``engine.admit`` (one
+    ``engine.prefill`` per admission), ``engine.faults``,
+    ``engine.decode`` and ``engine.bookkeeping``; every prefill and
+    decode call splits into ``.upload``, ``.launch`` and ``.wait``.
+    ``counters`` counts admissions, preemptions, quarantines, prefill
+    tokens true and padded, prefills per bucket, decode ticks and rows,
+    traces and busy seconds per tier.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Optional
@@ -51,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.core.policy import Numerics
 from repro.models.transformer import init_paged_lm_caches, lm_forward
@@ -82,8 +94,19 @@ def _strip_control(caches):
     return {"pool_k": caches["pool_k"], "pool_v": caches["pool_v"]}
 
 
+def _count_traces(fn, counters, key: str):
+    """``fn`` adding one to ``counters[key]`` each time it runs: under
+    ``jax.jit`` the Python body runs only when it is traced.  The wrapper
+    keeps ``fn``'s name, so the compiled module keeps its name too."""
+    @functools.wraps(fn)
+    def traced(*args):
+        counters[key] += 1
+        return fn(*args)
+    return traced
+
+
 def make_paged_prefill(cfg: ArchConfig, policy: Numerics,
-                       window: Optional[int] = None, trace_counter=None):
+                       window: Optional[int] = None):
     def paged_prefill(params, tokens, true_len, ptab, caches):
         """tokens (B, P) right-padded, true_len (B,) traced, ptab
         (B, n_ptab) -> (next_token (B, 1), ok (B,) bool, caches).
@@ -97,8 +120,6 @@ def make_paged_prefill(cfg: ArchConfig, policy: Numerics,
         trash page, and causal masking keeps real queries from seeing
         anything at or past their own position.
         """
-        if trace_counter is not None:
-            trace_counter[0] += 1
         B = tokens.shape[0]
         merged = _merge_control(caches, ptab,
                                 jnp.ones((B,), bool),
@@ -114,7 +135,7 @@ def make_paged_prefill(cfg: ArchConfig, policy: Numerics,
 
 
 def make_paged_serve_step(cfg: ArchConfig, policy: Numerics,
-                          window: Optional[int] = None, trace_counter=None):
+                          window: Optional[int] = None):
     def paged_serve_step(params, tokens, live, start, ptab, caches):
         """One decode step over every slot of a lane: tokens (C, 1),
         live (C,), start (C,), ptab (C, n_ptab) -> (next (C, 1),
@@ -124,8 +145,6 @@ def make_paged_serve_step(cfg: ArchConfig, policy: Numerics,
         Dead slots ride along at fixed shape: their writes are routed to
         the trash page and their outputs discarded by the scheduler.
         """
-        if trace_counter is not None:
-            trace_counter[0] += 1
         merged = _merge_control(caches, ptab, live, start)
         logits, merged, _ = lm_forward(params, tokens, cfg, policy,
                                        caches=merged, window=window)
@@ -181,19 +200,18 @@ class _Lane:
         self.slot_req: list[Optional[Request]] = [None] * engine.capacity
         self.slot_pages: list[dict] = [{} for _ in range(engine.capacity)]
         self.slot_seq = [0] * engine.capacity  # admission order, for victim pick
-        self.decode_traces = [0]
-        self.prefill_traces = [0]
-        self.busy_s = 0.0  # host seconds in this lane's prefill/decode calls
         self.caches = None  # allocated lazily (possibly sharded) by engine
         donate = () if jax.default_backend() == "cpu" else (5,)
         self.step = jax.jit(
-            make_paged_serve_step(engine.cfg, policy, engine.window,
-                                  self.decode_traces),
+            _count_traces(make_paged_serve_step(engine.cfg, policy,
+                                                engine.window),
+                          engine.counters, f"decode.traces.{name}"),
             donate_argnums=donate)
         donate = () if jax.default_backend() == "cpu" else (4,)
         self.prefill = jax.jit(
-            make_paged_prefill(engine.cfg, policy, engine.window,
-                               self.prefill_traces),
+            _count_traces(make_paged_prefill(engine.cfg, policy,
+                                             engine.window),
+                          engine.counters, f"prefill.traces.{name}"),
             donate_argnums=donate)
 
 
@@ -221,6 +239,10 @@ class ContinuousBatchingEngine:
         approximate datapath, docs/robustness.md) it is re-admitted
         once, from scratch, on the mapped tier; without a mapping — or
         on a second fault — it retires with ``status="fault"``.
+
+    ``counters`` (a ``collections.Counter``) holds the engine's running totals,
+    docs/serving.md lists them; ``busy_seconds``, ``decode_trace_counts``
+    and ``prefill_trace_counts`` are per-tier views of it.
     """
 
     def __init__(self, cfg: ArchConfig, tiers, params, *,
@@ -245,6 +267,7 @@ class ContinuousBatchingEngine:
             self.params = jax.device_put(
                 params, to_shardings(lm_param_pspecs(params, cfg, mesh),
                                      mesh))
+        self.counters = collections.Counter()
         self._lanes = {name: _Lane(self, name, pol)
                        for name, pol in tiers.items()}
         with self._ctx():
@@ -321,20 +344,23 @@ class ContinuousBatchingEngine:
         (including early retirements — check ``Request.status``)."""
         finished: list[Request] = []
         self.tick += 1
-        with self._ctx():
+        with obs.span("engine.tick", tick=self.tick), self._ctx():
             self._expire_queued(finished)
-            self._admit(finished)
+            with obs.span("engine.admit"):
+                self._admit(finished)
             # Faults AFTER admission: a freshly admitted slot whose prompt
             # exactly fills its pages needs the next page before its first
             # decode write, or the KV lands in the trash page and is lost.
-            for lane in self._lanes.values():
-                self._resolve_faults(lane)
+            with obs.span("engine.faults"):
+                for lane in self._lanes.values():
+                    self._resolve_faults(lane)
             for lane in self._lanes.values():
                 self._decode(lane, finished)
-            for lane in self._lanes.values():
-                self._expire_resident(lane, finished)
-        for req in finished:
-            self.finished[req.rid] = req
+            with obs.span("engine.bookkeeping"):
+                for lane in self._lanes.values():
+                    self._expire_resident(lane, finished)
+            for req in finished:
+                self.finished[req.rid] = req
         return finished
 
     def _progress(self):
@@ -420,6 +446,7 @@ class ContinuousBatchingEngine:
         req = lane.slot_req[slot]
         self._release_slot(lane, slot)
         req.preemptions += 1
+        self.counters["preemptions"] += 1
         self._queue.appendleft(req)
 
     def _quarantine(self, req: Request, finished: list) -> None:
@@ -428,6 +455,7 @@ class ContinuousBatchingEngine:
         mapping and a first fault, restart the request from scratch on
         the stronger tier (its earlier tokens came off the faulty
         datapath — discard them); otherwise retire with status="fault"."""
+        self.counters["quarantines"] += 1
         dst = self.fault_retier.get(req.tier)
         if dst is not None and req.retiers == 0:
             req.retiers += 1
@@ -503,13 +531,22 @@ class ContinuousBatchingEngine:
             P = _bucket(m)
             toks = np.zeros((1, P), np.int32)
             toks[0, :m] = cur
-            t0 = time.perf_counter()
-            nxt, ok, lane.caches = lane.prefill(
-                self.params, jnp.asarray(toks),
-                jnp.asarray([m], dtype=jnp.int32),
-                jnp.asarray(ctrl.ptab[slot:slot + 1]), lane.caches)
-            nxt, ok = np.asarray(nxt), np.asarray(ok)
-            lane.busy_s += time.perf_counter() - t0
+            with obs.span("engine.prefill", bucket=P, tokens=m):
+                t0 = time.perf_counter()
+                with obs.span("engine.prefill.upload"):
+                    args = (jnp.asarray(toks),
+                            jnp.asarray([m], dtype=jnp.int32),
+                            jnp.asarray(ctrl.ptab[slot:slot + 1]))
+                with obs.span("engine.prefill.launch"):
+                    nxt, ok, lane.caches = lane.prefill(self.params, *args,
+                                                        lane.caches)
+                with obs.span("engine.prefill.wait"):
+                    nxt, ok = np.asarray(nxt), np.asarray(ok)
+                self.counters[f"busy_s.{lane.name}"] += \
+                    time.perf_counter() - t0
+            self.counters.update({"admissions": 1, "prefill.tokens": m,
+                                  "prefill.bucket_tokens": P,
+                                  f"prefill.bucket.{P}": 1})
             lane.slot_req[slot] = req
             if not bool(ok[0]):
                 self._release_slot(lane, slot)
@@ -530,30 +567,40 @@ class ContinuousBatchingEngine:
 
     def _decode(self, lane: _Lane, finished: list) -> None:
         ctrl = lane.ctrl
-        if not ctrl.live.any():
+        live = int(ctrl.live.sum())
+        if not live:
             return
-        t0 = time.perf_counter()
-        nxt, ok, lane.caches = lane.step(self.params, *self._decode_args(lane))
-        nxt = np.asarray(nxt)[:, 0]
-        ok = np.asarray(ok)
-        lane.busy_s += time.perf_counter() - t0
-        for slot in range(self.capacity):
-            if not ctrl.live[slot]:
-                continue
-            req = lane.slot_req[slot]
-            if not ok[slot]:
-                self._release_slot(lane, slot)
-                self._quarantine(req, finished)
-                continue
-            tok = int(nxt[slot])
-            req.out.append(tok)
-            ctrl.start[slot] += 1
-            ctrl.last_tok[slot] = tok
-            if req.done:
-                self._release_slot(lane, slot)
-                finished.append(req)
-            else:
-                self._maybe_release_stale(lane, slot)
+        with obs.span("engine.decode", rows=self.capacity, live=live):
+            t0 = time.perf_counter()
+            with obs.span("engine.decode.upload"):
+                args = self._decode_args(lane)
+            with obs.span("engine.decode.launch"):
+                nxt, ok, lane.caches = lane.step(self.params, *args)
+            with obs.span("engine.decode.wait"):
+                nxt = np.asarray(nxt)[:, 0]
+                ok = np.asarray(ok)
+            self.counters[f"busy_s.{lane.name}"] += time.perf_counter() - t0
+        self.counters.update({"decode.ticks": 1,
+                              "decode.rows_run": self.capacity,
+                              "decode.rows_live": live})
+        with obs.span("engine.bookkeeping"):
+            for slot in range(self.capacity):
+                if not ctrl.live[slot]:
+                    continue
+                req = lane.slot_req[slot]
+                if not ok[slot]:
+                    self._release_slot(lane, slot)
+                    self._quarantine(req, finished)
+                    continue
+                tok = int(nxt[slot])
+                req.out.append(tok)
+                ctrl.start[slot] += 1
+                ctrl.last_tok[slot] = tok
+                if req.done:
+                    self._release_slot(lane, slot)
+                    finished.append(req)
+                else:
+                    self._maybe_release_stale(lane, slot)
 
     @staticmethod
     def _decode_args(lane: _Lane) -> tuple:
@@ -587,19 +634,19 @@ class ContinuousBatchingEngine:
     def decode_trace_counts(self) -> dict:
         """Tier name -> number of times its decode step was traced
         (steady-state contract: exactly 1)."""
-        return {n: lane.decode_traces[0] for n, lane in self._lanes.items()}
+        return {n: self.counters[f"decode.traces.{n}"] for n in self._lanes}
 
     @property
     def busy_seconds(self) -> dict:
         """Tier name -> host seconds spent in its prefill and decode
         calls, each waited to completion (first calls include
         compilation)."""
-        return {n: lane.busy_s for n, lane in self._lanes.items()}
+        return {n: float(self.counters[f"busy_s.{n}"]) for n in self._lanes}
 
     @property
     def prefill_trace_counts(self) -> dict:
         """Tier name -> prefill traces (at most one per prompt bucket)."""
-        return {n: lane.prefill_traces[0] for n, lane in self._lanes.items()}
+        return {n: self.counters[f"prefill.traces.{n}"] for n in self._lanes}
 
     @property
     def n_free_pages(self) -> dict:

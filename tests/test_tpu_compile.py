@@ -8,7 +8,14 @@ chip — an op with no TPU lowering, an unaligned slice, more VMEM than a
 kernel may use.  The topology is described inside a module fixture,
 never at import: only one process may hold the TPU library, and the
 tests run under several workers.
+
+Every launch carries its ``(site, pass)`` tag, in the custom call's
+``kernel_metadata`` and in its instruction name, and keeps the base name
+the benchmark's trace reduction (bench/trace.py) knows the kernel by.
 """
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -16,6 +23,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.lutgen import get_packed_lut
 from repro.core.multipliers import get_multiplier
+from repro.core.policy import NumericsPolicy
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench import trace as T  # noqa: E402
+from bench.program_trace import kernel_tag  # noqa: E402
 
 MULT = get_multiplier("afm16")
 M = MULT.mantissa_bits
@@ -48,6 +60,26 @@ def _compile(fn, sharding, *shapes):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     return text
+
+
+def _launches(text):
+    """Every Pallas launch in compiled HLO text: each tpu_custom_call, and
+    each ``kind=kCustom`` fusion XLA built around one.  A tag's JSON
+    spreads over several lines, so continuation lines are joined to the
+    instruction they belong to."""
+    instrs = []
+    for line in text.splitlines():
+        line = line.strip().removeprefix("ROOT ")
+        if line.startswith("%") or not instrs:
+            instrs.append(line)
+        else:
+            instrs[-1] += "\n" + line
+    return [i for i in instrs if T.is_kernel(i)]
+
+
+def _kernels(text):
+    """(name in a trace, tag) of every Pallas launch, sorted."""
+    return sorted((T.kernel_name(i), kernel_tag(i)) for i in _launches(text))
 
 
 def _lut_shape():
@@ -95,37 +127,41 @@ def test_decode_attention_paged(one_chip):
 
 def test_decode_chain_qkv(one_chip):
     from repro.kernels.decode_chain import fused_qkv_norm
-    _compile(lambda x, g, wq, wk, wv, lut: fused_qkv_norm(
+    text = _compile(lambda x, g, wq, wk, wv, lut: fused_qkv_norm(
                  x, g, wq, wk, wv, lut, M, eps=1e-5, interpret=False),
              one_chip, ((4, D), f32), ((D,), f32), ((D, H * DH), f32),
              ((D, KV * DH), f32), ((D, KV * DH), f32), _lut_shape())
+    assert _kernels(text) == [("fused_qkv_impl", ("qkv_norm", "fwd"))]
 
 
 def test_decode_chain_out_mlp(one_chip):
     from repro.kernels.decode_chain import fused_out_mlp
-    _compile(lambda x, at, g, wo, wg, wu, wd, lut: fused_out_mlp(
+    text = _compile(lambda x, at, g, wo, wg, wu, wd, lut: fused_out_mlp(
                  x, at, g, wo, wg, wu, wd, lut, M, eps=1e-5,
                  interpret=False),
              one_chip, ((4, D), f32), ((4, H * DH), f32), ((D,), f32),
              ((H * DH, D), f32), ((D, FF), f32), ((D, FF), f32),
              ((FF, D), f32), _lut_shape())
+    assert _kernels(text) == [("fused_out_mlp_impl", ("out_mlp", "fwd"))]
 
 
 @pytest.mark.parametrize("cin,cout,stride", [(16, 16, 1), (16, 32, 2)])
 def test_conv_forward(one_chip, cin, cout, stride):
     from repro.kernels.approx_conv import approx_conv2d_fused
-    _compile(lambda x, w, lut: approx_conv2d_fused(
-                 x, w, lut, M, stride=stride, interpret=False),
-             one_chip, ((8, 32, 32, cin), f32), ((3, 3, cin, cout), f32),
-             _lut_shape())
+    text = _compile(lambda x, w, lut: approx_conv2d_fused(
+                        x, w, lut, M, stride=stride, interpret=False),
+                    one_chip, ((8, 32, 32, cin), f32),
+                    ((3, 3, cin, cout), f32), _lut_shape())
+    assert _kernels(text) == [("fused_impl", ("conv", "fwd"))]
 
 
 def test_conv_weight_gradient(one_chip):
     from repro.kernels.approx_conv import approx_conv2d_dw
-    _compile(lambda x, g, lut: approx_conv2d_dw(
-                 x, g, lut, M, kh=3, kw=3, interpret=False),
-             one_chip, ((8, 32, 32, 16), f32), ((8, 32, 32, 16), f32),
-             _lut_shape())
+    text = _compile(lambda x, g, lut: approx_conv2d_dw(
+                        x, g, lut, M, kh=3, kw=3, interpret=False),
+                    one_chip, ((8, 32, 32, 16), f32), ((8, 32, 32, 16), f32),
+                    _lut_shape())
+    assert _kernels(text) == [("dw_impl", ("conv", "dw"))]
 
 
 def test_wide_table_raises_on_chip(one_chip):
@@ -169,3 +205,75 @@ def test_decode_chain_moe_launches(one_chip):
                  h, wg, wu, wd, lut, M, interpret=False),
              one_chip, ((experts, cap, d), f32), ((experts, d, ff), f32),
              ((experts, d, ff), f32), ((experts, ff, d), f32), _lut_shape())
+
+
+AFM16 = NumericsPolicy(mode="amsim", multiplier="afm16")
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Kernels reached through the policy seam compile for the chip, not
+    in interpret mode, while the host's backend is the CPU."""
+    import repro.kernels.approx_gemm as gemm
+    monkeypatch.setattr(gemm, "resolve_interpret", lambda interpret: False)
+
+
+def test_gemm_launches_carry_site_and_pass(one_chip, compiled_kernels):
+    """The tied head's forward, dx and dw GEMMs: three launches of the one
+    jitted impl, each tagged with its own pass."""
+    from repro.kernels.ops import policy_matmul
+
+    def step(x, w):
+        return jax.value_and_grad(
+            lambda x, w: policy_matmul(x, w, AFM16, "head").sum(),
+            argnums=(0, 1))(x, w)
+    text = _compile(step, one_chip, ((128, D), f32), ((D, 512), f32))
+    assert _kernels(text) == [("approx_gemm_impl", ("head", p))
+                              for p in ("dw", "dx", "fwd")]
+
+
+def test_batched_gemm_launches_carry_site_and_pass(one_chip,
+                                                   compiled_kernels):
+    """An attention score einsum and its VJP: both operands' gradients of
+    an activation-activation product are dx."""
+    from repro.kernels.ops import policy_einsum
+
+    def step(q, k):
+        return jax.value_and_grad(
+            lambda q, k: policy_einsum("bqd,btd->bqt", q, k, AFM16,
+                                       "attn_score").sum(),
+            argnums=(0, 1))(q, k)
+    text = _compile(step, one_chip, ((8, 128, DH), f32), ((8, 128, DH), f32))
+    assert _kernels(text) == [("approx_gemm_batched_impl", ("attn_score", p))
+                              for p in ("dx", "dx", "fwd")]
+
+
+def test_attention_launch_is_tagged(one_chip):
+    from repro.kernels.approx_attention import approx_attention_fused
+    S = 256
+    text = _compile(lambda q, k, v, lut: approx_attention_fused(
+                        q, k, v, jnp.arange(S), jnp.arange(S), lut, M,
+                        causal=True, interpret=False),
+                    one_chip, ((1, S, H, DH), f32), ((1, S, KV, DH), f32),
+                    ((1, S, KV, DH), f32), _lut_shape())
+    assert _kernels(text) == [("attn_impl", ("attn", "fwd"))]
+
+
+def test_fused_weight_gradient_keeps_its_tag(one_chip, compiled_kernels):
+    """A scanned layer's weight gradient is written in place into the
+    stacked gradient: XLA builds a ``kind=kCustom`` fusion around the
+    kernel, which keeps no ``frontend_attributes``.  Its name carries the
+    tag."""
+    from repro.kernels.ops import policy_matmul
+
+    def loss(ws, x):
+        layer = lambda h, w: (policy_matmul(h, w, AFM16, "wo"), None)
+        return jax.lax.scan(layer, x, ws)[0].sum()
+    text = _compile(jax.grad(loss), one_chip, ((2, 256, 256), f32),
+                    ((128, 256), f32))
+    fused = [i for i in _launches(text) if "kind=kCustom" in i]
+    assert fused and all("kernel_metadata" not in i for i in fused)
+    assert {kernel_tag(i) for i in fused} == {("wo", "dw")}
+    kernels = _kernels(text)
+    assert {name for name, _ in kernels} == {"approx_gemm_impl"}
+    assert {tag for _, tag in kernels} == {("wo", p) for p in ("fwd", "dx", "dw")}
