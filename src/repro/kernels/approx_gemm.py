@@ -11,8 +11,8 @@ TPU adaptation of the paper's custom CUDA GEMM with AMSim device function:
     (bm x bk and bk x bn operand tiles, bm x bn f32 accumulator scratch),
     the TPU analogue of the paper's 16x16 shared-memory tiles;
   * the inner product is computed on the **VPU** (vector unit): a table
-    gather + integer sign/exponent arithmetic per element, accumulated in
-    FP32.  A lookup-based multiply cannot enter the MXU (systolic array
+    gather and two multiplies per element (``common.factored_product``),
+    accumulated in FP32.  A lookup-based multiply cannot enter the MXU (systolic array
     of fused multipliers) — this is the structural cost of *simulating*
     non-native hardware, identical in kind to the paper's GEMM running
     ~2x slower than cuBLAS (Fig. 6).  The point preserved from the paper

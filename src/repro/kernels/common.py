@@ -12,16 +12,22 @@ The brick is written in the form Mosaic lowers for the TPU (docs/kernels.md,
 "The TPU-legal brick"), and the CPU tests run the same code in Pallas
 interpret mode:
 
-  * the contraction walks one k at a time.  A's column k is broadcast
-    across lanes by a lane gather of its aligned 128-lane block; B's row
-    k is a dynamic sublane load.  Both operands sit in scoped VMEM
-    scratch, so every dynamic slice is a ref slice;
+  * the operands are decoded once per tile, into VMEM scratch: each
+    word keeps its sign and exponent, with its table index (top M
+    mantissa bits) in the cleared mantissa field.  The contraction walks
+    one k at a time: A's column k is broadcast across lanes by a lane
+    gather of its aligned 128-lane block; B's row k is a dynamic sublane
+    load, so every dynamic slice is a ref slice;
   * for tables with M <= 7 the 2^M x 2^M LUT is a (128, 128) matrix.
-    A one-hot matmul on the MXU selects the rows for A's top-M bits (exact:
-    every output sums one non-zero entry, and each entry is a byte held
-    exactly in bf16), then a lane gather by B's top-M bits picks the
-    columns.  Canonical uint32 tables carry three byte planes, packed
-    uint16 tables one;
+    A one-hot matmul on the MXU selects the rows for A's indices (exact:
+    every output sums one non-zero entry, held exactly in bf16), then a
+    lane gather by B's indices picks the columns.  A packed uint16 table
+    becomes one plane of float values V = 2^carry * (1 + mnt / 2^M), and
+    each product is ``factored_product``: the operands' signed powers of
+    two times V, flushed below 2^-126 — Alg. 2 bit for bit on finite
+    words.  A tile holding an inf or NaN word, and a canonical uint32
+    table (three byte planes), take the integer form instead,
+    ``amsim_from_entry``;
   * wider tables (the M > 7 cross-format families) keep the 1-D table
     and ``jnp.take``, which only interpret mode lowers: ``kernel_lut``
     raises for them when compiling for the chip;
@@ -32,17 +38,24 @@ interpret mode:
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core.amsim import amsim_from_entry, mantissa_top
-from repro.core.float_bits import jnp_float
+from repro.core.float_bits import EXP_MASK, SIGN_MASK, jnp_float
 
 LANES = 128
 SUBLANES = 8
+_SIGN_EXP = SIGN_MASK | EXP_MASK
+# k-steps of a chunk the factored brick runs as one straight-line block.
+_UNROLL = 8
+_MIN_NORMAL = float(np.finfo(np.float32).tiny)     # 2^-126
 # Widest table whose 2^M x 2^M matrix fits one 128-lane row per A value.
 MATRIX_MAX_M = 7
 
@@ -64,23 +77,39 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 
 def lut_matrix(lut, M: int):
-    """The (128, planes*128) bf16 byte-plane matrix of a 1-D LUT.
+    """The (128, planes*128) bf16 matrix of a 1-D LUT.
 
     Row = A's top-M mantissa bits, column = B's (``amsim._amsim``'s
-    index, reshaped), zero-padded to 128 x 128.  Packed uint16 entries
-    are below 2^(M+1) <= 256 and need one plane; canonical uint32
-    entries need bits 0-23 (carry and mantissa), three planes.
+    index, reshaped), zero-padded to 128 x 128.  A packed uint16 table
+    becomes one plane of values ``V = 2^carry * (1 + mnt / 2^M)``, carry
+    and mantissa decoded as ``amsim_from_entry`` decodes a packed entry:
+    at most 8 significant bits, exact in bf16.  A canonical uint32 table
+    becomes three byte planes of its entries' bits 0-23.
     """
     n = 1 << M
-    packed = lut.dtype == jnp.uint16
     t = jnp.asarray(lut).astype(jnp.int32).reshape(n, n)
-    t = jnp.pad(t, ((0, LANES - n), (0, LANES - n)))
-    planes = [(t >> (8 * p)) & 0xFF for p in range(1 if packed else 3)]
+    if lut.dtype == jnp.uint16:
+        mnt = (t & (n - 1)).astype(jnp.float32)
+        planes = [(1.0 + mnt / n) * (1 + ((t >> M) & 1)).astype(jnp.float32)]
+    else:
+        planes = [((t >> (8 * p)) & 0xFF).astype(jnp.float32)
+                  for p in range(3)]
+    planes = [jnp.pad(v, ((0, LANES - n), (0, LANES - n))) for v in planes]
     return jnp.concatenate(planes, axis=1).astype(jnp.bfloat16)
 
 
+def table_form(lut) -> str:
+    """How the brick multiplies with a ``kernel_lut`` operand:
+    "factored" (the float-valued table of a packed LUT),
+    "integer.canonical" (byte planes) or "integer.wide" (1-D)."""
+    if lut.ndim == 1:
+        return "integer.wide"
+    return "factored" if lut.shape[1] == LANES else "integer.canonical"
+
+
 def kernel_lut(lut, M: int, interpret: bool):
-    """The LUT operand a kernel hands its brick.
+    """The LUT operand a kernel hands its brick, one per launch; records
+    the brick's path for it (``obs.routes["lut_brick.<form>"]``).
 
     M <= 7: the ``lut_matrix`` form, which lowers on the chip.  Wider
     tables stay 1-D for the interpret-mode gather and raise when the
@@ -89,12 +118,13 @@ def kernel_lut(lut, M: int, interpret: bool):
     lut = jnp.asarray(lut)
     lut = lut if lut.dtype == jnp.uint16 else lut.astype(jnp.uint32)
     if M <= MATRIX_MAX_M:
-        return lut_matrix(lut, M)
-    if not interpret:
+        lut = lut_matrix(lut, M)
+    elif not interpret:
         raise NotImplementedError(
             f"M={M} LUTs have no TPU lowering (the LUT brick takes "
             f"M <= {MATRIX_MAX_M}); run this multiplier in interpret mode "
             f"or under mode='amsim_jnp'")
+    obs.route("lut_brick", table_form(lut))
     return lut
 
 
@@ -131,33 +161,62 @@ def lut_spec(lut) -> pl.BlockSpec:
     return pl.BlockSpec(lut.shape, lambda *_: (0,) * lut.ndim)
 
 
-def _lut_is_packed(lut) -> bool:
-    if lut.ndim == 2:
-        return lut.shape[1] == LANES
-    return lut.dtype == jnp.uint16
+def _decode(x, M: int):
+    """Operand words as the brick keeps them: sign and exponent of each
+    f32 word, with its table index (the top M mantissa bits) in the
+    cleared mantissa field's low bits."""
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return (u & _SIGN_EXP) | mantissa_top(u, M, jnp)
 
 
-def _lookup(lut, ua, ub, M: int, bmp: int, bnp: int):
-    """LUT entries (bmp, bnp) for A words ``ua`` (bmp, 128; every row
-    constant) against B words ``ub`` (bmp, bnp; every column constant)."""
-    at = mantissa_top(ua, M, jnp).astype(jnp.int32)
-    bt = mantissa_top(ub, M, jnp).astype(jnp.int32)
-    if lut.ndim == 1:  # wide table: interpret mode only (kernel_lut)
-        idx = (_tile_lanes(at, bnp) << M) | bt
-        return jnp.take(lut, idx).astype(jnp.uint32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bmp, LANES), 1)
+def _index(w, M: int):
+    """A decoded word's table index."""
+    return (w & np.uint32((1 << M) - 1)).astype(jnp.int32)
+
+
+def _scale(w):
+    """A decoded word's signed power of two, 2^(e-127), or ±0 where its
+    exponent field is 0."""
+    return jax.lax.bitcast_convert_type(w & _SIGN_EXP, jnp.float32)
+
+
+def factored_product(fa, fb, v, xp=jnp):
+    """Alg. 2's product of two finite words from their ``_scale`` values
+    and the table value ``V``; ``xp`` is jnp or numpy.  Bitwise
+    ``amsim._amsim`` but for the sign of a zero, which no fold from +0.0
+    shows: ``t`` is an exact power of two, 0 or inf; ``t * V`` is exact
+    and overflows where Alg. 2 saturates; ``|t| < 2^-126`` is Alg. 2's
+    flush, with or without hardware flush-to-zero (docs/kernels.md)."""
+    t = fa * fb
+    return xp.where(xp.abs(t) < _MIN_NORMAL, xp.float32(0.0), t * v)
+
+
+def _any_nonfinite(x):
+    """Whether any word of ``x`` has exponent field 255 (inf or NaN)."""
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.any((u & EXP_MASK) == EXP_MASK)
+
+
+def _rows(lut, at):
+    """The table rows of A indices ``at`` (bmp, 128; every row constant),
+    selected by a one-hot matmul (exact: each output sums one non-zero
+    entry): f32 values of the float-valued table, int32 byte planes of a
+    canonical one."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, at.shape, 1)
     onehot = (at == lane).astype(jnp.bfloat16)
     rows = jnp.dot(onehot, lut, preferred_element_type=jnp.float32)
-    rows = rows.astype(jnp.int32)             # exact: bytes < 256
+    return rows if lut.shape[1] == LANES else rows.astype(jnp.int32)
+
+
+def _pick(rows, bt):
+    """Each row's entry at B indices ``bt`` (bmp, 128; every column
+    constant): a lane gather per plane, planes OR-ed back together."""
     entry = None
-    for p in range(lut.shape[1] // LANES):
-        plane = rows[:, p * LANES:(p + 1) * LANES]
-        cols = [jnp.take_along_axis(plane, bt[:, q:q + LANES], axis=1,
-                                    mode="promise_in_bounds")
-                for q in range(0, bnp, LANES)]
-        e = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+    for p in range(rows.shape[1] // LANES):
+        e = jnp.take_along_axis(rows[:, p * LANES:(p + 1) * LANES], bt,
+                                axis=1, mode="promise_in_bounds")
         entry = e if entry is None else entry | (e << (8 * p))
-    return entry.astype(jnp.uint32)
+    return entry
 
 
 def _tile_lanes(x, width: int):
@@ -174,36 +233,91 @@ def _gather_gemm_tile(a, b, lut, acc, *, M: int, chunk: int):
     the LUT operand of ``kernel_lut``; ``chunk`` must divide bk (see
     :func:`best_chunk`).  Fold: acc + (((+0 + p_0) + p_1) + ...) per
     chunk of ``chunk`` consecutive k, chunks in order.
+
+    The operands are decoded once, into the scratch (``_decode``).  With
+    the float-valued table each product is :func:`factored_product`,
+    exact on finite words; a tile holding an inf or NaN word instead runs
+    the integer form, ``amsim_from_entry``, as canonical and wide tables
+    always do.
     """
     bm, bk = a.shape
     bn = b.shape[1]
     bmp, bnp = _ceil_to(bm, SUBLANES), _ceil128(bn)
-    packed = _lut_is_packed(lut)
+    form = table_form(lut)
+    packed = lut.ndim == 1 and lut.dtype == jnp.uint16
 
     def run(a_scr, b_scr):
-        a_scr[:bm, :bk] = jax.lax.bitcast_convert_type(a, jnp.uint32)
-        b_scr[:bk, :bn] = jax.lax.bitcast_convert_type(b, jnp.uint32)
+        a_scr[:bm, :bk] = _decode(a, M)
+        b_scr[:bk, :bn] = _decode(b, M)
 
-        def product(k):
+        def a_col(k):
             blk = a_scr[:, pl.ds(pl.multiple_of(k // LANES * LANES, LANES),
                                  LANES)]
-            ua = jnp.take_along_axis(
+            return jnp.take_along_axis(
                 blk, jnp.full((bmp, LANES), k % LANES, jnp.int32), axis=1,
                 mode="promise_in_bounds")
-            # Broadcast B's row before slicing it: Mosaic lowers a
-            # sublane broadcast of the whole row, not of its lane slices.
-            ub = jnp.broadcast_to(b_scr[pl.ds(k, 1), :], (bmp, bnp))
-            entry = _lookup(lut, ua, ub, M, bmp, bnp)
+
+        def b_row(k, sublanes):
+            # B's row k on ``sublanes`` sublanes.  Mosaic lowers a sublane
+            # broadcast of the whole row, and a lane slice of a value
+            # computed from one, not a lane slice of the broadcast itself.
+            return jnp.broadcast_to(b_scr[pl.ds(k, 1), :], (sublanes, bnp))
+
+        def tile_rows(x):
+            """(8, 128) -> (bmp, 128) by repeating the vreg."""
+            return jnp.concatenate([x] * (bmp // SUBLANES), axis=0)
+
+        def factored(k):
+            wa = a_col(k)
+            rows, fa = _rows(lut, _index(wa, M)), _scale(wa)
+            # B's row is decoded on one vreg of sublanes, not on bmp.
+            wb = b_row(k, SUBLANES)
+            bt, fb = _index(wb, M), _scale(wb)
+            out = []
+            for q in range(0, bnp, LANES):
+                out.append(factored_product(
+                    fa, tile_rows(fb[:, q:q + LANES]),
+                    _pick(rows, tile_rows(bt[:, q:q + LANES]))))
+            return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+        def integer(k):
+            wa = a_col(k)
+            wb = b_row(k, bmp)
+            at, bt = _index(wa, M), _index(wb, M)
+            if form == "integer.wide":  # interpret mode only (kernel_lut)
+                entry = jnp.take(lut, (_tile_lanes(at, bnp) << M) | bt)
+            else:
+                rows = _rows(lut, at)
+                entry = [_pick(rows, bt[:, q:q + LANES])
+                         for q in range(0, bnp, LANES)]
+                entry = (entry[0] if len(entry) == 1
+                         else jnp.concatenate(entry, axis=1))
+                if form == "factored":  # V's f32 bits less 1.0's
+                    entry = (jax.lax.bitcast_convert_type(entry, jnp.uint32)
+                             - np.uint32(0x3F80_0000))
             return jnp_float(amsim_from_entry(
-                _tile_lanes(ua, bnp), ub, entry, M, jnp, packed))
+                _tile_lanes(wa, bnp), wb, entry.astype(jnp.uint32), M, jnp,
+                packed))
 
-        def chunk_sum(i, acc):
-            s = jax.lax.fori_loop(
-                0, chunk, lambda j, s: s + product(i * chunk + j),
-                jnp.zeros((bmp, bnp), jnp.float32))
-            return acc + s[:bm, :bn]
+        def fold(product, unroll=1):
+            def steps(i, acc):
+                # ``unroll`` consecutive k run as one straight-line block,
+                # in order: the compiler overlaps their loads, gathers and
+                # matmuls, and pushes the table into the MXU once a block.
+                def step(g, s):
+                    for j in range(unroll):
+                        s = s + product(i * chunk + g * unroll + j)
+                    return s
+                s = jax.lax.fori_loop(0, chunk // unroll, step,
+                                      jnp.zeros((bmp, bnp), jnp.float32))
+                return acc + s[:bm, :bn]
+            return lambda acc: jax.lax.fori_loop(0, bk // chunk, steps, acc)
 
-        return jax.lax.fori_loop(0, bk // chunk, chunk_sum, acc)
+        if form != "factored":
+            return fold(integer)(acc)
+        return jax.lax.cond(_any_nonfinite(a) | _any_nonfinite(b),
+                            fold(integer),
+                            fold(factored, math.gcd(chunk, _UNROLL)), acc)
 
     return pl.run_scoped(run,
                          pltpu.VMEM((bmp, _ceil128(bk)), jnp.uint32),

@@ -117,6 +117,26 @@ def test_decode_chain_route_records_why_it_was_refused(monkeypatch):
         "decode_chain.refused.policy": 1, "decode_chain.refused.env": 1})
 
 
+def test_lut_brick_route_records_the_path_each_launch_compiles():
+    """An afm16 GEMM at granite-3-2b's MLP widths (traced, not run) takes
+    the factored brick; an M > 7 table in interpret mode the integer
+    brick over its 1-D table."""
+    import jax.numpy as jnp
+
+    from repro.core.lutgen import get_lut
+    from repro.core.multipliers import get_multiplier
+    from repro.kernels.approx_gemm import approx_gemm
+    before = collections.Counter(obs.routes)
+    jax.eval_shape(lambda x, w: ops.policy_matmul(x, w, AFM16, "wg"),
+                   jax.ShapeDtypeStruct((256, 2048), jnp.float32),
+                   jax.ShapeDtypeStruct((2048, 8192), jnp.float32))
+    wide = get_multiplier("fp16xbf16")
+    approx_gemm(jnp.ones((8, 8)), jnp.ones((8, 8)), get_lut(wide),
+                wide.mantissa_bits, interpret=True)
+    assert obs.routes - before == collections.Counter({
+        "lut_brick.factored": 1, "lut_brick.integer.wide": 1})
+
+
 # ------------------------------------------------------------- spans
 _PARENT = {
     "engine.admit": "engine.tick", "engine.faults": "engine.tick",
