@@ -18,7 +18,6 @@ class MoEConfig:
     d_ff: int                    # per-expert hidden width
     interleave: int = 1          # MoE every `interleave`-th layer (1 = all)
     n_shared_experts: int = 0    # llama4-style always-on shared expert(s)
-    capacity_factor: float = 1.25
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,9 +1,8 @@
 """granite-moe-3b-a800m [moe]: 32L d=1536 24H (GQA kv=8), MoE 40e top-8, d_ff=512.
 
-[hf:ibm-granite/granite-3.0-1b-a400m-base; hf]
-Assignment header says "MoE 40e top-8" while the trailing comment says
-"32 experts" — the structured field wins: **40 experts, top-8** (flagged
-in DESIGN.md §Arch-applicability).  d_ff=512 is the per-expert width.
+[hf:ibm-granite/granite-3.0-3b-a800m-base; hf]
+40 experts of width 512 (d_ff is the per-expert width), the top 8 of
+them per token, tied embeddings.
 """
 from repro.configs.base import ArchConfig, MoEConfig, register
 

@@ -55,6 +55,7 @@ from jax.sharding import AxisType, PartitionSpec as P
 
 from repro import obs
 from repro.core import faults
+from repro.core.amsim import amsim_multiply
 from repro.core.float_bits import jnp_truncate_mantissa, jnp_round_mantissa
 from repro.core.lutgen import get_lut, get_packed_lut
 from repro.core.multipliers import get_multiplier
@@ -64,8 +65,12 @@ from repro.kernels.approx_attention import (NEG_INF, approx_attention_fused,
 from repro.kernels.common import attention_mask, best_chunk, rms_norm
 from repro.kernels.approx_conv import (approx_conv2d_dw, approx_conv2d_fused,
                                        conv_pads, fused_supported)
-from repro.kernels.approx_gemm import approx_gemm, approx_gemm_batched
-from repro.kernels.ref import ref_amsim_gemm, ref_direct_gemm, ref_im2col
+from repro.kernels.approx_gemm import (GroupedRows, approx_gemm,
+                                      approx_gemm_batched, approx_gemm_grouped,
+                                      approx_gemm_grouped_dw)
+from repro.kernels.ref import (ref_amsim_gemm, ref_direct_gemm,
+                               ref_grouped_gemm, ref_grouped_gemm_dw,
+                               ref_im2col)
 
 
 # =====================================================================
@@ -169,6 +174,22 @@ def _gemm_batched(a, b, policy: NumericsPolicy, tag=None):
     return _gemm_dispatch(a, b, policy, approx_gemm_batched, tag)
 
 
+def _surrogate_operands(a, b, policy: NumericsPolicy):
+    """The operands of a ``surrogate`` product, quantized for its native
+    matmul."""
+    mult = get_multiplier(policy.multiplier)
+    # Cross-format pipelines truncate each operand to its own format
+    # width (fp16 activations x bf16 weights); symmetric multipliers
+    # see ma == mb == mantissa_bits.
+    ma, mb = mult.operand_bits
+    # Pipeline specs always truncate operands (DenormStage); of the
+    # hand-written zoo only bf16 rounds them.
+    rnd = (jnp_round_mantissa
+           if mult.pipeline is None and mult.name.startswith("bf16")
+           else jnp_truncate_mantissa)
+    return rnd(a, ma), rnd(b, mb)
+
+
 def _matmul_nograd(a, b, policy: NumericsPolicy, tag=None):
     """Batched matmul (..., m, k) @ broadcastable (..., k, n), no custom grad.
     ``tag`` is the ``(site, pass)`` its kernel launches carry (``gemm_tag``).
@@ -189,17 +210,7 @@ def _matmul_nograd(a, b, policy: NumericsPolicy, tag=None):
         # Elementwise quantize + native batched matmul — no layout
         # restructuring, so GSPMD sharding propagates exactly as in
         # native mode (no spurious all-gathers).
-        mult = get_multiplier(policy.multiplier)
-        # Cross-format pipelines truncate each operand to its own format
-        # width (fp16 activations x bf16 weights); symmetric multipliers
-        # see ma == mb == mantissa_bits.
-        ma, mb = mult.operand_bits
-        # Pipeline specs always truncate operands (DenormStage); of the
-        # hand-written zoo only bf16 rounds them.
-        rnd = (jnp_round_mantissa
-               if mult.pipeline is None and mult.name.startswith("bf16")
-               else jnp_truncate_mantissa)
-        return jnp.matmul(rnd(a, ma), rnd(b, mb),
+        return jnp.matmul(*_surrogate_operands(a, b, policy),
                           preferred_element_type=jnp.float32)
     if a.ndim == 2 and b.ndim == 2:
         return _gemm2d(a, b, policy, tag)
@@ -290,6 +301,100 @@ def _mm_bwd(policy, site, res, g):
 
 
 policy_matmul.defvjp(_mm_fwd, _mm_bwd)
+
+
+# =====================================================================
+# Grouped matmul over rows sorted by expert (models/moe.py)
+#
+# The dropless MoE layer sorts its (token, choice) rows by expert and
+# pads each expert's group to the row tile (approx_gemm.grouped_layout);
+# each row tile is then multiplied by its expert's weights.  The forward
+# and dx run the grouped kernel (dx over each expert's transposed
+# weights), dw the grouped transposed-LHS kernel.  Under a mesh the
+# kernels run replicated (``_replicated``), as every kernel the sharded
+# dispatch does not take.
+# =====================================================================
+
+def _oracle_mul(leaf: NumericsPolicy, mult):
+    """The scalar product of the jnp oracle modes."""
+    if leaf.mode == "amsim_jnp":
+        lut = jnp.asarray(_oracle_lut(mult))
+        return lambda x, y: amsim_multiply(x, y, lut, mult.mantissa_bits)
+    if leaf.mode == "direct":
+        return mult.jnp_mul
+    raise ValueError(f"unknown mode {leaf.mode!r}")
+
+
+def _grouped_nograd(x, w, groups: GroupedRows, leaf: NumericsPolicy, tag):
+    """x (R, k) sorted rows @ w (E, k, n), each row tile times its
+    expert's block, under a resolved leaf: (R, n)."""
+    x, w = x.astype(jnp.float32), w.astype(jnp.float32)
+    if leaf.mode == "surrogate" and not leaf.is_native:
+        x, w = _surrogate_operands(x, w, leaf)
+    if leaf.is_native or leaf.mode == "surrogate":
+        return jax.lax.ragged_dot(x, w, groups.sizes,
+                                  preferred_element_type=jnp.float32)
+    mult = get_multiplier(leaf.multiplier)
+    if leaf.mode == "amsim":
+        return _replicated(
+            lambda x_, w_, g_: approx_gemm_grouped(
+                x_, w_, g_, _amsim_lut(mult), mult.mantissa_bits,
+                mult=mult.name, tag=tag), x, w, groups)
+    return ref_grouped_gemm(x, w, groups, _oracle_mul(leaf, mult))
+
+
+def _grouped_dw_nograd(x, g, groups: GroupedRows, n_experts: int,
+                       leaf: NumericsPolicy, tag):
+    """Per expert, x^T @ g over its rows, under a resolved leaf:
+    (E, k, n)."""
+    x, g = x.astype(jnp.float32), g.astype(jnp.float32)
+    if leaf.mode == "surrogate" and not leaf.is_native:
+        x, g = _surrogate_operands(x, g, leaf)
+    if leaf.is_native or leaf.mode == "surrogate":
+        zero = jnp.zeros((n_experts, x.shape[1], g.shape[1]), jnp.float32)
+        _, vjp = jax.vjp(lambda w: jax.lax.ragged_dot(
+            x, w, groups.sizes, preferred_element_type=jnp.float32), zero)
+        return vjp(g)[0]
+    mult = get_multiplier(leaf.multiplier)
+    if leaf.mode == "amsim":
+        return _replicated(
+            lambda x_, g_, gr_: approx_gemm_grouped_dw(
+                x_, g_, gr_, _amsim_lut(mult), mult.mantissa_bits, n_experts,
+                mult=mult.name, tag=tag), x, g, groups)
+    return ref_grouped_gemm_dw(x, g, groups, n_experts,
+                               _oracle_mul(leaf, mult))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def grouped_matmul(x, w, groups: GroupedRows, policy: Numerics,
+                   site: str | None = None):
+    """Differentiable grouped matmul: x (R, k), rows sorted by expert as
+    ``groups`` lays them out, times w (E, k, n) -> (R, n).  Forward under
+    the ``fwd`` leaf ``policy`` resolves at ``site`` (the expert sites
+    "wg", "wu", "wd"), dx and dw under theirs; rows of tiles past the last
+    group are zero, and so is their gradient."""
+    return _grouped_nograd(x, w, groups, policy.resolve(site),
+                           gemm_tag(site, "fwd"))
+
+
+def _gm_fwd(x, w, groups, policy, site=None):
+    return (_grouped_nograd(x, w, groups, policy.resolve(site),
+                            gemm_tag(site, "fwd")), (x, w, groups))
+
+
+def _gm_bwd(policy, site, res, g):
+    x, w, groups = res
+    g = g.astype(jnp.float32)
+    dx = _grouped_nograd(g, jnp.swapaxes(w, 1, 2), groups,
+                         policy.resolve(site, pass_="dx"),
+                         gemm_tag(site, "dx"))
+    dw = _grouped_dw_nograd(x, g, groups, w.shape[0],
+                            policy.resolve(site, pass_="dw"),
+                            gemm_tag(site, "dw"))
+    return dx.astype(x.dtype), dw.astype(w.dtype), None
+
+
+grouped_matmul.defvjp(_gm_fwd, _gm_bwd)
 
 
 # =====================================================================
@@ -744,9 +849,9 @@ def decode_moe_ffn_enabled(policy: Numerics, E: int, C: int, d: int,
                            d_ff: int) -> bool:
     """Dispatch guard for the stacked expert-bank FFN launch
     (kernels/decode_chain.fused_moe_ffn).  Shares the chain's kill
-    switch and mesh exclusion; the shape gate is vmem.moe_ffn_fits,
-    whose capacity bound (C <= MAX_ROWS) keeps this a decode-tick path
-    without a separate sequence-length plumb."""
+    switch and mesh exclusion; the shape gate is vmem.moe_ffn_fits.
+    models/moe.py asks on decode ticks only, with C the tick's row
+    count, so the bank drops no token."""
     leaf = moe_ffn_leaf(policy)
     if leaf is None or leaf.mode != "amsim" or leaf.is_native:
         return False

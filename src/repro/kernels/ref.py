@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core.amsim import amsim_multiply
 from repro.core.multipliers import Multiplier
+from repro.kernels.approx_gemm import GROUP_TILE
 
 # Contraction-chunk size of the oracle's FP32 fold (see _elementwise_gemm).
 _K_CHUNK = 128
@@ -59,6 +60,55 @@ def ref_amsim_gemm(a, b, lut, M: int):
     """
     lut = jnp.asarray(lut, jnp.uint32)
     return _elementwise_gemm(a, b, lambda x, y: amsim_multiply(x, y, lut, M))
+
+
+def _chunked_gemm(a, b, mul, chunk: int):
+    """(..., m, k) @ (..., k, n) folded as the Pallas brick folds k: k
+    zero-padded to a multiple of ``chunk``, each chunk of ``chunk``
+    consecutive k summed in order from +0.0 and added to the accumulator
+    in order.  Equal leading batch dims."""
+    k = a.shape[-1]
+    pad = -k % chunk
+    a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+    b = jnp.pad(b, [(0, 0)] * (b.ndim - 2) + [(0, pad), (0, 0)])
+    zero = jnp.zeros(a.shape[:-1] + b.shape[-1:], jnp.float32)
+
+    def add_chunk(i, acc):
+        def step(j, s):
+            aj = jax.lax.dynamic_slice_in_dim(a, i * chunk + j, 1, a.ndim - 1)
+            bj = jax.lax.dynamic_slice_in_dim(b, i * chunk + j, 1, b.ndim - 2)
+            return s + mul(aj, bj)
+        return acc + jax.lax.fori_loop(0, chunk, step, zero)
+
+    return jax.lax.fori_loop(0, (k + pad) // chunk, add_chunk, zero)
+
+
+def ref_grouped_gemm(a, b, groups, mul, chunk: int = _K_CHUNK):
+    """Oracle of ``approx_gemm_grouped``: row tile t of a (R, k) times
+    b[groups.tile_expert[t]] (k, n), ``mul`` as the scalar product, k
+    folded in chunks of ``chunk`` as the kernel folds it; rows of tiles
+    past the last group are zero."""
+    R, k = a.shape
+    tm = GROUP_TILE
+    out = _chunked_gemm(a.reshape(R // tm, tm, k), b[groups.tile_expert],
+                        mul, chunk)
+    live = jnp.arange(R // tm) < groups.live_tiles[0]
+    return jnp.where(live[:, None, None], out, 0.0).reshape(R, -1)
+
+
+def ref_grouped_gemm_dw(a, g, groups, n_experts: int, mul,
+                        chunk: int = _K_CHUNK):
+    """Oracle of ``approx_gemm_grouped_dw``: for each expert, a^T @ g over
+    the rows of its tiles (the others masked to zero), the rows folded in
+    chunks of ``chunk`` from the first row: (E, k, n)."""
+    R = a.shape[0]
+    tm = GROUP_TILE
+    live = jnp.arange(R // tm) < groups.live_tiles[0]
+    owner = jnp.repeat(jnp.where(live, groups.tile_expert, -1), tm)
+    mine = owner[None, :] == jnp.arange(n_experts)[:, None]        # (E, R)
+    at = jnp.where(mine[:, None, :], a.T[None], 0.0)                # (E, k, R)
+    return _chunked_gemm(at, jnp.broadcast_to(g, (n_experts,) + g.shape),
+                         mul, chunk)
 
 
 def ref_direct_gemm(a, b, multiplier: Multiplier):

@@ -1,32 +1,41 @@
-"""Mixture-of-Experts FFN: top-k routing, capacity-based static dispatch.
+"""Mixture-of-Experts FFN: top-k routing, dropless grouped dispatch.
 
-Index-based dispatch (scatter to per-expert slot buffers) rather than the
-one-hot einsum of Switch-Transformer: memory is O(assignments x d), not
-O(tokens x experts x capacity).  The (E, C, d) buffers shard over the
-"model" axis on E (expert parallelism) and the token axis of the router
-over "data"; expert GEMMs are policy-routed batched matmuls — in amsim
-mode the whole (E, C, d) @ (E, d, d_ff) stack is one E-batched
-``approx_gemm_batched`` launch (LUT broadcast over experts), so the
-paper's approximate numerics apply inside every expert at full-batch
-kernel efficiency.
+The router's logits are a policy GEMM (site "router"), so its top-k
+choices are made from simulated products.  Each token takes the k
+experts of its largest logits, weighted by a softmax over those k
+logits; no token is ever dropped.
 
-Tokens overflowing an expert's capacity are dropped (scatter mode=drop),
-standard capacity-factor semantics.  An auxiliary load-balance loss
-(Switch-style) is returned for training.
+Dispatch sorts the (token, choice) rows by expert and pads each expert's
+group to the grouped kernel's row tile (``approx_gemm.grouped_layout``):
+T*k + E*(tile-1) rows at most, so the expert work is the routed rows plus
+at most a tile of padding per expert, whatever the routing.  The expert
+FFN is three grouped GEMMs over those rows (``ops.grouped_matmul``, sites
+"wg", "wu", "wd"), one LUT kernel launch each, whose row tiles each pick
+their expert's weights.  The results are gathered back to assignment
+order and summed per token in choice order.
+
+On a decode tick (one token per sequence) under a homogeneous amsim
+wg/wu/wd leaf the experts can instead run in one persistent stacked-bank
+launch (kernels/decode_chain.fused_moe_ffn) over an (E, C, d) buffer
+whose capacity C is the tick's row count, so it cannot drop either.
+Each choice is recorded in ``obs.routes`` ("moe.grouped.taken" or
+"moe.grouped.refused.decode").  The two give the same bits: the grouped
+kernel folds every row as the E-batched kernel the launch is slaved to.
+
+An auxiliary load-balance loss (Switch-style) is returned for training.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.core.policy import NumericsPolicy
+from repro.kernels import ops
+from repro.kernels.approx_gemm import grouped_layout, grouped_rows_bound
 from repro.models.layers import init_linear
 from repro.models.mlp import ffn, init_ffn
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
 
 
 def init_moe(key, cfg: ArchConfig):
@@ -41,60 +50,83 @@ def init_moe(key, cfg: ArchConfig):
     return p
 
 
+def route(router, x, cfg: ArchConfig, policy: NumericsPolicy):
+    """x (T, d) -> (gate (T, k), sel (T, k), probs (T, E)): the experts of
+    each token's k largest router logits, in descending order, their
+    softmax over those k logits, and the softmax over all E (for the
+    load-balance loss)."""
+    logits = policy.matmul(x, router["w"], site="router").astype(jnp.float32)
+    top, sel = jax.lax.top_k(logits, cfg.moe.top_k)
+    return jax.nn.softmax(top, axis=-1), sel, jax.nn.softmax(logits, axis=-1)
+
+
+def _grouped_ffn(ew, xs, groups, policy: NumericsPolicy, act: str):
+    mm = lambda h, s: ops.grouped_matmul(h, ew[s]["w"], groups, policy, s)
+    if act == "swiglu":
+        h = jax.nn.silu(mm(xs, "wg")) * mm(xs, "wu")
+    else:
+        h = jax.nn.gelu(mm(xs, "wu"))
+    return mm(h, "wd")
+
+
+def _decode_bank(ew, cfg: ArchConfig, policy: NumericsPolicy, S: int,
+                 C: int) -> bool:
+    """Whether this call's experts run in the stacked-bank launch over a
+    capacity-``C`` buffer."""
+    m = cfg.moe
+    return (S == 1 and cfg.act == "swiglu" and "wg" in ew
+            and not any("b" in ew[s] for s in ("wg", "wu", "wd"))
+            and ops.decode_moe_ffn_enabled(policy, m.n_experts, C,
+                                           cfg.d_model, m.d_ff))
+
+
 def moe_ffn(p, x, cfg: ArchConfig, policy: NumericsPolicy):
     """x (B, S, d) -> (y (B, S, d), aux_loss scalar)."""
     m = cfg.moe
     B, S, d = x.shape
     T = B * S
     E, k = m.n_experts, m.top_k
-    C = _round_up(max(int(T * k * m.capacity_factor / E), 1), 8)
     xf = x.reshape(T, d)
 
-    logits = policy.matmul(xf, p["router"]["w"], site="router")   # (T, E)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate, sel = jax.lax.top_k(probs, k)                   # (T, k)
-    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    with jax.named_scope("moe.router"):
+        gate, sel, probs = route(p["router"], xf, cfg, policy)
 
-    # slot assignment: rank of each (token, choice) within its expert.
-    # associative_scan (log-depth) instead of cumsum: XLA-CPU lowers
-    # cumsum to reduce-window and cost-models it O(n^2), poisoning the
-    # roofline; the scan form is also how TPU lowers large prefix sums.
-    e_flat = sel.reshape(-1)                              # (T*k,) token-major
-    onehot = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)   # (T*k, E)
-    pos = jax.lax.associative_scan(jnp.add, onehot, axis=0) - onehot
-    slot = jnp.take_along_axis(pos, e_flat[:, None], axis=1)[:, 0]
-
-    tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
-    buf = jnp.zeros((E, C, d), xf.dtype).at[e_flat, slot].set(
-        xf[tok], mode="drop")                             # (E, C, d)
-
-    # Expert FFN over the capacity buffer.  On decode ticks (small C)
-    # under a homogeneous amsim wg/wu/wd leaf this is ONE persistent
-    # stacked-bank launch (kernels/decode_chain.fused_moe_ffn) —
-    # bit-identical to the E-batched per-op lowering, whose gemm3d folds
-    # the launch slaves its accumulation to.  Training/prefill C blows
-    # the guard's VMEM row bound, so those keep the batched per-op path.
     ew = p["experts"]
-    from repro.kernels import ops
-    if (cfg.act == "swiglu" and "wg" in ew
-            and not any("b" in ew[s] for s in ("wg", "wu", "wd"))
-            and ops.decode_moe_ffn_enabled(policy, E, C, d, m.d_ff)):
-        out = ops.decode_moe_ffn(buf, ew["wg"]["w"], ew["wu"]["w"],
-                                 ew["wd"]["w"], policy)
-    else:
-        out = ffn(ew, buf, policy, cfg.act)               # batched over E
+    C = -(-T // 8) * 8                # a bank's capacity: every row fits
+    bank = _decode_bank(ew, cfg, policy, S, C)
+    obs.route("moe.grouped", "refused.decode" if bank else "taken")
+    with jax.named_scope("moe.dispatch"):
+        e_flat = sel.reshape(-1)                          # (T*k,) token-major
+        tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+        groups = grouped_layout(e_flat, E)
+        if bank:
+            slot = groups.rows - (jnp.cumsum(groups.sizes)
+                                  - groups.sizes)[e_flat]
+            xs = jnp.zeros((E, C, d), xf.dtype).at[e_flat, slot].set(xf[tok])
+        else:
+            R = grouped_rows_bound(T * k, E)
+            src = jnp.full((R,), T, jnp.int32).at[groups.rows].set(tok)
+            xs = jnp.take(xf, src, axis=0, mode="fill", fill_value=0)
 
-    got = out.at[e_flat, jnp.minimum(slot, C - 1)].get()  # (T*k, d)
-    got = jnp.where((slot < C)[:, None], got, 0.0)
-    y = jnp.zeros((T, d), jnp.float32).at[tok].add(
-        got * gate.reshape(-1)[:, None])
+    with jax.named_scope("moe.experts"):
+        if bank:
+            out = ops.decode_moe_ffn(xs, ew["wg"]["w"], ew["wu"]["w"],
+                                     ew["wd"]["w"], policy)
+        else:
+            out = _grouped_ffn(ew, xs, groups, policy, cfg.act)
+
+    with jax.named_scope("moe.combine"):
+        got = out[e_flat, slot] if bank else out[groups.rows]
+        got = got.reshape(T, k, d)
+        y = gate[:, 0, None] * got[:, 0]
+        for j in range(1, k):
+            y = y + gate[:, j, None] * got[:, j]
 
     if m.n_shared_experts and "shared" in p:
         y = y + ffn(p["shared"], xf, policy, cfg.act)
 
-    # Switch-style load-balance loss: E * sum_e f_e * P_e
-    assign_frac = jnp.mean(
-        jax.nn.one_hot(sel, E, dtype=jnp.float32).sum(1), axis=0)  # f_e
+    # Switch-style load-balance loss: E * sum_e f_e * P_e / k
+    assign_frac = jnp.zeros((E,), jnp.float32).at[e_flat].add(1.0) / T
     router_frac = jnp.mean(probs, axis=0)                          # P_e
     aux = E * jnp.sum(assign_frac * router_frac) / k
     return y.reshape(B, S, d), aux

@@ -117,6 +117,32 @@ def test_decode_chain_route_records_why_it_was_refused(monkeypatch):
         "decode_chain.refused.policy": 1, "decode_chain.refused.env": 1})
 
 
+def test_moe_route_records_the_grouped_kernel_or_the_decode_bank():
+    """granite-moe-3b-a800m's layer at its published widths (traced, not
+    run): a training batch takes the grouped route, under native numerics
+    too; a small decode tick under amsim runs the stacked-bank launch and
+    records why the grouped route was refused.  The dispatch sits in the
+    layer's named scopes."""
+    import jax.numpy as jnp
+
+    from repro.models.moe import init_moe, moe_ffn
+    cfg = get_arch("granite-moe-3b-a800m")
+    p = jax.eval_shape(lambda k: init_moe(k, cfg), jax.random.PRNGKey(0))
+    run = lambda pol, shape: jax.jit(
+        lambda p, x: moe_ffn(p, x, cfg, pol)).lower(
+            p, jax.ShapeDtypeStruct(shape, jnp.float32))
+    before = collections.Counter(obs.routes)
+    text = run(AFM16, (4, 512, cfg.d_model)).as_text(debug_info=True)
+    run(NATIVE, (4, 512, cfg.d_model))
+    run(AFM16, (4, 1, cfg.d_model))
+    assert obs.routes - before >= collections.Counter({
+        "moe.grouped.taken": 2, "moe.grouped.refused.decode": 1})
+    assert obs.routes["moe.grouped.refused.decode"] - before[
+        "moe.grouped.refused.decode"] == 1
+    for scope in ("moe.router", "moe.dispatch", "moe.experts", "moe.combine"):
+        assert scope in text
+
+
 def test_lut_brick_route_records_the_path_each_launch_compiles():
     """An afm16 GEMM at granite-3-2b's MLP widths (traced, not run) takes
     the factored brick; an M > 7 table in interpret mode the integer

@@ -1,6 +1,7 @@
 """Compile the main-path LUT kernels for a TPU v5e that is described, not
 attached, at granite-3-2b's published widths (d=2048, 32 heads, 8 KV
-heads, head_dim 64, d_ff=8192) and resnet-mini's conv shapes.
+heads, head_dim 64, d_ff=8192), granite-moe-3b-a800m's expert GEMMs and
+resnet-mini's conv shapes.
 
 Nothing runs: each test lowers and compiles with ``interpret=False``, so
 the chip's compiler (Mosaic) refuses here what it would refuse on the
@@ -208,6 +209,41 @@ def test_decode_chain_moe_launches(one_chip):
 
 
 AFM16 = NumericsPolicy(mode="amsim", multiplier="afm16")
+
+
+@pytest.mark.parametrize("site,k,n", [("wg", 1536, 512), ("wd", 512, 1536)])
+def test_grouped_gemm_forward_dx_dw(one_chip, compiled_kernels, site, k, n):
+    """The dropless MoE layer's expert GEMMs at granite-moe-3b-a800m's
+    widths (d 1536, 40 experts of width 512, a 2048-token batch's 16384
+    routed rows): forward, dx and dw, three launches of the one grouped
+    impl, each tagged; the benchmark's work function reads the routed
+    rows' products from each launch's operands."""
+    from bench.kernels import work_of
+    from repro.kernels.approx_gemm import GroupedRows, grouped_rows_bound
+    from repro.kernels.ops import grouped_matmul
+    E, routed = 40, 16384
+    R = grouped_rows_bound(routed, E)
+
+    def step(x, w, rows, sizes, tile_expert, live):
+        g = GroupedRows(rows, sizes, tile_expert, live)
+        return jax.value_and_grad(
+            lambda x, w: grouped_matmul(x, w, g, AFM16, site).sum(),
+            argnums=(0, 1))(x, w)
+    text = _compile(step, one_chip, ((R, k), f32), ((E, k, n), f32),
+                    ((routed,), i32), ((E,), i32), ((R // 128,), i32),
+                    ((1,), i32))
+    assert _kernels(text) == [("approx_gemm_grouped_impl", (site, p))
+                              for p in ("dw", "dx", "fwd")]
+    work = work_of("approx_gemm_grouped_impl")
+    for launch in _launches(text):
+        # Compiled text names the operands; their shapes are in the
+        # layout constraints (a trace's op text carries them inline).
+        parse = lambda t: [(d, tuple(int(x) for x in dims.split(",") if x))
+                           for d, dims in T._SHAPE.findall(t)]
+        operands = parse(launch.split("operand_layout_constraints={")[1]
+                         .split("}}")[0])
+        results = parse(launch.split("=", 1)[1].split("custom-call")[0])
+        assert work(operands, results)[0] == 2.0 * routed * k * n
 
 
 @pytest.fixture
