@@ -93,7 +93,7 @@ def bench_shape(B, m, k, n, *, mult, lut, plut, iters, do_autotune):
          norm=t_bat / t_native)
 
     # The pre-engine fallback: vmap of the 2-D kernel at its 2-D defaults.
-    cfg2d = autotune.DEFAULT_2D
+    cfg2d = autotune.get_block_config("gemm2d", *a.shape[1:], b.shape[-1], M)
     vmapped = jax.jit(jax.vmap(lambda a, b: approx_gemm(
         a, b, lut, M, bm=cfg2d.bm, bn=cfg2d.bn, bk=cfg2d.bk,
         chunk=cfg2d.chunk)))

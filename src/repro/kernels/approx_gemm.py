@@ -43,9 +43,10 @@ Entry points:
                          as ``_approx_gemm_grouped_impl``.
 
 Block sizes default to the autotuner's cached winner for the (shape
-bucket, M, backend) — see ``kernels/autotune.py``; explicit bm/bn/bk/chunk
-arguments override.  Operand tiles are multiples of 128 to align MXU/VPU
-lanes and HBM burst transfers.
+bucket, M, backend), and for the 2-D and grouped kernels' output tile on a
+miss to the shape rule ``autotune.tile_2d`` — see ``kernels/autotune.py``;
+explicit bm/bn/bk/chunk arguments override.  Lane extents are multiples of
+128 and row extents of 16 (the brick's bf16 one-hot operand).
 """
 from __future__ import annotations
 
@@ -57,6 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels import autotune
 from repro.kernels.common import (_ceil128, _ceil_to, _gather_gemm_tile,
                                   _pad_to, best_chunk, kernel_lut, lut_spec,
@@ -181,6 +183,7 @@ def approx_gemm(
     assert k == k2, (a.shape, b.shape)
     bm, bn, bk, chunk, interpret = _resolve(
         "gemm2d", m, k, n, M, 0, bm, bn, bk, chunk, interpret, mult)
+    obs.route("gemm.tile.gemm2d", f"{bm}x{bn}")
     lut = kernel_lut(lut, M, interpret)
     return _approx_gemm_impl(a, b, lut, M, bm=bm, bn=bn, bk=bk,
                              chunk=chunk, interpret=interpret, tag=tag)
@@ -249,6 +252,7 @@ def approx_gemm_batched(
     assert B == B2 and k == k2, (a.shape, b.shape)
     bm, bn, bk, chunk, interpret = _resolve(
         "gemm3d", m, k, n, M, B, bm, bn, bk, chunk, interpret, mult)
+    obs.route("gemm.tile.gemm3d", f"{bm}x{bn}")
     lut = kernel_lut(lut, M, interpret)
     return _approx_gemm_batched_impl(a, b, lut, M, bm=bm, bn=bn, bk=bk,
                                      chunk=chunk, interpret=interpret,
@@ -310,8 +314,9 @@ def grouped_layout(experts, n_experts: int) -> GroupedRows:
 
 
 def _grouped_kernel(te_ref, live_ref, rows_ref, a_ref, b_ref, lut_ref, o_ref,
-                    acc_ref, *, M: int, chunk: int):
-    # Grid (tile, n/bn, k/bk); a tile past the last group writes zeros.
+                    acc_ref, *, M: int, chunk: int, strips: int):
+    # Grid (row strip, n/bn, k/bk), ``strips`` strips to a tile; a strip
+    # of a tile past the last group writes zeros.
     del te_ref, rows_ref
     kk = pl.program_id(2)
 
@@ -319,7 +324,7 @@ def _grouped_kernel(te_ref, live_ref, rows_ref, a_ref, b_ref, lut_ref, o_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(pl.program_id(0) < live_ref[0])
+    @pl.when(pl.program_id(0) // strips < live_ref[0])
     def _step():
         acc_ref[...] = _gather_gemm_tile(
             a_ref[...], b_ref[0], lut_ref[...], acc_ref[...],
@@ -355,7 +360,8 @@ def _grouped_dw_kernel(te_ref, live_ref, rows_ref, a_ref, b_ref, lut_ref,
                      "interpret", "tag"))
 def _approx_gemm_grouped_impl(a, b, groups, lut, M, *, dw, n_experts, bm, bn,
                               bk, chunk, interpret, tag=None):
-    """``dw`` False: a (R, k) sorted rows @ b (E, k, n) -> (R, n).
+    """``dw`` False: a (R, k) sorted rows @ b (E, k, n) -> (R, n), in row
+    strips of ``bm`` rows, each inside one row tile.
     ``dw`` True: a (k, R) the sorted rows transposed, b (R, n) their
     output gradient -> (E, k, n), zero for an expert with no rows."""
     tm = GROUP_TILE
@@ -380,19 +386,24 @@ def _approx_gemm_grouped_impl(a, b, groups, lut, M, *, dw, n_experts, bm, bn,
         b = _pad_to(b.astype(jnp.float32), bk, bn)
         kp, np_ = b.shape[1:]
         nj, nk = np_ // bn, kp // bk
-        grid = (n_tiles, nj, nk)
-        # A tile past the last group keeps the blocks of the last live
-        # step, so the pipeline fetches nothing for it.
-        live_ = lambda t, live, x, last: jnp.where(t < live[0], x, last)
+        strips = tm // bm
+        grid = (n_tiles * strips, nj, nk)
+        # A strip of a tile past the last group keeps the blocks of the
+        # last live step, so the pipeline fetches nothing for it.
+        live_ = lambda s, live, x, last: jnp.where(s // strips < live[0], x,
+                                                   last)
         in_specs = [
-            pl.BlockSpec((tm, bk), lambda t, j, kk, te, live: (
-                live_(t, live, t, live[0] - 1), live_(t, live, kk, nk - 1))),
-            pl.BlockSpec((1, bk, bn), lambda t, j, kk, te, live: (
-                te[t], live_(t, live, kk, nk - 1), live_(t, live, j, nj - 1))),
+            pl.BlockSpec((bm, bk), lambda s, j, kk, te, live: (
+                live_(s, live, s, live[0] * strips - 1),
+                live_(s, live, kk, nk - 1))),
+            pl.BlockSpec((1, bk, bn), lambda s, j, kk, te, live: (
+                te[s // strips], live_(s, live, kk, nk - 1),
+                live_(s, live, j, nj - 1))),
         ]
-        out_spec = pl.BlockSpec((tm, bn), lambda t, j, kk, te, live: (t, j))
+        out_spec = pl.BlockSpec((bm, bn), lambda s, j, kk, te, live: (s, j))
         out_shape = (a.shape[0], np_)
-        kernel, scratch = _grouped_kernel, [pltpu.VMEM((tm, bn), jnp.float32)]
+        kernel = functools.partial(_grouped_kernel, strips=strips)
+        scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     # ``rows`` stays in HBM, untouched: it carries the routed-row count
     # in the launch's operand shapes (bench/work/approx_gemm_grouped_impl).
     out = tagged_pallas_call(
@@ -418,6 +429,7 @@ def approx_gemm_grouped(
     lut,
     M: int,
     *,
+    bm: int | None = None,
     bn: int | None = None,
     bk: int | None = None,
     chunk: int | None = None,
@@ -430,18 +442,24 @@ def approx_gemm_grouped(
     accumulate; rows of tiles past the last group come back zero.
 
     Each output row folds its k products exactly as the E-batched kernel
-    folds that expert's rows (the same ``gemm3d`` tiling bucket), so a
-    row's bits do not depend on how the rows were grouped.  ``tag`` as
-    for :func:`approx_gemm`."""
+    folds that expert's rows (``bk`` and ``chunk`` from the same ``gemm3d``
+    tiling bucket), so a row's bits do not depend on how the rows were
+    grouped.  The output tile, ``bm`` rows (a divisor of the row tile) by
+    ``bn`` lanes, is the 2-D kernel's (``autotune.tile_2d``) and moves no
+    bit.  ``tag`` as for :func:`approx_gemm`."""
     R, k = a.shape
     E, k2, n = b.shape
     assert k == k2 and R % GROUP_TILE == 0, (a.shape, b.shape)
-    _, bn, bk, chunk, interpret = _resolve(
-        "gemm3d", GROUP_TILE, k, n, M, E, GROUP_TILE, bn, bk, chunk,
-        interpret, mult)
+    rule_bm, rule_bn = autotune.tile_2d(R, n)
+    bm = min(rule_bm, GROUP_TILE) if bm is None else bm
+    bn = rule_bn if bn is None else bn
+    assert GROUP_TILE % bm == 0, bm
+    _, _, bk, chunk, interpret = _resolve(
+        "gemm3d", GROUP_TILE, k, n, M, E, bm, bn, bk, chunk, interpret, mult)
+    obs.route("gemm.tile.grouped", f"{bm}x{bn}")
     lut = kernel_lut(lut, M, interpret)
     out = _approx_gemm_grouped_impl(
-        a, b, groups, lut, M, dw=False, n_experts=E, bm=GROUP_TILE, bn=bn,
+        a, b, groups, lut, M, dw=False, n_experts=E, bm=bm, bn=bn,
         bk=bk, chunk=chunk, interpret=interpret, tag=tag)
     return out[:, :n]
 
@@ -468,13 +486,18 @@ def approx_gemm_grouped_dw(
     The contraction over an expert's rows folds as the 2-D brick folds k:
     chunks of ``chunk`` consecutive rows, each summed in order from +0.0,
     added to the accumulator in row order, from the expert's first tile
-    to its last; an expert with no rows gets zeros."""
+    to its last; an expert with no rows gets zeros.  The output tile is
+    the 2-D kernel's (``autotune.tile_2d``)."""
     R, k = a.shape
     assert g.shape[0] == R and R % GROUP_TILE == 0, (a.shape, g.shape)
     n = g.shape[1]
-    bm, bn, _, chunk, interpret = _resolve(
+    rule_bm, rule_bn = autotune.tile_2d(k, n)
+    bm = rule_bm if bm is None else bm
+    bn = rule_bn if bn is None else bn
+    _, _, _, chunk, interpret = _resolve(
         "gemm3d", k, GROUP_TILE, n, M, n_experts, bm, bn, GROUP_TILE, chunk,
         interpret, mult)
+    obs.route("gemm.tile.grouped_dw", f"{bm}x{bn}")
     lut = kernel_lut(lut, M, interpret)
     out = _approx_gemm_grouped_impl(
         a.T, g, groups, lut, M, dw=True, n_experts=n_experts, bm=bm, bn=bn,

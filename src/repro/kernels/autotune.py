@@ -21,7 +21,8 @@ B*KV/S/T and keeps G/head_dim exact.  ``approx_gemm`` /
 ``approx_gemm_batched`` / ``approx_conv2d_fused`` /
 ``approx_attention_fused`` consult the cache at trace time via
 :func:`get_block_config` / :func:`get_conv_config` /
-:func:`get_attn_config`; a miss falls back to safe defaults — tuning
+:func:`get_attn_config`; a miss falls back to safe defaults (for the
+2-D GEMM, the shape rule :func:`tile_2d` with the fold ``FOLD_2D``) — tuning
 itself only runs when :func:`autotune` / :func:`autotune_conv` /
 :func:`autotune_attention` is called explicitly
 (``benchmarks/bench_batched_gemm.py --autotune``,
@@ -59,6 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkout import CACHE
+from repro.kernels.common import _ceil_to
 
 SCHEMA_VERSION = 1
 
@@ -131,8 +133,24 @@ class DecodeChainConfig:
 # deeper k-tile / wider gather brick: one grid point per (batch, m, n) tile
 # amortises kernel-dispatch overhead that the vmapped 2-D path pays per
 # k-block (interpret mode) and keeps the accumulator resident longer (TPU).
-DEFAULT_2D = BlockConfig(128, 128, 128, 8)
+# The 2-D kernel takes its tile from ``tile_2d`` and its fold from FOLD_2D.
 DEFAULT_BATCHED = BlockConfig(128, 128, 256, 64)
+# The 2-D kernel's fold, (bk, chunk): the only tiling parameters its bits
+# depend on (docs/kernels.md, "An explicit fold").
+FOLD_2D = (128, 8)
+# Output vregs (8 x 128 f32 each) that one k-step of the brick folds into,
+# the live accumulator of its straight-line block, and the tallest tile:
+# on a TPU v5e 64 x 512 (32 vregs) took the least time a product of the
+# tiles swept at granite's GEMM shapes (PERF.md §6).
+ACC_VREGS = 32
+MAX_ROWS = 128
+# Lane extents of an output tile, widest first, and the share of n a wider
+# one may pad beyond the 128-lane padding every extent pays.
+LANE_WIDTHS = (512, 256, 128)
+LANE_PAD = 1 / 32
+# Row tiles are whole bf16 sublane tiles: the brick's one-hot operand is
+# bf16, and Mosaic tiles a bf16 array 16 rows deep.
+ROW_TILE = 16
 # Conv default: whole output-channel extent per block (``bo`` is clamped
 # to O by the wrapper, avoiding the lane padding the GEMM path pays when
 # O < 128) and a full-C gather brick for the paper's C <= 128 layers.
@@ -343,6 +361,26 @@ def decode_chain_cache_key(rows: int, d: int, k_attn: int, d_ff: int,
     return f"{backend}|decode_chain|{bucket}|{_m_tag(M, mult)}"
 
 
+# ------------------------------------------------------------------ tile rule
+def tile_2d(m: int, n: int) -> tuple[int, int]:
+    """(bm, bn) of an (m, k) @ (k, n) brick launch, from its shape.
+
+    A k-step's A-side work (broadcast A's column, select its table rows
+    by a one-hot matmul) is done once per row of the tile and serves every
+    128-lane block of it, so ``bn`` is the widest of LANE_WIDTHS that pads
+    n by at most LANE_PAD of n beyond the 128-lane padding; ``bm`` keeps
+    the accumulator at ACC_VREGS vregs, at most MAX_ROWS rows, and pads m
+    only to a whole bf16 sublane tile.  Neither enters the fold, so the
+    rule moves no bit.
+    """
+    n128 = _ceil_to(n, 128)
+    bn = next(w for w in LANE_WIDTHS
+              if w <= n128 and _ceil_to(n, w) - n128 <= LANE_PAD * n)
+    bm = min(ACC_VREGS * 8 * 128 // bn, MAX_ROWS,
+             _ceil_to(max(m, 1), ROW_TILE))
+    return bm, bn
+
+
 # ------------------------------------------------------------------ lookup
 def _lookup(key_fn, mult):
     """Per-multiplier entry first, bare-M entry as fallback (so sweeps
@@ -354,12 +392,16 @@ def _lookup(key_fn, mult):
 def get_block_config(kind: str, m: int, k: int, n: int, M: int,
                      batch: int = 0, backend: str | None = None,
                      mult: str | None = None) -> BlockConfig:
-    """Tuned winner for this bucket, or the kind's default on a miss."""
+    """Tuned winner for this bucket; on a miss DEFAULT_BATCHED for
+    ``gemm3d``, and for ``gemm2d`` the tile of :func:`tile_2d` with the
+    fold FOLD_2D."""
     hit = _lookup(lambda mu: cache_key(kind, m, k, n, M, batch, backend, mu),
                   mult)
     if isinstance(hit, BlockConfig):
         return hit
-    return DEFAULT_BATCHED if kind == "gemm3d" else DEFAULT_2D
+    if kind == "gemm3d":
+        return DEFAULT_BATCHED
+    return BlockConfig(*tile_2d(m, n), *FOLD_2D)
 
 
 def get_conv_config(n: int, h: int, w: int, c: int, kh: int, kw: int,

@@ -16,8 +16,9 @@ interpret mode:
     word keeps its sign and exponent, with its table index (top M
     mantissa bits) in the cleared mantissa field.  The contraction walks
     one k at a time: A's column k is broadcast across lanes by a lane
-    gather of its aligned 128-lane block; B's row k is a dynamic sublane
-    load, so every dynamic slice is a ref slice;
+    gather of its aligned 128-lane block, once per tile row for every
+    128-lane block of the output tile; each lane block of B's row k is a
+    dynamic sublane load, so every dynamic slice is a ref slice;
   * for tables with M <= 7 the 2^M x 2^M LUT is a (128, 128) matrix.
     A one-hot matmul on the MXU selects the rows for A's indices (exact:
     every output sums one non-zero entry, held exactly in bf16), then a
@@ -219,13 +220,6 @@ def _pick(rows, bt):
     return entry
 
 
-def _tile_lanes(x, width: int):
-    """(r, 128) -> (r, width) by repeating the 128-lane block."""
-    if width == LANES:
-        return x
-    return jnp.concatenate([x] * (width // LANES), axis=1)
-
-
 def _gather_gemm_tile(a, b, lut, acc, *, M: int, chunk: int):
     """Rank-``chunk`` gather-GEMM update of the f32 accumulator tile.
 
@@ -245,10 +239,15 @@ def _gather_gemm_tile(a, b, lut, acc, *, M: int, chunk: int):
     bmp, bnp = _ceil_to(bm, SUBLANES), _ceil128(bn)
     form = table_form(lut)
     packed = lut.ndim == 1 and lut.dtype == jnp.uint16
+    nq = bnp // LANES
 
     def run(a_scr, b_scr):
         a_scr[:bm, :bk] = _decode(a, M)
-        b_scr[:bk, :bn] = _decode(b, M)
+        # B by 128-lane blocks, so a k-step loads each block's row whole.
+        for q in range(nq):
+            width = min(LANES, bn - q * LANES)
+            b_scr[q, :bk, :width] = _decode(
+                b[:, q * LANES:q * LANES + width], M)
 
         def a_col(k):
             blk = a_scr[:, pl.ds(pl.multiple_of(k // LANES * LANES, LANES),
@@ -257,11 +256,12 @@ def _gather_gemm_tile(a, b, lut, acc, *, M: int, chunk: int):
                 blk, jnp.full((bmp, LANES), k % LANES, jnp.int32), axis=1,
                 mode="promise_in_bounds")
 
-        def b_row(k, sublanes):
-            # B's row k on ``sublanes`` sublanes.  Mosaic lowers a sublane
-            # broadcast of the whole row, and a lane slice of a value
-            # computed from one, not a lane slice of the broadcast itself.
-            return jnp.broadcast_to(b_scr[pl.ds(k, 1), :], (sublanes, bnp))
+        def b_row(k, q):
+            # Lane block q of B's row k, decoded on one vreg of sublanes
+            # and not on bmp.  Mosaic lowers a sublane broadcast of a
+            # whole scratch row, not a lane slice of one.
+            return jnp.broadcast_to(b_scr[q, pl.ds(k, 1), :],
+                                    (SUBLANES, LANES))
 
         def tile_rows(x):
             """(8, 128) -> (bmp, 128) by repeating the vreg."""
@@ -270,34 +270,33 @@ def _gather_gemm_tile(a, b, lut, acc, *, M: int, chunk: int):
         def factored(k):
             wa = a_col(k)
             rows, fa = _rows(lut, _index(wa, M)), _scale(wa)
-            # B's row is decoded on one vreg of sublanes, not on bmp.
-            wb = b_row(k, SUBLANES)
-            bt, fb = _index(wb, M), _scale(wb)
             out = []
-            for q in range(0, bnp, LANES):
+            for q in range(nq):
+                wb = b_row(k, q)
                 out.append(factored_product(
-                    fa, tile_rows(fb[:, q:q + LANES]),
-                    _pick(rows, tile_rows(bt[:, q:q + LANES]))))
+                    fa, tile_rows(_scale(wb)),
+                    _pick(rows, tile_rows(_index(wb, M)))))
             return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
         def integer(k):
             wa = a_col(k)
-            wb = b_row(k, bmp)
-            at, bt = _index(wa, M), _index(wb, M)
-            if form == "integer.wide":  # interpret mode only (kernel_lut)
-                entry = jnp.take(lut, (_tile_lanes(at, bnp) << M) | bt)
-            else:
-                rows = _rows(lut, at)
-                entry = [_pick(rows, bt[:, q:q + LANES])
-                         for q in range(0, bnp, LANES)]
-                entry = (entry[0] if len(entry) == 1
-                         else jnp.concatenate(entry, axis=1))
-                if form == "factored":  # V's f32 bits less 1.0's
-                    entry = (jax.lax.bitcast_convert_type(entry, jnp.uint32)
-                             - np.uint32(0x3F80_0000))
-            return jnp_float(amsim_from_entry(
-                _tile_lanes(wa, bnp), wb, entry.astype(jnp.uint32), M, jnp,
-                packed))
+            at = _index(wa, M)
+            rows = None if form == "integer.wide" else _rows(lut, at)
+            out = []
+            for q in range(nq):
+                wb = b_row(k, q)
+                bt = tile_rows(_index(wb, M))
+                if rows is None:  # interpret mode only (kernel_lut)
+                    entry = jnp.take(lut, (at << M) | bt)
+                else:
+                    entry = _pick(rows, bt)
+                    if form == "factored":  # V's f32 bits less 1.0's
+                        entry = (jax.lax.bitcast_convert_type(
+                            entry, jnp.uint32) - np.uint32(0x3F80_0000))
+                out.append(jnp_float(amsim_from_entry(
+                    wa, tile_rows(wb), entry.astype(jnp.uint32), M, jnp,
+                    packed)))
+            return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
         def fold(product, unroll=1):
             def steps(i, acc):
@@ -321,7 +320,8 @@ def _gather_gemm_tile(a, b, lut, acc, *, M: int, chunk: int):
 
     return pl.run_scoped(run,
                          pltpu.VMEM((bmp, _ceil128(bk)), jnp.uint32),
-                         pltpu.VMEM((_ceil_to(bk, SUBLANES), bnp), jnp.uint32))
+                         pltpu.VMEM((nq, _ceil_to(bk, SUBLANES), LANES),
+                                    jnp.uint32))
 
 
 def _rms_scale(x, eps: float):

@@ -205,7 +205,7 @@ def test_autotune_corrupt_cache_is_safe(tuned_env):
     assert autotune.get_block_config("gemm3d", 32, 32, 32, 7, batch=2) == \
         autotune.DEFAULT_BATCHED
     assert autotune.get_block_config("gemm2d", 32, 32, 32, 7) == \
-        autotune.DEFAULT_2D
+        autotune.BlockConfig(*autotune.tile_2d(32, 32), *autotune.FOLD_2D)
     # Re-tune overwrites the corrupt file with a valid cache.
     won = autotune.autotune("gemm3d", tuned_env["a"], tuned_env["b"],
                             tuned_env["lut"], 7,

@@ -4,16 +4,21 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core.amsim import _amsim
+from repro.core.amsim import _amsim, amsim_multiply
 from repro.core.faults import FaultSpec, apply_faults
 from repro.core.lutgen import generate_lut, get_lut, get_packed_lut
 from repro.core.multipliers import REGISTRY, get_multiplier
 from repro.core.policy import NumericsPolicy
 from repro.kernels import common
 from repro.kernels.approx_attention import approx_attention_fused
-from repro.kernels.approx_gemm import approx_gemm, approx_gemm_batched
+from repro.kernels.approx_gemm import (approx_gemm, approx_gemm_batched,
+                                       approx_gemm_grouped,
+                                       approx_gemm_grouped_dw, grouped_layout,
+                                       grouped_rows_bound)
 from repro.kernels.ops import attend_einsum
-from repro.kernels.ref import ref_amsim_gemm, ref_direct_gemm, ref_im2col, ref_conv2d
+from repro.kernels.ref import (_chunked_gemm, ref_amsim_gemm, ref_conv2d,
+                               ref_direct_gemm, ref_grouped_gemm,
+                               ref_grouped_gemm_dw, ref_im2col)
 from test_multiplier_properties import _EDGE_BITS
 
 MULT = get_multiplier("afm16")
@@ -214,3 +219,42 @@ def test_nonfinite_tile_falls_back_bitwise(kernel, rng):
     assert np.all(np.isfinite(zero_times_inf))
     np.testing.assert_array_equal(np.asarray(out).view(np.uint32),
                                   np.asarray(ref).view(np.uint32))
+
+
+@pytest.mark.parametrize("kernel", ["gemm", "grouped"])
+@pytest.mark.parametrize("bm,bn", [(128, 128), (64, 256), (32, 512),
+                                   (16, 1024)])
+def test_output_tile_moves_no_bit(kernel, bm, bn, rng):
+    """The output tile is free of the numerics: at one fold (bk 64, chunk
+    8) every tile gives the oracle's bits, for a short m (48 rows), an n
+    that no wide tile divides (640), a B tile holding inf and NaN (the
+    integer form), and the grouped expert kernel in row strips of ``bm``
+    and its weight gradient."""
+    fold = dict(bk=64, chunk=8, interpret=True)
+    lut = get_packed_lut("afm16")
+    mul = lambda x, y: amsim_multiply(x, y, jnp.asarray(LUT, jnp.uint32), 7)
+    if kernel == "gemm":
+        a = jnp.asarray(rng.standard_normal((48, 64)), jnp.float32)
+        b = jnp.asarray(rng.standard_normal((64, 640)), jnp.float32)
+        for b in (b, _plant(_plant(b, (3, 600), np.inf), (5, 130), np.nan)):
+            out = approx_gemm(a, b, lut, 7, bm=bm, bn=bn, **fold)
+            np.testing.assert_array_equal(_bits(out),
+                                          _bits(_chunked_gemm(a, b, mul, 8)))
+        assert not np.isfinite(np.asarray(out)[:, [130, 600]]).all()
+        return
+    E = 4
+    experts = jnp.asarray(rng.choice([0, 1, 3], 200).astype(np.int32))
+    groups = grouped_layout(experts, E)
+    R = grouped_rows_bound(200, E)
+    x = jnp.zeros((R, 64), jnp.float32).at[groups.rows].set(
+        jnp.asarray(rng.standard_normal((200, 64)), jnp.float32))
+    w = jnp.asarray(rng.standard_normal((E, 64, 640)), jnp.float32)
+    g = jnp.zeros((R, 640), jnp.float32).at[groups.rows].set(
+        jnp.asarray(rng.standard_normal((200, 640)), jnp.float32))
+    out = approx_gemm_grouped(x, w, groups, lut, 7, bm=bm, bn=bn, **fold)
+    np.testing.assert_array_equal(
+        _bits(out), _bits(ref_grouped_gemm(x, w, groups, mul, chunk=8)))
+    dw = approx_gemm_grouped_dw(x, g, groups, lut, 7, E, bm=bm, bn=bn,
+                                chunk=8, interpret=True)
+    np.testing.assert_array_equal(
+        _bits(dw), _bits(ref_grouped_gemm_dw(x, g, groups, E, mul, chunk=8)))

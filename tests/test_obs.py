@@ -160,7 +160,67 @@ def test_lut_brick_route_records_the_path_each_launch_compiles():
     approx_gemm(jnp.ones((8, 8)), jnp.ones((8, 8)), get_lut(wide),
                 wide.mantissa_bits, interpret=True)
     assert obs.routes - before == collections.Counter({
-        "lut_brick.factored": 1, "lut_brick.integer.wide": 1})
+        "lut_brick.factored": 1, "lut_brick.integer.wide": 1,
+        "gemm.tile.gemm2d.64x512": 1, "gemm.tile.gemm2d.16x128": 1})
+
+
+def test_gemm_tile_route_records_each_launch_tile():
+    """Each launch of a LUT GEMM records its output tile next to its
+    brick's form (traced, not run): granite-3-2b's MLP forward, the
+    forward's dx and dw, and granite-moe-3b-a800m's grouped expert GEMM
+    and its weight gradient."""
+    import jax.numpy as jnp
+
+    from repro.core.lutgen import get_packed_lut
+    from repro.kernels.approx_gemm import (approx_gemm_grouped,
+                                           approx_gemm_grouped_dw,
+                                           grouped_layout, grouped_rows_bound)
+    before = collections.Counter(obs.routes)
+    x = jax.ShapeDtypeStruct((256, 2048), jnp.float32)
+    w = jax.ShapeDtypeStruct((2048, 8192), jnp.float32)
+    jax.eval_shape(jax.grad(lambda x, w: ops.policy_matmul(
+        x, w, AFM16, "wg").sum(), argnums=(0, 1)), x, w)
+    E, rows = 40, 16384
+    groups = grouped_layout(jnp.zeros((rows,), jnp.int32), E)
+    R = grouped_rows_bound(rows, E)
+    lut = get_packed_lut("afm16")
+    jax.eval_shape(lambda x, w: approx_gemm_grouped(x, w, groups, lut, 7),
+                   jax.ShapeDtypeStruct((R, 1536), jnp.float32),
+                   jax.ShapeDtypeStruct((E, 1536, 512), jnp.float32))
+    jax.eval_shape(lambda x, g: approx_gemm_grouped_dw(x, g, groups, lut, 7,
+                                                       E),
+                   jax.ShapeDtypeStruct((R, 1536), jnp.float32),
+                   jax.ShapeDtypeStruct((R, 512), jnp.float32))
+    routes = obs.routes - before
+    assert {k: v for k, v in routes.items() if k.startswith("gemm.tile")} \
+        == {"gemm.tile.gemm2d.64x512": 3, "gemm.tile.grouped.64x512": 1,
+            "gemm.tile.grouped_dw.64x512": 1}
+    assert routes["lut_brick.factored"] == 5
+
+
+@pytest.mark.parametrize("m,n", [
+    (256, 3072), (256, 2048), (256, 8192), (256, 49155), (2048, 49155),
+    (48, 3072), (64, 2048), (32, 8192), (128, 49155), (1, 40), (96, 40),
+    (256, 640), (16384, 512), (21504, 1536)])
+def test_tile_rule_keeps_the_accumulator_and_the_fold(m, n):
+    """The default 2-D tile at granite's site shapes (forward, dx, dw,
+    prefill, decode, the router, the grouped expert GEMMs): the live
+    accumulator stays within ``ACC_VREGS`` vregs, rows pad only to a
+    bf16 sublane tile, lanes pad n by at most ``LANE_PAD`` of n beyond
+    the 128-lane padding, and the fold (bk, chunk) resolves as it did
+    before the rule, at every k."""
+    from repro.kernels import autotune
+    from repro.kernels.approx_gemm import _resolve
+    bm, bn = autotune.tile_2d(m, n)
+    assert bm * bn // (8 * 128) <= autotune.ACC_VREGS
+    assert bm % 16 == 0 and bm <= min(autotune.MAX_ROWS, -(-m // 16) * 16)
+    n128 = -(-n // 128) * 128
+    assert bn % 128 == 0 and bn <= n128
+    assert -(-n // bn) * bn - n128 <= autotune.LANE_PAD * n
+    for k in (40, 256, 2048, 8192, 49155):
+        got = _resolve("gemm2d", m, k, n, 7, 0, None, None, None, None,
+                       True)
+        assert got[:4] == (bm, bn, 128, 8)
 
 
 # ------------------------------------------------------------- spans

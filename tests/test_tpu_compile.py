@@ -14,6 +14,7 @@ Every launch carries its ``(site, pass)`` tag, in the custom call's
 ``kernel_metadata`` and in its instruction name, and keeps the base name
 the benchmark's trace reduction (bench/trace.py) knows the kernel by.
 """
+import collections
 import sys
 from pathlib import Path
 
@@ -33,6 +34,7 @@ from bench.program_trace import kernel_tag  # noqa: E402
 MULT = get_multiplier("afm16")
 M = MULT.mantissa_bits
 D, H, KV, DH, FF = 2048, 32, 8, 64, 8192
+VOCAB = 49155
 
 
 @pytest.fixture(scope="module")
@@ -95,6 +97,30 @@ def test_gemm_brick(one_chip):
     from repro.kernels.approx_gemm import approx_gemm
     _compile(lambda a, b, lut: approx_gemm(a, b, lut, M, interpret=False),
              one_chip, ((256, D), f32), ((D, FF), f32), _lut_shape())
+
+
+@pytest.mark.parametrize("m,k,n", [
+    (256, D, 3 * D // 2),   # qkv forward: q, k and v of 32 + 2 x 8 heads
+    (256, FF, D),           # wd forward
+    (256, D, VOCAB),        # the tied head: forward,
+    (256, VOCAB, D),        # dx
+    (D, 256, VOCAB),        # and dw
+    (48, D, 3 * D // 2),    # qkv of a 48-row prefill
+], ids=["qkv", "wd", "head.fwd", "head.dx", "head.dw", "prefill48.qkv"])
+def test_gemm_at_the_rule_tile(one_chip, m, k, n):
+    """The 2-D kernel at the output tile ``autotune.tile_2d`` picks for
+    granite-3-2b's sites, the one each launch records: Mosaic takes every
+    such tile (a bf16 one-hot of 16 rows or more, whole 128-lane blocks
+    of B's scratch)."""
+    from repro import obs
+    from repro.kernels import autotune
+    from repro.kernels.approx_gemm import approx_gemm
+    before = collections.Counter(obs.routes)
+    _compile(lambda a, b, lut: approx_gemm(a, b, lut, M, interpret=False),
+             one_chip, ((m, k), f32), ((k, n), f32), _lut_shape())
+    bm, bn = autotune.tile_2d(m, n)
+    assert obs.routes - before == collections.Counter({
+        f"gemm.tile.gemm2d.{bm}x{bn}": 1, "lut_brick.factored": 1})
 
 
 def test_gemm_batched(one_chip):
