@@ -31,22 +31,6 @@ Rows:
                                   traces its decode step exactly once
                                   for the whole stream (asserts, and
                                   fails the bench outright on retrace).
-  serving_chain_toks_per_s        informational: amsim-tier stream with
-                                  the fused decode chain engaged on the
-                                  paged decode ticks
-  serving_perop_toks_per_s        informational: same stream + engine
-                                  shape with REPRO_DECODE_FUSED=0
-  serving_chain_vs_perop_tokens_per_s
-                                  **gated**: chain/per-op wall-time
-                                  ratio under paged continuous batching
-                                  (lower is better; same box, runner
-                                  speed cancels).  Asserts the chain
-                                  actually engaged on the fused side,
-                                  stayed off on the kill-switch side,
-                                  and that both engines served
-                                  identical tokens.  Norm clamps below
-                                  at 0.4 so a fast chain run cannot
-                                  mis-seed the committed baseline.
 
 Both sides are warmed with the same prompt-length buckets first, so the
 comparison is steady-state throughput, not compile time.
@@ -54,7 +38,6 @@ comparison is steady-state throughput, not compile time.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 from pathlib import Path
@@ -70,7 +53,6 @@ import numpy as np
 from benchmarks.common import emit
 from repro.configs import get_arch, reduced
 from repro.core.policy import NumericsPolicy
-from repro.kernels import decode_chain
 from repro.models.transformer import init_lm
 from repro.serve.engine import ServingEngine
 from repro.serve.scheduler import ContinuousBatchingEngine
@@ -82,7 +64,6 @@ _PAGE = 8
 # retraces bounded (and warmed) on both sides.
 _PLENS = (8, 12)
 _CLAMP = 1.0
-_CHAIN_CLAMP = 0.4  # norm floor for the chain-vs-per-op serving ratio
 
 
 def _stream(rng, n, vocab, tier_names):
@@ -148,55 +129,6 @@ def main(smoke: bool = False) -> None:
     assert all(c == 1 for c in counts.values()), counts
     emit("serving_decode_traces", 0.0,
          "_".join(f"{n}{c}" for n, c in sorted(counts.items())) + "_(all_1)")
-
-    # --- fused decode chain vs per-op under paged continuous batching.
-    # Both tiers are amsim (the chain only engages on amsim leaves); the
-    # kill switch is read at lane trace time, so it is pinned around
-    # engine construction + the warm run that traces every lane.
-    am_tiers = {"premium": NumericsPolicy(mode="amsim", multiplier="exact7"),
-                "bulk": NumericsPolicy(mode="amsim",
-                                       multiplier="mitchell8")}
-    am_reqs = _stream(rng, n_reqs, cfg.vocab, sorted(am_tiers))
-
-    def build(fused: bool):
-        prev = os.environ.get("REPRO_DECODE_FUSED")
-        os.environ["REPRO_DECODE_FUSED"] = "1" if fused else "0"
-        try:
-            eng = ContinuousBatchingEngine(cfg, am_tiers, params,
-                                           max_len=max_len,
-                                           capacity=_CAPACITY,
-                                           page_size=_PAGE)
-            out = eng.run(am_reqs)  # warm: traces every lane under env
-        finally:
-            if prev is None:
-                os.environ.pop("REPRO_DECODE_FUSED", None)
-            else:
-                os.environ["REPRO_DECODE_FUSED"] = prev
-        return eng, out
-
-    tr0 = decode_chain.trace_count()
-    cbe_chain, out_chain = build(True)
-    assert decode_chain.trace_count() > tr0, \
-        "paged serving decode tick did not engage the fused chain"
-    tr1 = decode_chain.trace_count()
-    cbe_perop, out_perop = build(False)
-    assert decode_chain.trace_count() == tr1, \
-        "kill switch REPRO_DECODE_FUSED=0 did not disable the chain"
-    assert out_chain == out_perop, \
-        "fused decode chain changed served tokens"
-
-    t_chain = t_perop = float("inf")
-    for _ in range(3 if smoke else 4):
-        t_chain = min(t_chain, once(lambda: cbe_chain.run(am_reqs)))
-        t_perop = min(t_perop, once(lambda: cbe_perop.run(am_reqs)))
-    emit("serving_chain_toks_per_s", t_chain,
-         f"{total / t_chain:.1f}toks_per_s_amsim_chain")
-    emit("serving_perop_toks_per_s", t_perop,
-         f"{total / t_perop:.1f}toks_per_s_amsim_perop")
-    chain_ratio = t_chain / t_perop
-    emit("serving_chain_vs_perop_tokens_per_s", 0.0,
-         f"{1 / chain_ratio:.2f}x_chain_over_perop",
-         norm=max(chain_ratio, _CHAIN_CLAMP), gate=True)
 
 
 if __name__ == "__main__":

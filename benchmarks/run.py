@@ -32,8 +32,7 @@ def _sections(smoke: bool):
     # Smoke (the CI gate) imports only the engine benches; an
     # import-time error in an unused full-run module must not brick it.
     from benchmarks import (bench_attention, bench_batched_gemm,
-                            bench_conv2d, bench_crossformat,
-                            bench_decode_chain, bench_faults,
+                            bench_conv2d, bench_crossformat, bench_faults,
                             bench_policy_table, bench_serving)
 
     if smoke:
@@ -50,8 +49,6 @@ def _sections(smoke: bool):
              lambda: bench_policy_table.main(smoke=True), "kernels"),
             ("Fault-injection seam overhead (smoke)",
              lambda: bench_faults.main(smoke=True), "kernels"),
-            ("Fused decode chain (smoke)",
-             lambda: bench_decode_chain.main(smoke=True), "kernels"),
             ("Continuous-batching serving (smoke)",
              lambda: bench_serving.main(smoke=True), "serving"),
         ]
@@ -72,7 +69,6 @@ def _sections(smoke: bool):
         ("Fused approx-attention engine", bench_attention.main, "kernels"),
         ("Policy-table overhead", bench_policy_table.main, "kernels"),
         ("Fault-injection seam overhead", bench_faults.main, "kernels"),
-        ("Fused decode chain", bench_decode_chain.main, "kernels"),
         ("Continuous-batching serving", bench_serving.main, "serving"),
         ("Fig.10/Table III convergence & accuracy", bench_convergence.main,
          "kernels"),

@@ -8,7 +8,7 @@ that is a *trace-time constant* (core/lutgen.py), a hardware fault in
 the multiplier array is exactly a perturbation of that table — so
 injection is a pure numpy transform applied at the single LUT-closure
 seam in ``kernels/ops.py`` and every kernel family (GEMM / conv /
-attention / decode chain, fused or oracle, sharded or not) inherits it
+attention, fused or oracle, sharded or not) inherits it
 with zero kernel edits.
 
 Fault models (all seeded, reproducible, composable via

@@ -33,7 +33,7 @@ widths.  Two evaluators share one code path:
     ``pipeline_lut`` to emit a table in the *existing* LUT layout
     (uint32 ``(carry << 23) | mantissa_field``, packable to uint16), so
     generated pipelines drop into every kernel family (GEMM / conv /
-    attention / decode chain) with zero kernel edits.
+    attention) with zero kernel edits.
   * ``pipeline_multiply``          — the numpy full-FP32 staged reference
     ("oracle"): sign/exponent algebra + specials around the same mantissa
     pipeline.  In FTZ mode it matches AMSim's special-case semantics

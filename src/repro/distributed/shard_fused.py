@@ -267,8 +267,7 @@ def _overlap_setting(n: int):
       * ``auto`` (default) — chunk the psum 4 ways when the output width
         allows it (n >= 512 and divisible), else the single psum.
       * integer N — chunk N ways (falls back to 1 when N doesn't divide
-        n; the ``decode_chain`` autotune namespace's ``overlap`` knob is
-        applied by exporting its winner here).
+        n).
       * ``ring`` — ppermute-pipelined all-reduce in fixed shard-index
         order (bitwise-deterministic; see ``_ring_psum``).
 

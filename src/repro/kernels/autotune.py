@@ -109,26 +109,6 @@ class AttnBlockConfig:
         return (self.bq, self.bkv, self.chunk)
 
 
-@dataclasses.dataclass(frozen=True)
-class DecodeChainConfig:
-    """One fused decode-chain tiling (kernels/decode_chain.py): ``bn``
-    output-column streaming block for the qkv launch, ``bko`` wo
-    contraction streaming block and ``bf`` d_ff streaming block for the
-    out-mlp launch, ``overlap`` psum chunk count for the sharded row
-    reduce (consumed by distributed/shard_fused when REPRO_OVERLAP_PSUM
-    is ``auto``).  Streaming blocks are free perf knobs: the wrappers
-    snap them to divisors compatible with the oracle fold, so they never
-    affect bit-exactness."""
-
-    bn: int = 128
-    bko: int = 128
-    bf: int = 128
-    overlap: int = 1
-
-    def astuple(self):
-        return (self.bn, self.bko, self.bf, self.overlap)
-
-
 # Fallbacks when no tuned entry exists.  The batched kernel defaults to a
 # deeper k-tile / wider gather brick: one grid point per (batch, m, n) tile
 # amortises kernel-dispatch overhead that the vmapped 2-D path pays per
@@ -159,9 +139,6 @@ DEFAULT_CONV = ConvBlockConfig(8, 128, 64, 128)
 # steps — bkv=128 keeps the value-GEMM brick inside one jnp.sum while
 # still giving block-skip granularity for sliding-window decode.
 DEFAULT_ATTN = AttnBlockConfig(128, 128, 64)
-# Decode-chain default: 128-wide streaming blocks everywhere (one MXU/VPU
-# lane tile per step), no psum chunking.
-DEFAULT_DECODE_CHAIN = DecodeChainConfig(128, 128, 128, 1)
 
 CANDIDATES_2D = [
     BlockConfig(128, 128, 128, 8),
@@ -191,14 +168,6 @@ CANDIDATES_ATTN = [
     AttnBlockConfig(128, 256, 64),
     AttnBlockConfig(256, 128, 64),
 ]
-CANDIDATES_DECODE_CHAIN = [
-    DecodeChainConfig(128, 128, 128, 1),
-    DecodeChainConfig(256, 128, 128, 1),
-    DecodeChainConfig(128, 256, 256, 1),
-    DecodeChainConfig(256, 256, 256, 1),
-    DecodeChainConfig(128, 128, 512, 1),
-    DecodeChainConfig(512, 256, 512, 1),
-]
 
 _MEM: dict[str, BlockConfig | ConvBlockConfig] | None = None  # file mirror
 
@@ -218,9 +187,6 @@ def _parse_entry(e) -> BlockConfig | ConvBlockConfig | AttnBlockConfig | None:
         elif "bq" in e:
             cfg = AttnBlockConfig(int(e["bq"]), int(e["bkv"]),
                                   int(e["chunk"]))
-        elif "bf" in e:
-            cfg = DecodeChainConfig(int(e["bn"]), int(e["bko"]),
-                                    int(e["bf"]), int(e["overlap"]))
         else:
             cfg = BlockConfig(int(e["bm"]), int(e["bn"]),
                               int(e["bk"]), int(e["chunk"]))
@@ -345,22 +311,6 @@ def attn_cache_key(bh: int, s: int, t: int, g: int, dh: int, M: int,
             f"|{_m_tag(M, mult)}")
 
 
-def decode_chain_shape_bucket(rows: int, d: int, k_attn: int,
-                              d_ff: int) -> str:
-    """Decode rows pow2-bucketed (B varies per tick); the model dims are
-    exact — they come from a named config in ``configs/`` and fix both
-    kernels' streaming structure."""
-    return f"r{_pow2_ceil(rows)}_d{d}_k{k_attn}_f{d_ff}"
-
-
-def decode_chain_cache_key(rows: int, d: int, k_attn: int, d_ff: int,
-                           M: int, backend: str | None = None,
-                           mult: str | None = None) -> str:
-    backend = backend or jax.default_backend()
-    bucket = decode_chain_shape_bucket(rows, d, k_attn, d_ff)
-    return f"{backend}|decode_chain|{bucket}|{_m_tag(M, mult)}"
-
-
 # ------------------------------------------------------------------ tile rule
 def tile_2d(m: int, n: int) -> tuple[int, int]:
     """(bm, bn) of an (m, k) @ (k, n) brick launch, from its shape.
@@ -421,15 +371,6 @@ def get_attn_config(bh: int, s: int, t: int, g: int, dh: int, M: int,
     hit = _lookup(lambda mu: attn_cache_key(bh, s, t, g, dh, M, backend, mu),
                   mult)
     return hit if isinstance(hit, AttnBlockConfig) else DEFAULT_ATTN
-
-
-def get_decode_chain_config(rows: int, d: int, k_attn: int, d_ff: int,
-                            M: int, backend: str | None = None,
-                            mult: str | None = None) -> DecodeChainConfig:
-    """Tuned decode-chain tiling for this bucket, or DEFAULT_DECODE_CHAIN."""
-    hit = _lookup(lambda mu: decode_chain_cache_key(rows, d, k_attn, d_ff,
-                                                    M, backend, mu), mult)
-    return hit if isinstance(hit, DecodeChainConfig) else DEFAULT_DECODE_CHAIN
 
 
 # ------------------------------------------------------------------ tuning
@@ -565,49 +506,4 @@ def autotune_attention(q, k, v, q_pos, k_pos, lut, M: int, *,
     if save:
         _save_entry(attn_cache_key(B * KV, S, T, G, dh, M, mult=mult), best,
                     best_t * 1e6)
-    return best
-
-
-def autotune_decode_chain(x, attn, g1, g2, wq, wk, wv, wo, wg, wu, wd,
-                          lut, M: int, *, eps: float = 1e-5,
-                          candidates=None, interpret: bool | None = None,
-                          iters: int = 2, save: bool = True,
-                          mult: str | None = None) -> DecodeChainConfig:
-    """Sweep fused decode-chain streaming blocks (both launches timed
-    together — one cache entry serves the whole chain); cache + return
-    the winner.  ``x`` is the (rows, d) residual stream, ``attn`` the
-    (rows, H*dh) attention output, weights shaped as in a dense block.
-    The ``overlap`` knob is not timed here (it only matters under a
-    mesh); candidates carry it through so a sweep can seed it.
-    Candidates whose streamed blocks overrun the VMEM budget model
-    (kernels/vmem.py) are pruned before timing — the tuner never times
-    a config the dispatch guard would refuse.  Candidates that fail to
-    lower are skipped; if every candidate fails the first failure is
-    raised.
-    """
-    from repro.kernels import vmem  # lazy: vmem imports this module
-    from repro.kernels.decode_chain import fused_out_mlp, fused_qkv_norm
-
-    if candidates is None:
-        candidates = CANDIDATES_DECODE_CHAIN
-    rows, d = x.shape
-    k_attn = attn.shape[1]
-    d_ff = wg.shape[1]
-    candidates = vmem.filter_candidates(
-        [(c.bn, c.bko, c.bf, c.overlap) for c in candidates],
-        rows, d, k_attn, d_ff, M, mult=mult)
-    candidates = [DecodeChainConfig(*c) for c in candidates]
-
-    def run(cfg):
-        q, kk, vv = fused_qkv_norm(x, g1, wq, wk, wv, lut, M, eps=eps,
-                                   bn=cfg.bn, interpret=interpret, mult=mult)
-        out = fused_out_mlp(x, attn, g2, wo, wg, wu, wd, lut, M, eps=eps,
-                            bko=cfg.bko, bf=cfg.bf, interpret=interpret,
-                            mult=mult)
-        return q, kk, vv, out
-
-    best, best_t = _sweep(candidates, run, iters)
-    if save:
-        _save_entry(decode_chain_cache_key(rows, d, k_attn, d_ff, M,
-                                           mult=mult), best, best_t * 1e6)
     return best

@@ -1,7 +1,7 @@
 """Shared bricks for every LUT approx-kernel family (GEMM / conv / attention).
 
-The fused engines (``approx_gemm``, ``approx_conv``, ``approx_attention``,
-``decode_chain``) all reduce to the same inner operation: look up the
+The fused engines (``approx_gemm``, ``approx_conv``, ``approx_attention``)
+all reduce to the same inner operation: look up the
 mantissa-product LUT for every (A, B) operand pair of a rank-``chunk``
 brick and accumulate in FP32 (the paper's AMSim device function inlined
 into the consuming GEMM, §V-B).  This module holds that brick, the LUT
@@ -330,8 +330,7 @@ def _rms_scale(x, eps: float):
 
 
 def rmsnorm_expr(x, g, eps: float):
-    """``(x * rsqrt(mean(x^2) + eps)) * g`` — THE RMSNorm forward, shared
-    verbatim by the decode-chain kernels, their oracles and
+    """``(x * rsqrt(mean(x^2) + eps)) * g`` — THE RMSNorm forward of
     models/layers.rmsnorm (bit-for-bit on one backend)."""
     return (x * _rms_scale(x, eps)) * g
 
@@ -344,8 +343,8 @@ def rms_norm(x, g, eps: float):
     Autodiff's own backward leaves two choices to the compiler, and
     XLA:CPU makes them per fusion: it contracts a multiply feeding an add
     into one FMA, and it reassociates a fused reduction.  The same
-    gradient then differed in its last bits between the decode chain's
-    recompute-through-oracle VJP and plain autodiff of the oracle.  Here
+    gradient then differed in its last bits between a VJP that recomputes
+    through the forward and plain autodiff of it.  Here
     every product is materialised (``optimization_barrier``) before it
     meets an add or a reduction, so neither choice exists.
     """

@@ -53,7 +53,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, PartitionSpec as P
 
-from repro import obs
 from repro.core import faults
 from repro.core.amsim import amsim_multiply
 from repro.core.float_bits import jnp_truncate_mantissa, jnp_round_mantissa
@@ -62,7 +61,7 @@ from repro.core.multipliers import get_multiplier
 from repro.core.policy import PASSES, Numerics, NumericsPolicy
 from repro.kernels.approx_attention import (NEG_INF, approx_attention_fused,
                                             attention_fused_supported)
-from repro.kernels.common import attention_mask, best_chunk, rms_norm
+from repro.kernels.common import attention_mask, best_chunk
 from repro.kernels.approx_conv import (approx_conv2d_dw, approx_conv2d_fused,
                                        conv_pads, fused_supported)
 from repro.kernels.approx_gemm import (GroupedRows, approx_gemm,
@@ -102,8 +101,8 @@ def _amsim_lut(mult):
     This is the **fault-injection seam** (core/faults.py): when a fault
     spec is active (REPRO_FAULTS or faults.inject), the table is
     perturbed here — once, at trace time — so every kernel family that
-    closes over a LUT (GEMM, conv fwd/dw/dx, fused attention, decode
-    chain, and all their sharded forms) inherits the faults with zero
+    closes over a LUT (GEMM, conv fwd/dw/dx, fused attention, and all
+    their sharded forms) inherits the faults with zero
     kernel edits.  Off (the default) returns the cached array object
     untouched: bitwise-identical traces, zero copies.
     """
@@ -255,8 +254,8 @@ def _mm_fwd(a, b, policy, site=None):
 
 
 # Sites whose second operand is a *parameter* even when it is a stacked
-# 3-D bank: the MoE expert FFN runs (E, C, d) @ (E, d, d_ff), so its
-# weight matmuls take the equal-batch layout that is otherwise an
+# 3-D bank: an expert FFN over a bank runs (E, C, d) @ (E, d, d_ff), so
+# its weight matmuls take the equal-batch layout that is otherwise an
 # activation-activation contraction (attention scores, SSD einsums).
 # Their db is a weight gradient and must resolve under the dw pass —
 # without this set, a table's dw rule would silently skip MoE experts.
@@ -766,441 +765,3 @@ def _pattn_bwd(policy, causal, window, res, g):
 
 
 policy_attention.defvjp(_pattn_fwd, _pattn_bwd)
-
-
-# =====================================================================
-# Fused decode chain (whole-layer persistent kernels)
-#
-# kernels/decode_chain.py fuses a dense block's qkv-projection front
-# half and wo->rmsnorm->FFN back half into one persistent launch each
-# (LUT + activations VMEM-resident, weights streamed).  This section is
-# the dispatch seam, mirroring the conv/attention structure: a leaf
-# resolver, an enable guard (kill switch ``REPRO_DECODE_FUSED=0``), and
-# custom-VJP wrappers whose backward recomputes through the unfused
-# policy_matmul chain — the oracle the fused forward is bit-tested
-# against — so gradients are identical to the per-op lowering.
-# models/transformer.py routes single-token dense decode blocks here.
-# =====================================================================
-
-_CHAIN_SITES = ("qkv", "wo", "wg", "wu", "wd")
-
-
-def decode_chain_leaf(policy: Numerics) -> NumericsPolicy | None:
-    """The single forward leaf the chain kernels would run EVERY
-    projection under, or None when the policy resolves any two chain
-    sites differently (the kernels bake one LUT; a heterogeneous table
-    forces the per-op lowering)."""
-    leaves = [policy.resolve(s) for s in _CHAIN_SITES]
-    first = leaves[0]
-    for leaf in leaves[1:]:
-        if (leaf.mode, leaf.multiplier) != (first.mode, first.multiplier):
-            return None
-    return first
-
-
-def decode_chain_enabled(policy: Numerics, rows: int, d: int,
-                         k_attn: int, d_ff: int, *,
-                         moe: bool = False) -> bool:
-    """Dispatch guard for the fused decode chain: every chain site must
-    resolve to the same amsim leaf, killable via REPRO_DECODE_FUSED=0,
-    no active shard_fused mesh dispatch (the sharded per-op path owns
-    Megatron partitioning; under a mesh with REPRO_SHARD_FUSED=0 the
-    chain engages with GSPMD-replicated lowering), and the shape must
-    pass the VMEM budget model (kernels/vmem.py).  ``moe=True`` prices
-    the MoE back half (qkv + wo->norm launches; the expert-bank FFN
-    launch has its own guard, :func:`decode_moe_ffn_enabled`) instead of
-    the dense out-mlp launch.  Each decision is recorded, with its
-    reason, in ``obs.routes`` under "decode_chain.*"."""
-    leaf = decode_chain_leaf(policy)
-    if leaf is None or leaf.mode != "amsim" or leaf.is_native:
-        return obs.route("decode_chain", "refused.policy")
-    if os.environ.get("REPRO_DECODE_FUSED", "1").lower() in ("0", "false"):
-        return obs.route("decode_chain", "refused.env")
-    from repro.distributed import shard_fused  # lazy: circular import
-    if shard_fused.active_mesh(leaf) is not None:
-        return obs.route("decode_chain", "refused.mesh")
-    from repro.kernels import vmem
-    mult = get_multiplier(leaf.multiplier)
-    if moe:
-        fits = vmem.moe_chain_fits(rows, d, k_attn, mult.mantissa_bits,
-                                   mult=mult.name)
-    else:
-        fits = vmem.chain_fits(rows, d, k_attn, d_ff,
-                               mult.mantissa_bits, mult=mult.name)
-    return obs.route("decode_chain", "taken" if fits else "refused.vmem")
-
-
-_MOE_FFN_SITES = ("wg", "wu", "wd")
-
-
-def moe_ffn_leaf(policy: Numerics) -> NumericsPolicy | None:
-    """The single leaf the stacked expert-bank launch would run wg/wu/wd
-    under, or None when they resolve differently (the router site stays
-    per-op either way, so it may differ freely)."""
-    leaves = [policy.resolve(s) for s in _MOE_FFN_SITES]
-    first = leaves[0]
-    for leaf in leaves[1:]:
-        if (leaf.mode, leaf.multiplier) != (first.mode, first.multiplier):
-            return None
-    return first
-
-
-def decode_moe_ffn_enabled(policy: Numerics, E: int, C: int, d: int,
-                           d_ff: int) -> bool:
-    """Dispatch guard for the stacked expert-bank FFN launch
-    (kernels/decode_chain.fused_moe_ffn).  Shares the chain's kill
-    switch and mesh exclusion; the shape gate is vmem.moe_ffn_fits.
-    models/moe.py asks on decode ticks only, with C the tick's row
-    count, so the bank drops no token."""
-    leaf = moe_ffn_leaf(policy)
-    if leaf is None or leaf.mode != "amsim" or leaf.is_native:
-        return False
-    if os.environ.get("REPRO_DECODE_FUSED", "1").lower() in ("0", "false"):
-        return False
-    from repro.distributed import shard_fused  # lazy: circular import
-    if shard_fused.active_mesh(leaf) is not None:
-        return False
-    from repro.kernels import vmem
-    mult = get_multiplier(leaf.multiplier)
-    return vmem.moe_ffn_fits(E, C, d, d_ff, mult.mantissa_bits,
-                             mult=mult.name)
-
-
-def decode_qkv_oracle(x, g1, wq, wk, wv, policy: Numerics, eps: float):
-    """Unfused reference for the chain's front half: rmsnorm + three
-    per-op projections, exactly what models/layers runs when the chain
-    is off.  The fused forward is bit-tested against this, and the
-    fused VJP recomputes through it."""
-    h = rms_norm(x.astype(jnp.float32), g1, eps)
-    return (policy_matmul(h, wq, policy, "qkv"),
-            policy_matmul(h, wk, policy, "qkv"),
-            policy_matmul(h, wv, policy, "qkv"))
-
-
-def decode_out_mlp_oracle(x, attn, g2, wo, wg, wu, wd, policy: Numerics,
-                          eps: float, bo=None, bd=None):
-    """Unfused reference for the chain's back half: wo projection +
-    residual + rmsnorm + swiglu FFN + residual, per-op.  Optional wo/wd
-    epilogue biases are added before the residual, matching
-    models/layers.linear's op order."""
-    yo = policy_matmul(attn.astype(jnp.float32), wo, policy, "wo")
-    if bo is not None:
-        yo = yo + bo
-    x1 = x.astype(jnp.float32) + yo
-    h = rms_norm(x1, g2, eps)
-    y = policy_matmul(
-        jax.nn.silu(policy_matmul(h, wg, policy, "wg"))
-        * policy_matmul(h, wu, policy, "wu"),
-        wd, policy, "wd")
-    if bd is not None:
-        y = y + bd
-    return x1 + y
-
-
-def decode_wo_norm_oracle(x, attn, g2, wo, bo, policy: Numerics, eps: float):
-    """Unfused reference for the MoE back half's shared prefix:
-    x1 = x + (attn @ wo [+ bo]); h = rmsnorm(x1).  Returns (x1, h)."""
-    yo = policy_matmul(attn.astype(jnp.float32), wo, policy, "wo")
-    if bo is not None:
-        yo = yo + bo
-    x1 = x.astype(jnp.float32) + yo
-    return x1, rms_norm(x1, g2, eps)
-
-
-def decode_moe_ffn_oracle(buf, wg, wu, wd, policy: Numerics):
-    """Unfused reference for the stacked expert-bank launch: exactly
-    what models/mlp.ffn runs on the (E, C, d) capacity buffer without a
-    mesh — three E-batched policy GEMMs (gemm3d bucket) under the
-    wg/wu/wd sites.  Expert banks carry no biases (init_ffn default)."""
-    return policy_matmul(
-        jax.nn.silu(policy_matmul(buf, wg, policy, "wg"))
-        * policy_matmul(buf, wu, policy, "wu"),
-        wd, policy, "wd")
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def decode_qkv(x, g1, wq, wk, wv, policy: Numerics, eps: float):
-    """rmsnorm(x; g1) + q/k/v projections in one persistent launch.
-
-    x (rows, d); returns (q, k, v) f32.  Backward recomputes through
-    :func:`decode_qkv_oracle` (jax.vjp), so each backward GEMM runs
-    under the numerics the policy resolves for the qkv site's dx/dw
-    passes — bit-identical to the per-op lowering's gradients.  Callers
-    must have checked :func:`decode_chain_enabled`.
-    """
-    return _decode_qkv_fwd_impl(x, g1, wq, wk, wv, policy, eps)
-
-
-def _decode_qkv_fwd_impl(x, g1, wq, wk, wv, policy, eps):
-    from repro.kernels.decode_chain import fused_qkv_norm
-    mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return _replicated(
-        lambda *a: fused_qkv_norm(*a, _amsim_lut(mult), mult.mantissa_bits,
-                                  eps=eps, mult=mult.name),
-        x, g1, wq, wk, wv)
-
-
-def _decode_qkv_fwd(x, g1, wq, wk, wv, policy, eps):
-    out = _decode_qkv_fwd_impl(x, g1, wq, wk, wv, policy, eps)
-    return out, (x, g1, wq, wk, wv)
-
-
-def _decode_qkv_bwd(policy, eps, res, g):
-    x, g1, wq, wk, wv = res
-    _, vjp = jax.vjp(
-        lambda *args: decode_qkv_oracle(*args, policy, eps),
-        x, g1, wq, wk, wv)
-    return vjp(tuple(c.astype(jnp.float32) for c in g))
-
-
-decode_qkv.defvjp(_decode_qkv_fwd, _decode_qkv_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def decode_out_mlp(x, attn, g2, wo, wg, wu, wd, policy: Numerics,
-                   eps: float):
-    """wo projection + residual + rmsnorm + swiglu FFN + residual in one
-    persistent launch.  x (rows, d) residual stream, attn (rows, H*dh).
-    Backward recomputes through :func:`decode_out_mlp_oracle`.  Callers
-    must have checked :func:`decode_chain_enabled`.
-    """
-    return _decode_out_mlp_fwd_impl(x, attn, g2, wo, wg, wu, wd, policy,
-                                    eps)
-
-
-def _decode_out_mlp_fwd_impl(x, attn, g2, wo, wg, wu, wd, policy, eps):
-    from repro.kernels.decode_chain import fused_out_mlp
-    mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return _replicated(
-        lambda *a: fused_out_mlp(*a, _amsim_lut(mult), mult.mantissa_bits,
-                                 eps=eps, mult=mult.name),
-        x, attn, g2, wo, wg, wu, wd)
-
-
-def _decode_out_mlp_fwd(x, attn, g2, wo, wg, wu, wd, policy, eps):
-    out = _decode_out_mlp_fwd_impl(x, attn, g2, wo, wg, wu, wd, policy, eps)
-    return out, (x, attn, g2, wo, wg, wu, wd)
-
-
-def _decode_out_mlp_bwd(policy, eps, res, g):
-    x, attn, g2, wo, wg, wu, wd = res
-    _, vjp = jax.vjp(
-        lambda *args: decode_out_mlp_oracle(*args, policy, eps),
-        x, attn, g2, wo, wg, wu, wd)
-    return vjp(g.astype(jnp.float32))
-
-
-decode_out_mlp.defvjp(_decode_out_mlp_fwd, _decode_out_mlp_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(9, 10))
-def decode_out_mlp_b(x, attn, g2, wo, wg, wu, wd, bo, bd, policy: Numerics,
-                     eps: float):
-    """:func:`decode_out_mlp` with optional wo/wd epilogue biases (None
-    when absent).  Biases are folded into the launch's accumulator
-    epilogues — added before each residual, the per-op op order — and
-    the bias-free call lowers the identical kernel (statically absent
-    operands, not zero-valued ones, so no-bias outputs stay bitwise
-    against the historical launch)."""
-    return _decode_out_mlp_b_fwd_impl(x, attn, g2, wo, wg, wu, wd, bo, bd,
-                                      policy, eps)
-
-
-def _decode_out_mlp_b_fwd_impl(x, attn, g2, wo, wg, wu, wd, bo, bd,
-                               policy, eps):
-    from repro.kernels.decode_chain import fused_out_mlp
-    mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return _replicated(
-        lambda *a, bo, bd: fused_out_mlp(
-            *a, _amsim_lut(mult), mult.mantissa_bits, eps=eps, bo=bo, bd=bd,
-            mult=mult.name),
-        x, attn, g2, wo, wg, wu, wd, bo=bo, bd=bd)
-
-
-def _decode_out_mlp_b_fwd(x, attn, g2, wo, wg, wu, wd, bo, bd, policy, eps):
-    out = _decode_out_mlp_b_fwd_impl(x, attn, g2, wo, wg, wu, wd, bo, bd,
-                                     policy, eps)
-    return out, (x, attn, g2, wo, wg, wu, wd, bo, bd)
-
-
-def _decode_out_mlp_b_bwd(policy, eps, res, g):
-    x, attn, g2, wo, wg, wu, wd, bo, bd = res
-    _, vjp = jax.vjp(
-        lambda *args: decode_out_mlp_oracle(*args[:7], policy, eps,
-                                            bo=args[7], bd=args[8]),
-        x, attn, g2, wo, wg, wu, wd, bo, bd)
-    return vjp(g.astype(jnp.float32))
-
-
-decode_out_mlp_b.defvjp(_decode_out_mlp_b_fwd, _decode_out_mlp_b_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def decode_wo_norm(x, attn, g2, wo, bo, policy: Numerics, eps: float):
-    """The MoE back half's shared prefix in one persistent launch:
-    x1 = x + (attn @ wo [+ bo]); h = rmsnorm(x1; g2); returns (x1, h).
-
-    Same fold as :func:`decode_out_mlp`'s phase A (bit-tested against
-    :func:`decode_wo_norm_oracle`); the router/top-k/scatter that
-    consume h stay per-op in models/moe.py.  Backward recomputes through
-    the oracle.  Callers must have checked
-    ``decode_chain_enabled(..., moe=True)``.
-    """
-    return _decode_wo_norm_fwd_impl(x, attn, g2, wo, bo, policy, eps)
-
-
-def _decode_wo_norm_fwd_impl(x, attn, g2, wo, bo, policy, eps):
-    from repro.kernels.decode_chain import fused_wo_norm
-    mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return _replicated(
-        lambda *a, bo: fused_wo_norm(*a, _amsim_lut(mult), mult.mantissa_bits,
-                                     eps=eps, bo=bo, mult=mult.name),
-        x, attn, g2, wo, bo=bo)
-
-
-def _decode_wo_norm_fwd(x, attn, g2, wo, bo, policy, eps):
-    out = _decode_wo_norm_fwd_impl(x, attn, g2, wo, bo, policy, eps)
-    return out, (x, attn, g2, wo, bo)
-
-
-def _decode_wo_norm_bwd(policy, eps, res, g):
-    x, attn, g2, wo, bo = res
-    _, vjp = jax.vjp(
-        lambda *args: decode_wo_norm_oracle(*args, policy, eps),
-        x, attn, g2, wo, bo)
-    return vjp(tuple(c.astype(jnp.float32) for c in g))
-
-
-decode_wo_norm.defvjp(_decode_wo_norm_fwd, _decode_wo_norm_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def decode_moe_ffn(buf, wg, wu, wd, policy: Numerics):
-    """Stacked expert-bank swiglu FFN in one persistent launch: buf is
-    the scattered (E, C, d) capacity buffer, wg/wu (E, d, d_ff) and
-    wd (E, d_ff, d) the expert banks.  Bit-identical to the E-batched
-    per-op lowering (:func:`decode_moe_ffn_oracle` — the gemm3d folds
-    are slaved to ``approx_gemm_batched``'s bucket); backward recomputes
-    through the oracle.  Callers must have checked
-    :func:`decode_moe_ffn_enabled`.
-    """
-    return _decode_moe_ffn_fwd_impl(buf, wg, wu, wd, policy)
-
-
-def _decode_moe_ffn_fwd_impl(buf, wg, wu, wd, policy):
-    from repro.kernels.decode_chain import fused_moe_ffn
-    mult = get_multiplier(moe_ffn_leaf(policy).multiplier)
-    return _replicated(
-        lambda *a: fused_moe_ffn(*a, _amsim_lut(mult), mult.mantissa_bits,
-                                 mult=mult.name),
-        buf, wg, wu, wd)
-
-
-def _decode_moe_ffn_fwd(buf, wg, wu, wd, policy):
-    out = _decode_moe_ffn_fwd_impl(buf, wg, wu, wd, policy)
-    return out, (buf, wg, wu, wd)
-
-
-def _decode_moe_ffn_bwd(policy, res, g):
-    buf, wg, wu, wd = res
-    _, vjp = jax.vjp(
-        lambda *args: decode_moe_ffn_oracle(*args, policy),
-        buf, wg, wu, wd)
-    return vjp(g.astype(jnp.float32))
-
-
-decode_moe_ffn.defvjp(_decode_moe_ffn_fwd, _decode_moe_ffn_bwd)
-
-
-def decode_fuse_attn_enabled(policy: Numerics, rows: int, d: int,
-                             k_attn: int, d_ff: int, T: int, KV: int,
-                             dh: int) -> bool:
-    """Dispatch guard for collapsing the attention core INTO the
-    back-half launch (three chain launches -> two,
-    kernels/decode_chain.fused_attn_out_mlp).  On top of the chain's own
-    guard (callers check :func:`decode_chain_enabled` first) this
-    requires the attention sites to resolve to the SAME leaf as the
-    chain sites (the launch bakes one LUT for all seven GEMMs), honours
-    REPRO_ATTN_FUSED=0 (the attention core stays per-op / standalone)
-    and its own kill switch REPRO_DECODE_FUSE_ATTN=0, and asks the VMEM
-    budget model whether the K/V views fit next to the back half's
-    working set in the single-KV-block bitwise regime
-    (vmem.fuse_attention_ok)."""
-    leaf = decode_chain_leaf(policy)
-    if leaf is None or leaf.mode != "amsim" or leaf.is_native:
-        return False
-    aleaf = attention_fused_leaf(policy)
-    if aleaf is None or (aleaf.mode, aleaf.multiplier) != \
-            (leaf.mode, leaf.multiplier):
-        return False
-    if os.environ.get("REPRO_DECODE_FUSED", "1").lower() in ("0", "false"):
-        return False
-    if os.environ.get("REPRO_ATTN_FUSED", "1").lower() in ("0", "false"):
-        return False
-    if os.environ.get("REPRO_DECODE_FUSE_ATTN", "1").lower() in \
-            ("0", "false"):
-        return False
-    from repro.kernels import vmem
-    mult = get_multiplier(leaf.multiplier)
-    return vmem.fuse_attention_ok(rows, d, k_attn, d_ff, rows, T, KV, dh,
-                                  mult.mantissa_bits, mult=mult.name)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(13, 14, 15, 16))
-def decode_attn_out_mlp(x, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd,
-                        bo, bd, policy: Numerics, eps: float,
-                        causal: bool, window: int):
-    """Attention core + the whole dense back half in ONE persistent
-    launch (the chain's launches 2 and 3 collapsed).  x (rows, d)
-    residual stream; q (B, 1, H, dh) RoPE'd queries; k/v (B, T, KV, dh)
-    post-update cache views; positions shared or per-row as
-    ``attend_einsum``.  Bit-identical to the 3-launch chain AND the
-    per-op path in the guard's single-KV-block regime; backward
-    recomputes through ``attend_einsum`` + :func:`decode_out_mlp_oracle`
-    (jax.vjp), so gradients take exactly the per-op lowering.  Callers
-    must have checked :func:`decode_fuse_attn_enabled`.
-    """
-    return _decode_attn_out_mlp_fwd_impl(x, q, k, v, q_pos, k_pos, g2, wo,
-                                         wg, wu, wd, bo, bd, policy, eps,
-                                         causal, window)
-
-
-def _decode_attn_out_mlp_fwd_impl(x, q, k, v, q_pos, k_pos, g2, wo, wg, wu,
-                                  wd, bo, bd, policy, eps, causal, window):
-    from repro.kernels.decode_chain import fused_attn_out_mlp
-    mult = get_multiplier(decode_chain_leaf(policy).multiplier)
-    return _replicated(
-        lambda *a, bo, bd: fused_attn_out_mlp(
-            *a, _amsim_lut(mult), mult.mantissa_bits, eps=eps, causal=causal,
-            window=int(window), bo=bo, bd=bd, mult=mult.name),
-        x, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd, bo=bo, bd=bd)
-
-
-def _decode_attn_out_mlp_fwd(x, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd,
-                             bo, bd, policy, eps, causal, window):
-    out = _decode_attn_out_mlp_fwd_impl(x, q, k, v, q_pos, k_pos, g2, wo,
-                                        wg, wu, wd, bo, bd, policy, eps,
-                                        causal, window)
-    return out, (x, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd, bo, bd)
-
-
-def _decode_attn_out_mlp_bwd(policy, eps, causal, window, res, g):
-    x, q, k, v, q_pos, k_pos, g2, wo, wg, wu, wd, bo, bd = res
-    B, S, H, dh = q.shape
-
-    def f(x_, q_, k_, v_, g2_, wo_, wg_, wu_, wd_, bo_, bd_):
-        a = attend_einsum(q_, k_, v_, q_pos, k_pos, policy,
-                          causal=causal, window=window)
-        return decode_out_mlp_oracle(x_, a.reshape(B * S, H * dh), g2_,
-                                     wo_, wg_, wu_, wd_, policy, eps,
-                                     bo=bo_, bd=bd_)
-
-    _, vjp = jax.vjp(f, x, q, k, v, g2, wo, wg, wu, wd, bo, bd)
-    dx, dq, dk, dv, dg2, dwo, dwg, dwu, dwd, dbo, dbd = \
-        vjp(g.astype(jnp.float32))
-    zero = lambda p: np.zeros(p.shape, jax.dtypes.float0)  # int positions
-    return (dx, dq, dk, dv, zero(q_pos), zero(k_pos), dg2, dwo, dwg, dwu,
-            dwd, dbo, dbd)
-
-
-decode_attn_out_mlp.defvjp(_decode_attn_out_mlp_fwd, _decode_attn_out_mlp_bwd)

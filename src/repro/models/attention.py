@@ -244,8 +244,7 @@ def _paged_cache_update(cache, k, v, q_pos):
 def attention(p, x, cfg: ArchConfig, policy: Numerics, *,
               kv_src=None, causal=True, q_offset=0, cache=None,
               window: int = 0, q_chunk: int | None = None,
-              use_rope: bool = True, qkv=None, project_out: bool = True,
-              capture_attend: bool = False):
+              use_rope: bool = True):
     """Full attention block.  Returns (out, new_cache).
 
     kv_src: encoder states for cross-attention (no rope, no cache update
@@ -253,23 +252,6 @@ def attention(p, x, cfg: ArchConfig, policy: Numerics, *,
     cache:  {"k","v": (B, Tmax, KV, dh), "len": int32} for decode (ring
             buffer), or a paged-cache dict carrying a ``ptab`` page
             table (``_paged_cache_update``; serve/paged_cache.py).
-    qkv:    optional pre-computed (q, k, v) projections, shaped
-            (B, S, H, dh) / (B, S, KV, dh), *before* rope — the fused
-            decode chain (kernels/decode_chain.py) computes them in its
-            persistent qkv launch and hands them in here so rope, cache
-            update and the score/value lowering stay shared.  Mutually
-            exclusive with ``kv_src``.
-    project_out: when False, return the pre-``wo`` context
-            (B, S, H*dh) — the fused decode chain folds the output
-            projection into its out-mlp launch.
-    capture_attend: when True, stop AFTER rope + cache update and
-            return ``((q, k, v, q_pos, k_pos), new_cache)`` — the RoPE'd
-            queries, the post-update full K/V views and both position
-            vectors — instead of attending.  This is the decode chain's
-            2-launch hook (ops.decode_attn_out_mlp): the attention core
-            runs INSIDE the back-half launch, while rope and the cache
-            update stay shared here.  Callers are responsible for having
-            checked ``ops.decode_fuse_attn_enabled``.
     """
     B, S, d = x.shape
     H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -278,19 +260,14 @@ def attention(p, x, cfg: ArchConfig, policy: Numerics, *,
     # each runs the fused LUT kernel per shard (distributed/shard_fused).
     # Numerics sites: projections are "qkv"/"wo"; the score/value
     # contractions below resolve "attn_score"/"attn_value".
-    if qkv is not None:
-        assert kv_src is None, "qkv= is decoder self-attention only"
-        q, k, v = qkv
-        Tsrc = k.shape[1]
-    else:
-        q = linear(p["wq"], x, policy, kind="column",
-                   site="qkv").reshape(B, S, H, dh)
-        src = x if kv_src is None else kv_src
-        Tsrc = src.shape[1]
-        k = linear(p["wk"], src, policy, kind="column",
-                   site="qkv").reshape(B, Tsrc, KV, dh)
-        v = linear(p["wv"], src, policy, kind="column",
-                   site="qkv").reshape(B, Tsrc, KV, dh)
+    q = linear(p["wq"], x, policy, kind="column",
+               site="qkv").reshape(B, S, H, dh)
+    src = x if kv_src is None else kv_src
+    Tsrc = src.shape[1]
+    k = linear(p["wk"], src, policy, kind="column",
+               site="qkv").reshape(B, Tsrc, KV, dh)
+    v = linear(p["wv"], src, policy, kind="column",
+               site="qkv").reshape(B, Tsrc, KV, dh)
 
     paged = cache is not None and "ptab" in cache
     if paged:
@@ -346,9 +323,6 @@ def attention(p, x, cfg: ArchConfig, policy: Numerics, *,
     else:
         k_pos = jnp.arange(Tsrc, dtype=jnp.int32) if kv_src is not None else q_pos
 
-    if capture_attend:
-        return (q, k, v, q_pos, k_pos), cache
-
     # Dispatch decision, made ONCE here and passed down: both kernel
     # lowerings ("fused" single-device, "sharded" per-shard) block q
     # internally (the q-block grid axis), so the memory-side motivation
@@ -368,9 +342,7 @@ def attention(p, x, cfg: ArchConfig, policy: Numerics, *,
         # kernel).  Off-mesh, the single-device one-launch kernel
         # accepts per-row positions directly (its mask/liveness
         # operands grow a leading batch axis), so paged serving decode
-        # ticks run the same fused attention core as the ring layout —
-        # this is what lets ContinuousBatchingEngine ticks take the
-        # persistent decode chain end to end.
+        # ticks run the same fused attention core as the ring layout.
         leaf = attention_fused_leaf(policy)
         mesh = shard_fused.active_mesh(leaf) if leaf is not None else None
         if mesh is None and fused_attention_enabled(
@@ -419,8 +391,6 @@ def attention(p, x, cfg: ArchConfig, policy: Numerics, *,
     else:
         out = attend(q, q_pos)
     out = out.reshape(B, S, H * dh)
-    if not project_out:
-        return out, cache
     return linear(p["wo"], out, policy, kind="row", site="wo"), cache
 
 

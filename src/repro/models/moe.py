@@ -14,13 +14,8 @@ FFN is three grouped GEMMs over those rows (``ops.grouped_matmul``, sites
 their expert's weights.  The results are gathered back to assignment
 order and summed per token in choice order.
 
-On a decode tick (one token per sequence) under a homogeneous amsim
-wg/wu/wd leaf the experts can instead run in one persistent stacked-bank
-launch (kernels/decode_chain.fused_moe_ffn) over an (E, C, d) buffer
-whose capacity C is the tick's row count, so it cannot drop either.
-Each choice is recorded in ``obs.routes`` ("moe.grouped.taken" or
-"moe.grouped.refused.decode").  The two give the same bits: the grouped
-kernel folds every row as the E-batched kernel the launch is slaved to.
+Decode ticks (one token per sequence) take the grouped kernel too.
+Each call counts "moe.grouped.taken" in ``obs.routes`` at trace time.
 
 An auxiliary load-balance loss (Switch-style) is returned for training.
 """
@@ -69,17 +64,6 @@ def _grouped_ffn(ew, xs, groups, policy: NumericsPolicy, act: str):
     return mm(h, "wd")
 
 
-def _decode_bank(ew, cfg: ArchConfig, policy: NumericsPolicy, S: int,
-                 C: int) -> bool:
-    """Whether this call's experts run in the stacked-bank launch over a
-    capacity-``C`` buffer."""
-    m = cfg.moe
-    return (S == 1 and cfg.act == "swiglu" and "wg" in ew
-            and not any("b" in ew[s] for s in ("wg", "wu", "wd"))
-            and ops.decode_moe_ffn_enabled(policy, m.n_experts, C,
-                                           cfg.d_model, m.d_ff))
-
-
 def moe_ffn(p, x, cfg: ArchConfig, policy: NumericsPolicy):
     """x (B, S, d) -> (y (B, S, d), aux_loss scalar)."""
     m = cfg.moe
@@ -91,33 +75,20 @@ def moe_ffn(p, x, cfg: ArchConfig, policy: NumericsPolicy):
     with jax.named_scope("moe.router"):
         gate, sel, probs = route(p["router"], xf, cfg, policy)
 
-    ew = p["experts"]
-    C = -(-T // 8) * 8                # a bank's capacity: every row fits
-    bank = _decode_bank(ew, cfg, policy, S, C)
-    obs.route("moe.grouped", "refused.decode" if bank else "taken")
+    obs.route("moe.grouped", "taken")
     with jax.named_scope("moe.dispatch"):
         e_flat = sel.reshape(-1)                          # (T*k,) token-major
         tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
         groups = grouped_layout(e_flat, E)
-        if bank:
-            slot = groups.rows - (jnp.cumsum(groups.sizes)
-                                  - groups.sizes)[e_flat]
-            xs = jnp.zeros((E, C, d), xf.dtype).at[e_flat, slot].set(xf[tok])
-        else:
-            R = grouped_rows_bound(T * k, E)
-            src = jnp.full((R,), T, jnp.int32).at[groups.rows].set(tok)
-            xs = jnp.take(xf, src, axis=0, mode="fill", fill_value=0)
+        R = grouped_rows_bound(T * k, E)
+        src = jnp.full((R,), T, jnp.int32).at[groups.rows].set(tok)
+        xs = jnp.take(xf, src, axis=0, mode="fill", fill_value=0)
 
     with jax.named_scope("moe.experts"):
-        if bank:
-            out = ops.decode_moe_ffn(xs, ew["wg"]["w"], ew["wu"]["w"],
-                                     ew["wd"]["w"], policy)
-        else:
-            out = _grouped_ffn(ew, xs, groups, policy, cfg.act)
+        out = _grouped_ffn(p["experts"], xs, groups, policy, cfg.act)
 
     with jax.named_scope("moe.combine"):
-        got = out[e_flat, slot] if bank else out[groups.rows]
-        got = got.reshape(T, k, d)
+        got = out[groups.rows].reshape(T, k, d)
         y = gate[:, 0, None] * got[:, 0]
         for j in range(1, k):
             y = y + gate[:, j, None] * got[:, j]

@@ -49,8 +49,6 @@ def _init_dense_layer(key, cfg: ArchConfig, use_moe: bool):
 
 
 def _dense_block(p, x, cfg, policy, cache, window):
-    if _use_fused_decode_chain(p, x, cfg, policy, cache):
-        return _dense_block_fused_decode(p, x, cfg, policy, cache, window)
     a, cache = attention(p["attn"], rmsnorm(p["n1"], x, cfg.norm_eps), cfg,
                          policy, cache=cache, window=window)
     x = x + a
@@ -60,86 +58,6 @@ def _dense_block(p, x, cfg, policy, cache, window):
     else:
         y, aux = ffn(p["ffn"], h, policy, cfg.act), 0.0
     return x + y, cache, aux
-
-
-def _use_fused_decode_chain(p, x, cfg, policy, cache) -> bool:
-    """Trace-time dispatch for the persistent fused decode chain
-    (kernels/decode_chain.py): single-token decode (dense or MoE) under
-    a homogeneous amsim policy, no sharded per-op mesh dispatch
-    (``ops.decode_chain_enabled``, kill switch REPRO_DECODE_FUSED=0),
-    shape under the VMEM budget model (kernels/vmem.py).  Swiglu-only:
-    the back-half launches bake the gate/up/down structure.  Epilogue
-    biases on wo/wd are folded into the launch epilogues (statically
-    gated operands), so they no longer force the per-op path.
-    """
-    B, S, d = x.shape
-    if cache is None or S != 1 or cfg.act != "swiglu":
-        return False
-    if "ffn" not in p and "moe" not in p:
-        return False
-    if cfg.shard_attn_heads and jax.device_count() > 1:
-        return False  # meshless multi-device einsum constraints path
-    from repro.kernels import ops
-    return ops.decode_chain_enabled(
-        policy, B * S, d, cfg.n_heads * cfg.head_dim, cfg.d_ff,
-        moe="moe" in p)
-
-
-def _dense_block_fused_decode(p, x, cfg, policy, cache, window):
-    """One decode step of a dense or MoE block in persistent launches:
-    fused norm+qkv, attention (shared lowering), then the back half —
-    dense: fused wo+residual+norm+FFN+residual in one launch; MoE: fused
-    wo+residual+norm (emitting x1 and h), per-op routing on h, and the
-    stacked expert-bank launch inside ``moe_ffn``.  Bit-identical to
-    ``_dense_block`` (the per-op path is the oracle;
-    tests/test_decode_chain.py)."""
-    from repro.kernels import ops
-    B, S, d = x.shape
-    H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    x2 = x.reshape(B * S, d)
-    at = p["attn"]
-    q2, k2, v2 = ops.decode_qkv(x2, p["n1"]["g"], at["wq"]["w"],
-                                at["wk"]["w"], at["wv"]["w"],
-                                policy, cfg.norm_eps)
-    if "b" in at["wq"]:
-        q2 = q2 + at["wq"]["b"]
-        k2 = k2 + at["wk"]["b"]
-        v2 = v2 + at["wv"]["b"]
-    qkv = (q2.reshape(B, S, H, dh), k2.reshape(B, S, KV, dh),
-           v2.reshape(B, S, KV, dh))
-    if "moe" not in p:
-        # Dense back half: when the VMEM budget model says the K/V views
-        # fit next to the back half's working set (and the shape sits in
-        # the single-KV-block bitwise regime), collapse the attention
-        # core INTO the out-mlp launch — 2 launches per layer instead of
-        # 3.  Rope + cache update stay inside attention() (capture hook).
-        T = (cache["ptab"].shape[1] * cache["pool_k"].shape[1]
-             if "ptab" in cache else cache["k"].shape[1])
-        if ops.decode_fuse_attn_enabled(policy, B * S, d, H * dh,
-                                        cfg.d_ff, T, KV, dh):
-            (qr, kr, vr, qp, kp), cache = attention(
-                at, x, cfg, policy, cache=cache, window=window, qkv=qkv,
-                project_out=False, capture_attend=True)
-            y2 = ops.decode_attn_out_mlp(
-                x2, qr, kr, vr, qp, kp, p["n2"]["g"], at["wo"]["w"],
-                p["ffn"]["wg"]["w"], p["ffn"]["wu"]["w"],
-                p["ffn"]["wd"]["w"], at["wo"].get("b"),
-                p["ffn"]["wd"].get("b"), policy, cfg.norm_eps, True,
-                int(window))
-            return y2.reshape(B, S, d), cache, jnp.zeros((), jnp.float32)
-    a2, cache = attention(at, x, cfg, policy, cache=cache, window=window,
-                          qkv=qkv, project_out=False)
-    a2 = a2.reshape(B * S, H * dh)
-    if "moe" in p:
-        x1, h = ops.decode_wo_norm(x2, a2, p["n2"]["g"], at["wo"]["w"],
-                                   at["wo"].get("b"), policy, cfg.norm_eps)
-        y, aux = moe_ffn(p["moe"], h.reshape(B, S, d), cfg, policy)
-        return x1.reshape(B, S, d) + y, cache, aux
-    y2 = ops.decode_out_mlp_b(x2, a2, p["n2"]["g"], at["wo"]["w"],
-                              p["ffn"]["wg"]["w"], p["ffn"]["wu"]["w"],
-                              p["ffn"]["wd"]["w"], at["wo"].get("b"),
-                              p["ffn"]["wd"].get("b"), policy, cfg.norm_eps)
-    return y2.reshape(B, S, d), cache, jnp.zeros((), jnp.float32)
 
 
 def _ssm_block(p, x, cfg, policy, cache):

@@ -12,9 +12,8 @@ something adds to it, and ``after - before`` is what was counted in
 between.
 
 ``routes`` counts, at trace time, each choice a dispatch guard made and
-why (``decode_chain.taken``, ``decode_chain.refused.vmem``,
-``moe.grouped.taken``, ...): one
-count per trace of the function that asked, not per call.  It is the
+why (``gemm.tile.gemm2d.64x512``, ``lut_brick.factored``,
+``moe.grouped.taken``, ...): one count per trace of the function that asked, not per call.  It is the
 process's, because a guard is a pure function of shapes and policy with
 no owner to hold it.
 """

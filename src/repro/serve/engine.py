@@ -45,12 +45,8 @@ def make_prefill(cfg: ArchConfig, policy: Numerics, max_len: int):
 
 def make_serve_step(cfg: ArchConfig, policy: Numerics,
                     window: Optional[int] = None):
-    """Build the single-token decode step.  For homogeneous-amsim
-    policies the S=1 dense blocks lower to the persistent fused decode
-    chain (kernels/decode_chain.py; kill switch ``REPRO_DECODE_FUSED=0``
-    restores the per-op oracle, bit-identically) — the dispatch is
-    trace-time, so jit the returned step AFTER setting any REPRO_*
-    switches."""
+    """Build the single-token decode step.  The dispatch is trace-time,
+    so jit the returned step AFTER setting any REPRO_* switches."""
     def serve_step(params, tokens, caches):
         """One decode step: tokens (B, 1) -> (logits, next_token, caches)."""
         logits, caches, _ = lm_forward(params, tokens, cfg, policy,

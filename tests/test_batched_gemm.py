@@ -217,6 +217,25 @@ def test_autotune_corrupt_cache_is_safe(tuned_env):
     assert autotune.get_block_config("gemm3d", 32, 32, 32, 7, batch=2) == won
 
 
+def test_autotune_cache_drops_an_entry_of_a_retired_kind(tuned_env):
+    """A cache written when the fused decode chain was still tuned holds
+    entries of its (bn, bko, bf, overlap) form.  They carry no ``bm``, so
+    they parse as no tiling and are dropped, while the valid gemm2d entry
+    beside them still loads."""
+    stale = "cpu|retired|r128_d2048_k2048_f8192|M7"
+    key = autotune.cache_key("gemm2d", 32, 32, 32, 7)
+    tuned_env["path"].write_text(json.dumps({
+        "version": autotune.SCHEMA_VERSION,
+        "entries": {
+            stale: {"bn": 128, "bko": 128, "bf": 128, "overlap": 1,
+                    "us": 1.0},
+            key: {"bm": 32, "bn": 32, "bk": 32, "chunk": 8, "us": 1.0}}}))
+    autotune.reload_cache()
+    assert autotune.get_block_config("gemm2d", 32, 32, 32, 7) == \
+        autotune.BlockConfig(32, 32, 32, 8)
+    assert list(autotune._entries()) == [key]
+
+
 def test_shape_bucket_is_pow2_and_batch_aware():
     assert autotune.shape_bucket(256, 256, 256, batch=8) == "b8_m256_k256_n256"
     assert autotune.shape_bucket(200, 129, 96) == "m256_k256_n128"

@@ -102,26 +102,11 @@ def test_busy_seconds_and_trace_counts_are_views_of_the_counters(setup):
 
 
 # ------------------------------------------------------------- routes
-def test_decode_chain_route_records_why_it_was_refused(monkeypatch):
-    """granite-3-2b's widths at 128 slots: the VMEM budget refuses the
-    chain; a small shape takes it; native numerics and the kill switch
-    refuse it for their own reasons."""
-    before = collections.Counter(obs.routes)
-    assert not ops.decode_chain_enabled(AFM16, 128, 2048, 2048, 8192)
-    assert ops.decode_chain_enabled(AFM16, 4, 256, 256, 512)
-    assert not ops.decode_chain_enabled(NATIVE, 4, 256, 256, 512)
-    monkeypatch.setenv("REPRO_DECODE_FUSED", "0")
-    assert not ops.decode_chain_enabled(AFM16, 4, 256, 256, 512)
-    assert obs.routes - before == collections.Counter({
-        "decode_chain.refused.vmem": 1, "decode_chain.taken": 1,
-        "decode_chain.refused.policy": 1, "decode_chain.refused.env": 1})
-
-
-def test_moe_route_records_the_grouped_kernel_or_the_decode_bank():
+def test_moe_route_records_the_grouped_kernel():
     """granite-moe-3b-a800m's layer at its published widths (traced, not
-    run): a training batch takes the grouped route, under native numerics
-    too; a small decode tick under amsim runs the stacked-bank launch and
-    records why the grouped route was refused.  The dispatch sits in the
+    run): a training batch, under amsim and under native numerics, and a
+    128-slot decode tick all take the grouped route, and the tick's
+    expert GEMMs launch the grouped kernel.  The dispatch sits in the
     layer's named scopes."""
     import jax.numpy as jnp
 
@@ -134,11 +119,13 @@ def test_moe_route_records_the_grouped_kernel_or_the_decode_bank():
     before = collections.Counter(obs.routes)
     text = run(AFM16, (4, 512, cfg.d_model)).as_text(debug_info=True)
     run(NATIVE, (4, 512, cfg.d_model))
-    run(AFM16, (4, 1, cfg.d_model))
-    assert obs.routes - before >= collections.Counter({
-        "moe.grouped.taken": 2, "moe.grouped.refused.decode": 1})
-    assert obs.routes["moe.grouped.refused.decode"] - before[
-        "moe.grouped.refused.decode"] == 1
+    mid = collections.Counter(obs.routes)
+    run(AFM16, (128, 1, cfg.d_model))
+    assert obs.routes - before >= collections.Counter({"moe.grouped.taken": 3})
+    tick = obs.routes - mid
+    assert {k: v for k, v in tick.items()
+            if k.startswith("gemm.tile.grouped")} \
+        == {"gemm.tile.grouped.64x512": 3}       # wg, wu and wd
     for scope in ("moe.router", "moe.dispatch", "moe.experts", "moe.combine"):
         assert scope in text
 

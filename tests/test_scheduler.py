@@ -191,6 +191,37 @@ def test_windowed_stream_recycles_pages(setup):
     assert cbe.n_free_pages["default"] == 4  # everything released
 
 
+def test_cbe_decode_ticks_zero_added_retraces(setup):
+    """An amsim tier's decode ticks keep the one-decode-trace-per-tier
+    contract: a second wave of requests through the SAME engine (prompts
+    in the first wave's prefill bucket) adds no decode trace and traces
+    no LUT GEMM launch again (``obs.routes`` counts a launch's tile each
+    time it is traced)."""
+    from repro import obs
+    cfg, params = setup
+    pol = NumericsPolicy(mode="amsim", multiplier="exact7")
+    prompts = _prompts(cfg, (5, 3, 6, 4), seed=4)
+    tiles = lambda: sum(v for k, v in obs.routes.items()
+                        if k.startswith("gemm.tile."))
+
+    cbe = ContinuousBatchingEngine(cfg, {"cheap": pol}, params,
+                                   max_len=32, capacity=2, page_size=4)
+    t0 = tiles()
+    rids = [cbe.submit(p, 5, tier="cheap") for p in prompts[:2]]
+    out = cbe.drain()
+    assert all(len(out[r]) == 5 for r in rids)
+    assert tiles() > t0, "the amsim tier ran no LUT GEMM"
+    assert cbe.decode_trace_counts == {"cheap": 1}
+
+    t1 = tiles()
+    rids2 = [cbe.submit(p, 4, tier="cheap") for p in prompts[2:]]
+    out2 = cbe.drain()
+    assert all(len(out2[r]) == 4 for r in rids2)
+    assert cbe.decode_trace_counts == {"cheap": 1}, \
+        "second wave retraced the decode step"
+    assert tiles() == t1, "second wave traced LUT GEMM launches again"
+
+
 def test_submit_validation(setup):
     cfg, params = setup
     cbe = ContinuousBatchingEngine(cfg, NATIVE, params, max_len=16,

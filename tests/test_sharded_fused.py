@@ -299,6 +299,48 @@ def test_train_steps_mesh_loss_parity():
     assert "OK" in run_in_subprocess(code)
 
 
+def test_overlap_psum_settings():
+    """REPRO_OVERLAP_PSUM on the row-parallel reduce: 1 (single psum),
+    explicit chunk counts, and auto must all be bitwise-identical (the
+    chunking splits OUTPUT columns, never the fold); the ring variant
+    accumulates in fixed shard-index order — on the two-device model
+    axis that is bitwise-identical to the single psum too (FP add is
+    commutative), so it is held to the same standard."""
+    code = textwrap.dedent("""
+    import os
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.core.policy import NumericsPolicy
+    from repro.distributed import shard_fused as sf
+
+    from repro.launch.mesh import make_debug_mesh
+    mesh = make_debug_mesh()
+    rng = np.random.default_rng(0)
+    pol = NumericsPolicy(mode="amsim", multiplier="mitchell8")
+    x = jnp.asarray(rng.standard_normal((4, 8, 256)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((256, 512)) * 0.1, jnp.float32)
+
+    def run():
+        # fresh closure per call: the overlap setting is read at trace
+        # time, so a cached jit would mask the env change.
+        with jax.set_mesh(mesh):
+            return jax.jit(lambda a, b: sf.row_parallel_matmul(
+                a, b, pol, mesh))(x, w)
+
+    os.environ["REPRO_OVERLAP_PSUM"] = "1"
+    base = run()
+    for setting in ("auto", "2", "4"):
+        os.environ["REPRO_OVERLAP_PSUM"] = setting
+        out = run()
+        assert bool(jnp.all(out == base)), f"overlap={setting} not bitwise"
+    os.environ["REPRO_OVERLAP_PSUM"] = "ring"
+    ring = run()
+    assert bool(jnp.all(ring == base)), "ring not bitwise on 2-dev axis"
+    del os.environ["REPRO_OVERLAP_PSUM"]
+    print("OK overlap")
+    """)
+    assert "OK overlap" in run_in_subprocess(code)
+
+
 @pytest.mark.slow
 def test_launch_train_cli_20step_parity():
     """launch/train.py --numerics amsim on the debug mesh: reports the

@@ -152,26 +152,6 @@ def test_decode_attention_paged(one_chip):
              _lut_shape())
 
 
-def test_decode_chain_qkv(one_chip):
-    from repro.kernels.decode_chain import fused_qkv_norm
-    text = _compile(lambda x, g, wq, wk, wv, lut: fused_qkv_norm(
-                 x, g, wq, wk, wv, lut, M, eps=1e-5, interpret=False),
-             one_chip, ((4, D), f32), ((D,), f32), ((D, H * DH), f32),
-             ((D, KV * DH), f32), ((D, KV * DH), f32), _lut_shape())
-    assert _kernels(text) == [("fused_qkv_impl", ("qkv_norm", "fwd"))]
-
-
-def test_decode_chain_out_mlp(one_chip):
-    from repro.kernels.decode_chain import fused_out_mlp
-    text = _compile(lambda x, at, g, wo, wg, wu, wd, lut: fused_out_mlp(
-                 x, at, g, wo, wg, wu, wd, lut, M, eps=1e-5,
-                 interpret=False),
-             one_chip, ((4, D), f32), ((4, H * DH), f32), ((D,), f32),
-             ((H * DH, D), f32), ((D, FF), f32), ((D, FF), f32),
-             ((FF, D), f32), _lut_shape())
-    assert _kernels(text) == [("fused_out_mlp_impl", ("out_mlp", "fwd"))]
-
-
 @pytest.mark.parametrize("cin,cout,stride", [(16, 16, 1), (16, 32, 2)])
 def test_conv_forward(one_chip, cin, cout, stride):
     from repro.kernels.approx_conv import approx_conv2d_fused
@@ -203,35 +183,6 @@ def test_wide_table_raises_on_chip(one_chip):
                                           wide.mantissa_bits,
                                           interpret=False),
                  one_chip, ((128, 128), f32), ((128, 128), f32))
-
-
-def test_decode_chain_attn_out_mlp(one_chip):
-    """The 2-launch form: attention core folded into the back half."""
-    from repro.kernels.decode_chain import fused_attn_out_mlp
-    B, T = 4, 128
-    _compile(lambda x, q, k, v, qp, kp, g, wo, wg, wu, wd, lut:
-             fused_attn_out_mlp(x, q, k, v, qp, kp, g, wo, wg, wu, wd, lut,
-                                M, eps=1e-5, interpret=False),
-             one_chip, ((B, D), f32), ((B, 1, H, DH), f32),
-             ((B, T, KV, DH), f32), ((B, T, KV, DH), f32), ((B, 1), i32),
-             ((B, T), i32), ((D,), f32), ((H * DH, D), f32), ((D, FF), f32),
-             ((D, FF), f32), ((FF, D), f32), _lut_shape())
-
-
-def test_decode_chain_moe_launches(one_chip):
-    """The MoE back half at granite-moe-3b-a800m's widths (d=1536, 24
-    heads of 64, 40 experts of d_ff 512): wo -> norm, and the stacked
-    expert-bank FFN."""
-    from repro.kernels.decode_chain import fused_moe_ffn, fused_wo_norm
-    d, k, experts, cap, ff = 1536, 24 * 64, 40, 8, 512
-    _compile(lambda x, a, g, wo, lut: fused_wo_norm(
-                 x, a, g, wo, lut, M, eps=1e-5, interpret=False),
-             one_chip, ((4, d), f32), ((4, k), f32), ((d,), f32),
-             ((k, d), f32), _lut_shape())
-    _compile(lambda h, wg, wu, wd, lut: fused_moe_ffn(
-                 h, wg, wu, wd, lut, M, interpret=False),
-             one_chip, ((experts, cap, d), f32), ((experts, d, ff), f32),
-             ((experts, d, ff), f32), ((experts, ff, d), f32), _lut_shape())
 
 
 AFM16 = NumericsPolicy(mode="amsim", multiplier="afm16")
@@ -272,12 +223,53 @@ def test_grouped_gemm_forward_dx_dw(one_chip, compiled_kernels, site, k, n):
         assert work(operands, results)[0] == 2.0 * routed * k * n
 
 
+@pytest.mark.parametrize("arch", ["granite-3-2b", "granite-moe-3b-a800m"],
+                         ids=["dense", "moe"])
+def test_decode_tick_compiles(one_chip, compiled_kernels, arch):
+    """One layer at its published widths on a 128-slot paged decode tick
+    (one token a slot, pages of 16, 24 pages a slot): the per-op path the
+    serving engine runs.  The MoE layer routes 1,024 rows to 40 experts
+    through the grouped kernel."""
+    from repro.configs import get_arch
+    from repro.models.transformer import _dense_block, _init_dense_layer
+    cfg = get_arch(arch)
+    moe = cfg.moe is not None
+    p = jax.eval_shape(lambda k: _init_dense_layer(k, cfg, moe),
+                       jax.random.PRNGKey(0))
+    slots, page, per_slot = 128, 16, 24
+    pool = (slots * per_slot + 1, page, cfg.n_kv_heads, cfg.head_dim)
+
+    leaves, tree = jax.tree.flatten(p)
+
+    def tick(x, pool_k, pool_v, ptab, live, start, *leaves):
+        cache = dict(pool_k=pool_k, pool_v=pool_v, ptab=ptab, live=live,
+                     start=start)
+        return _dense_block(jax.tree.unflatten(tree, leaves), x, cfg, AFM16,
+                            cache, 0)
+    text = _compile(tick, one_chip, ((slots, 1, cfg.d_model), f32),
+                    (pool, f32), (pool, f32), ((slots, per_slot), i32),
+                    ((slots,), jnp.bool_), ((slots,), i32),
+                    *[(a.shape, a.dtype) for a in leaves])
+    experts = [("approx_gemm_grouped_impl", (s, "fwd"))
+               for s in ("wd", "wg", "wu")]
+    mlp = [("approx_gemm_impl", (s, "fwd")) for s in ("wd", "wg", "wu")]
+    want = sorted([("approx_gemm_impl", ("qkv", "fwd"))] * 3
+                  + [("approx_gemm_impl", ("wo", "fwd")),
+                     ("attn_impl", ("attn", "fwd"))]
+                  + (experts + [("approx_gemm_impl", ("router", "fwd"))]
+                     if moe else mlp))
+    assert _kernels(text) == want
+
+
 @pytest.fixture
 def compiled_kernels(monkeypatch):
     """Kernels reached through the policy seam compile for the chip, not
     in interpret mode, while the host's backend is the CPU."""
+    import repro.kernels.approx_attention as attention
     import repro.kernels.approx_gemm as gemm
-    monkeypatch.setattr(gemm, "resolve_interpret", lambda interpret: False)
+    for module in (gemm, attention):
+        monkeypatch.setattr(module, "resolve_interpret",
+                            lambda interpret: False)
 
 
 def test_gemm_launches_carry_site_and_pass(one_chip, compiled_kernels):
