@@ -111,10 +111,14 @@ def init_lm(key, cfg: ArchConfig):
 # ---------------------------------------------------------------- forward
 def lm_forward(params, tokens, cfg: ArchConfig, policy: NumericsPolicy, *,
                embeds=None, caches=None, window: int | None = None,
-               train: bool = False):
+               train: bool = False, logits_at=None):
     """tokens (B, S) [+ optional frontend embeds (B, F, d) prepended].
 
-    Returns (logits (B, S_total, vocab), new_caches, aux_loss).
+    Returns (logits (B, S_total, vocab), new_caches, aux_loss).  With
+    ``logits_at`` (B,) int, positions into the S_total outputs, only
+    row ``logits_at[b]`` of each sequence is unembedded: logits are
+    (B, 1, vocab).  The row is gathered after the final norm, so its
+    bits are the full head's row at that position.
     """
     window = cfg.sliding_window if window is None else window
     if cfg.fsdp and cfg.unshard_weights:
@@ -171,6 +175,8 @@ def lm_forward(params, tokens, cfg: ArchConfig, policy: NumericsPolicy, *,
             new_caches = None
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    if logits_at is not None:
+        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
     if cfg.tie_embeddings:
         logits = unembed(params["embed"], x, policy)
     else:

@@ -35,10 +35,13 @@ from repro.models.transformer import init_lm_caches, lm_forward
 
 def make_prefill(cfg: ArchConfig, policy: Numerics, max_len: int):
     def prefill(params, tokens, caches):
-        """tokens (B, S_prompt) -> (next_token (B,1), caches)."""
-        logits, caches, _ = lm_forward(params, tokens, cfg, policy,
-                                       caches=caches)
-        nxt = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+        """tokens (B, S_prompt) -> (next_token (B,1), caches).  Only
+        the last position is unembedded."""
+        B, S = tokens.shape
+        logits, caches, _ = lm_forward(
+            params, tokens, cfg, policy, caches=caches,
+            logits_at=jnp.full((B,), S - 1, jnp.int32))
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return nxt, caches
     return prefill
 

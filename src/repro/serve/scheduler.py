@@ -115,21 +115,21 @@ def make_paged_prefill(cfg: ArchConfig, policy: Numerics,
         garbage) — the scheduler quarantines it instead of emitting.
 
         Padding garbage is harmless: queries past true_len are never
-        read (the next token comes from position true_len - 1), their
-        K/V writes land in allocated-but-not-yet-valid positions or the
-        trash page, and causal masking keeps real queries from seeing
-        anything at or past their own position.
+        read (only position true_len - 1 is unembedded, and the next
+        token comes from it), their K/V writes land in
+        allocated-but-not-yet-valid positions or the trash page, and
+        causal masking keeps real queries from seeing anything at or
+        past their own position.
         """
         B = tokens.shape[0]
         merged = _merge_control(caches, ptab,
                                 jnp.ones((B,), bool),
                                 jnp.zeros((B,), jnp.int32))
         logits, merged, _ = lm_forward(params, tokens, cfg, policy,
-                                       caches=merged, window=window)
-        last = jnp.take_along_axis(logits, (true_len - 1)[:, None, None],
-                                   axis=1)
-        nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        ok = jnp.isfinite(last[:, 0, :]).all(axis=-1)
+                                       caches=merged, window=window,
+                                       logits_at=true_len - 1)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        ok = jnp.isfinite(logits[:, 0, :]).all(axis=-1)
         return nxt, ok, _strip_control(merged)
     return paged_prefill
 
@@ -546,6 +546,7 @@ class ContinuousBatchingEngine:
                     time.perf_counter() - t0
             self.counters.update({"admissions": 1, "prefill.tokens": m,
                                   "prefill.bucket_tokens": P,
+                                  "prefill.head_rows": toks.shape[0],
                                   f"prefill.bucket.{P}": 1})
             lane.slot_req[slot] = req
             if not bool(ok[0]):

@@ -67,7 +67,7 @@ def test_counters_count_a_scripted_stream(setup):
 
 def test_counters_count_preemptions_and_readmissions(setup):
     """An overcommitted pool evicts mid-flight; every eviction is one
-    preemption and one more admission prefill."""
+    preemption and one more admission prefill, which unembeds one row."""
     cfg, params = setup
     cbe = ContinuousBatchingEngine(cfg, NATIVE, params, max_len=32,
                                    capacity=3, page_size=4, n_pages=7)
@@ -78,6 +78,7 @@ def test_counters_count_preemptions_and_readmissions(setup):
     assert evicted > 0
     assert cbe.counters["preemptions"] == evicted
     assert cbe.counters["admissions"] == 3 + evicted
+    assert cbe.counters["prefill.head_rows"] == 3 + evicted
 
 
 def test_busy_seconds_and_trace_counts_are_views_of_the_counters(setup):

@@ -106,7 +106,9 @@ def test_gemm_brick(one_chip):
     (256, VOCAB, D),        # dx
     (D, 256, VOCAB),        # and dw
     (48, D, 3 * D // 2),    # qkv of a 48-row prefill
-], ids=["qkv", "wd", "head.fwd", "head.dx", "head.dw", "prefill48.qkv"])
+    (1, D, VOCAB),          # the head of an admission prefill: one row
+], ids=["qkv", "wd", "head.fwd", "head.dx", "head.dw", "prefill48.qkv",
+        "prefill.head"])
 def test_gemm_at_the_rule_tile(one_chip, m, k, n):
     """The 2-D kernel at the output tile ``autotune.tile_2d`` picks for
     granite-3-2b's sites, the one each launch records: Mosaic takes every
