@@ -17,6 +17,7 @@ from repro.configs import (  # noqa: F401
     qwen1_5_110b,
     zamba2_1_2b,
     granite_moe_3b_a800m,
+    granite_4_0_h_micro,
     llama4_maverick_400b_a17b,
     llava_next_34b,
     mamba2_780m,

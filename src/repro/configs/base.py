@@ -45,9 +45,14 @@ class ArchConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     attn_every: int = 0          # hybrid: shared attn block every k layers
+    # Mixer of each layer ("mamba" | "attention"), every layer with an MLP
+    # (granite-4.0-h); the stack runs the first n_layers.  Empty: the
+    # family decides.
+    layer_types: tuple = ()
     n_enc_layers: int = 0        # encdec: encoder depth (n_layers = decoder)
     sliding_window: int = 0      # 0 = full attention
     rope_theta: float = 10000.0
+    use_rope: bool = True        # False: no position embedding (NoPE)
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     act: str = "swiglu"          # swiglu | gelu
@@ -78,6 +83,11 @@ class ArchConfig:
                                        # by launch/ for multi-pod meshes)
 
     @property
+    def kinds(self) -> tuple:
+        """The mixer kind of each layer that runs (``layer_types``)."""
+        return tuple(self.layer_types[:self.n_layers])
+
+    @property
     def head_dim(self) -> int:
         if self.d_head:
             return self.d_head
@@ -90,7 +100,12 @@ class ArchConfig:
         n = v * d  # token embedding
         if not self.tie_embeddings:
             n += d * v  # LM head
-        n += self.n_layers * self._layer_params()
+        if self.layer_types:
+            n += sum(self._attn_params() if k == "attention"
+                     else self._ssm_params() for k in self.kinds)
+            n += self.n_layers * (2 * d + self._ffn_params(self.d_ff))
+        else:
+            n += self.n_layers * self._layer_params()
         if self.n_enc_layers:
             n += self.n_enc_layers * self._enc_layer_params()
         if self.family == "hybrid" and self.attn_every:
@@ -222,5 +237,10 @@ def reduced(cfg: ArchConfig, **overrides) -> ArchConfig:
     if cfg.attn_every:
         base["attn_every"] = 2
         base["n_layers"] = 4
+    if cfg.layer_types:
+        # One of each kind between Mamba layers: both scans and the
+        # attention layer between them.
+        base["layer_types"] = ("mamba", "attention", "mamba")
+        base["n_layers"] = 3
     base.update(overrides)
     return dataclasses.replace(cfg, name=cfg.name + "-smoke", **base)

@@ -167,10 +167,10 @@ def cache_pspecs(caches, mesh: Mesh, batch: int):
                 tail = (None, daxes, "model", None)  # SP over cache length
             lead = (None,) * (leaf.ndim - 4)
             return P(*_fix_divisibility(lead + tail, leaf.shape, mesh))
-        if re.search(r"ssm$", s) and leaf.ndim >= 4:
-            # (L?, B, nh, p, N): heads over model
-            tail = (daxes if batch_sharded else None, "model", None, None)
-            lead = (None,) * (leaf.ndim - 4)
+        if re.search(r"ssm$", s) and leaf.ndim >= 3:
+            # (L?, B, N, nh * p): heads over model
+            tail = (daxes if batch_sharded else None, None, "model")
+            lead = (None,) * (leaf.ndim - 3)
             return P(*_fix_divisibility(lead + tail, leaf.shape, mesh))
         if re.search(r"conv$", s) and leaf.ndim >= 3:
             tail = (daxes if batch_sharded else None, None, "model")

@@ -69,7 +69,8 @@ from repro.kernels.approx_gemm import (GroupedRows, approx_gemm,
                                       approx_gemm_grouped_dw)
 from repro.kernels.ref import (ref_amsim_gemm, ref_direct_gemm,
                                ref_grouped_gemm, ref_grouped_gemm_dw,
-                               ref_im2col)
+                               ref_im2col, ref_ssm_state)
+from repro.kernels.ssm_state import decayed, ssm_state_update
 
 
 # =====================================================================
@@ -458,6 +459,41 @@ def policy_einsum(spec: str, a, b, policy: Numerics, site: str | None = None):
     cur = batch + afree + bfree
     operm = [cur.index(c) for c in out]
     return jnp.transpose(o, operm)
+
+
+# =====================================================================
+# Mamba-2 recurrent state step (decode; models/ssm.py)
+# =====================================================================
+
+def policy_ssm_state(state, layer, xdt, dec, b, c, policy: Numerics,
+                     site: str = "ssm"):
+    """One decode step of layer ``layer`` of the stacked Mamba-2 state
+    (L, S, N, H*p) under the leaf ``policy`` resolves at ``site``: the
+    products ``b (x dt)`` and ``c h`` are the multiplier's, the decays
+    and sums exact float32 (``kernels/ssm_state.py``).  Returns
+    (y (S, H*p), state).  Forward only: decode takes no gradient."""
+    leaf = policy.resolve(site)
+    xdt, dec, b, c = (x.astype(jnp.float32) for x in (xdt, dec, b, c))
+    if leaf.is_native or leaf.mode == "surrogate":
+        st = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+        bb, xx = b[:, :, None], xdt[:, None, :]
+        if not leaf.is_native:
+            bb, xx = _surrogate_operands(bb, xx, leaf)
+        new = decayed(st, dec[:, None, :]) + bb * xx
+        cc, hh = (c, new) if leaf.is_native else \
+            _surrogate_operands(c, new, leaf)
+        y = jnp.einsum("sn,snj->sj", cc, hh,
+                       preferred_element_type=jnp.float32)
+        return y, jax.lax.dynamic_update_index_in_dim(state, new, layer, 0)
+    mult = get_multiplier(leaf.multiplier)
+    if leaf.mode == "amsim":
+        return _replicated(
+            lambda *a: ssm_state_update(*a, _amsim_lut(mult),
+                                        mult.mantissa_bits,
+                                        tag=gemm_tag(site, "fwd")),
+            state, layer, xdt, dec, b, c)
+    return ref_ssm_state(state, layer, xdt, dec, b, c,
+                         _oracle_mul(leaf, mult))
 
 
 # =====================================================================

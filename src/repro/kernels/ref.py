@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.core.amsim import amsim_multiply
 from repro.core.multipliers import Multiplier
 from repro.kernels.approx_gemm import GROUP_TILE
+from repro.kernels.ssm_state import decayed
 
 # Contraction-chunk size of the oracle's FP32 fold (see _elementwise_gemm).
 _K_CHUNK = 128
@@ -117,6 +118,31 @@ def ref_direct_gemm(a, b, multiplier: Multiplier):
     Batched like ``ref_amsim_gemm``: (..., m, k) @ (..., k, n).
     """
     return _elementwise_gemm(a, b, multiplier.jnp_mul)
+
+
+def ref_ssm_state(state, layer, xdt, dec, b, c, mul):
+    """Oracle of ``kernels/ssm_state.ssm_state_update``: one recurrent
+    step of layer ``layer`` of the stacked state (L, S, N, H*p) with
+    ``mul`` as the scalar product,
+
+        new[s, n, j] = state[layer, s, n, j] * dec[s, j] + mul(b[s, n], xdt[s, j])
+        (the decay a product of its own, ``ssm_state.decayed``)
+        y[s, j]      = sum_n mul(c[s, n], new[s, n, j]),
+
+    the sum folded as the kernel folds it: n in groups of 8, each of the
+    8 partial sums (n mod 8) over the groups in order from +0.0, then the
+    8 partials in order from +0.0.  Returns (y (S, H*p), state)."""
+    st = jax.lax.dynamic_index_in_dim(state, layer, axis=0, keepdims=False)
+    new = decayed(st, dec[:, None, :]) + mul(b[:, :, None], xdt[:, None, :])
+    p = mul(c[:, :, None], new)
+    S, N, HP = p.shape
+    acc = jnp.zeros((S, 8, HP), jnp.float32)
+    for g in range(N // 8):
+        acc = acc + p[:, 8 * g:8 * g + 8]
+    y = jnp.zeros((S, HP), jnp.float32)
+    for r in range(8):
+        y = y + acc[:, r]
+    return y, jax.lax.dynamic_update_index_in_dim(state, new, layer, axis=0)
 
 
 # --------------------------------------------------------------- conv oracle

@@ -9,7 +9,10 @@ approximate-GEMM technique directly applicable to an attention-free arch.
 
 Decode is a constant-size recurrent state update: the "KV cache" of an
 SSM is O(1) in sequence length (noted in the roofline table for the
-decode_32k / long_500k cells).
+decode_32k / long_500k cells).  A decode step forms the same two products
+the chunked form does, ``B (x dt)`` and ``C h``, under the same policy,
+through the state-update kernel (``kernels/ssm_state.py``); decays,
+``dt x``, ``D x``, the gate and the norms stay exact float32.
 
 n_groups=1 (Mamba2 default): B and C are shared across heads.
 """
@@ -18,8 +21,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.core.policy import NumericsPolicy
+from repro.kernels.ops import policy_ssm_state
 from repro.models.layers import init_linear, init_rmsnorm, linear, rmsnorm
 
 
@@ -48,12 +53,13 @@ def init_mamba2(key, cfg: ArchConfig):
     }
 
 
-def _causal_conv(x, w, b):
-    """Depthwise causal conv1d. x (B, L, ch), w (K, ch)."""
-    K = w.shape[0]
-    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    y = sum(xp[:, i : i + x.shape[1], :] * w[i] for i in range(K))
-    return y + b
+def _conv_with_state(xbc, c0, w, b):
+    """Depthwise causal conv1d of xbc (B, L, ch) continuing from the K-1
+    inputs before it, c0 (B, K-1, ch), w (K, ch).  Products are exact
+    float32."""
+    K, L = w.shape[0], xbc.shape[1]
+    full = jnp.concatenate([c0.astype(xbc.dtype), xbc], axis=1)
+    return sum(full[:, i:i + L, :] * w[i] for i in range(K)) + b, full
 
 
 def _split_proj(cfg, zxbcdt):
@@ -64,53 +70,91 @@ def _split_proj(cfg, zxbcdt):
     return z, xs, Bc, Cc, dt
 
 
-def mamba2(p, u, cfg: ArchConfig, policy: NumericsPolicy, *, cache=None):
+def mamba2(p, u, cfg: ArchConfig, policy: NumericsPolicy, *, cache=None,
+           true_len=None):
     """u (B, L, d) -> (y (B, L, d), new_cache).
 
-    cache: {"ssm": (B, nh, p, N), "conv": (B, K-1, conv_ch)} for decode.
+    The recurrent state is kept as ``ssm`` (B, N, nh * p), d_state first
+    (``kernels/ssm_state.py``), and the conv input history as ``conv``
+    (B, K-1, conv_ch).  ``cache`` holds both for decoding, or, in the
+    serving engine's slot state, both stacked over layers and slots,
+    (layers, slots, ...), with the layer's index under ``"layer"``; it is
+    returned in the form it came in.  A stacked cache with ``"slot"``
+    (B,) is an admission prefill: the block starts from zero and its
+    final state is written to those slots.
+
+    * One token with a cache: a recurrent step, the products
+      ``B (x dt)`` and ``C h`` under ``policy`` (``kernels/ops.policy_ssm_state``).
+    * Otherwise the chunked SSD over the whole block, from the cache's
+      state or from zero, with chunk ``min(chunk, L)``.  ``true_len``
+      (B,) marks each row's real length in a right-padded block: ``dt``
+      is 0 at and past it, so padding neither decays nor feeds the state,
+      whose value after the last real position, with the conv history of
+      its K-1 inputs, is the new cache.
     """
     s, d_in, nheads, conv_ch = _dims(cfg)
     B_, L, _ = u.shape
-    hp, N, Q = s.head_dim, s.d_state, s.chunk
+    hp, N, K = s.head_dim, s.d_state, s.conv_kernel
 
     zxbcdt = linear(p["in_proj"], u, policy, site="ssm")
     z, xs, Bc, Cc, dt = _split_proj(cfg, zxbcdt)
     xbc = jnp.concatenate([xs, Bc, Cc], axis=-1)
 
-    if cache is not None:
-        # decode: prepend conv state, run conv over the (K-1+L) window
-        full = jnp.concatenate([cache["conv"], xbc], axis=1)
-        K = s.conv_kernel
-        y = sum(full[:, i : i + L, :] * p["conv_w"][i] for i in range(K))
-        xbc = y + p["conv_b"]
-        new_conv = full[:, -(K - 1) :, :]
+    fresh = cache is None or "slot" in cache
+    stacked = cache is not None and "layer" in cache
+    step = cache is not None and not fresh and L == 1
+    if fresh:
+        c0 = jnp.zeros((B_, K - 1, conv_ch), jnp.float32)
+    elif stacked:
+        c0 = jax.lax.dynamic_index_in_dim(cache["conv"], cache["layer"], 0,
+                                          keepdims=False)
     else:
-        xbc = _causal_conv(xbc, p["conv_w"], p["conv_b"])
-        new_conv = None
+        c0 = cache["conv"]
+    xbc, hist = _conv_with_state(xbc, c0, p["conv_w"], p["conv_b"])
+    end = jnp.full((B_,), L, jnp.int32) if true_len is None else true_len
+    new_conv = jax.vmap(lambda h, e: jax.lax.dynamic_slice_in_dim(
+        h, e, K - 1, axis=0))(hist, end.astype(jnp.int32))
     xbc = jax.nn.silu(xbc)
     xs = xbc[..., :d_in].reshape(B_, L, nheads, hp)
     Bc = xbc[..., d_in : d_in + N]                      # (B, L, N)  G=1
     Cc = xbc[..., d_in + N :]                           # (B, L, N)
 
     dt = jax.nn.softplus(dt + p["dt_bias"])             # (B, L, nh)
+    if true_len is not None:
+        real = jnp.arange(L)[None, :] < true_len[:, None]
+        dt = jnp.where(real[..., None], dt, 0.0)
     A = -jnp.exp(p["A_log"])                            # (nh,)
     dA = dt * A                                         # (B, L, nh)  log-decay
     xdt = xs * dt[..., None]                            # (B, L, nh, p)
 
-    if cache is not None:
-        # recurrent step(s): state <- state*exp(dA) + B (x*dt);  y = C.state
-        def step(state, t):
-            st = state * jnp.exp(dA[:, t])[:, :, None, None]
-            st = st + jnp.einsum("bn,bhp->bhpn", Bc[:, t], xdt[:, t])
-            y = jnp.einsum("bn,bhpn->bhp", Cc[:, t], st)
-            return st, y
-
-        state, ys = jax.lax.scan(step, cache["ssm"], jnp.arange(L))
-        y = jnp.moveaxis(ys, 0, 1)                      # (B, L, nh, p)
-        new_cache = {"ssm": state, "conv": new_conv}
+    if step:
+        state = cache["ssm"] if stacked else cache["ssm"][None]
+        layer = cache["layer"] if stacked else 0
+        dec = jnp.repeat(jnp.exp(dA[:, 0]), hp, axis=-1)   # (B, nh * p)
+        y, state = policy_ssm_state(state, layer, xdt.reshape(B_, d_in), dec,
+                                    Bc[:, 0], Cc[:, 0], policy)
+        y = y.reshape(B_, 1, nheads, hp)
     else:
-        y = _ssd_chunked(xdt, Bc, Cc, dA, Q, policy)
+        h0 = None if fresh else cache["ssm"]
+        Q = min(s.chunk, L)
+        obs.route("ssm.chunk", str(Q))
+        if h0 is not None:
+            h0 = h0.reshape(B_, N, nheads, hp).transpose(0, 2, 3, 1)
+        y, h = _ssd_chunked(xdt, Bc, Cc, dA, Q, policy, h0)
+        state = h.transpose(0, 3, 1, 2).reshape(B_, N, d_in)
+
+    if cache is None:
         new_cache = None
+    elif stacked and step:
+        new_cache = dict(cache, ssm=state,
+                         conv=jax.lax.dynamic_update_index_in_dim(
+                             cache["conv"], new_conv, cache["layer"], 0))
+    elif stacked:
+        at = (cache["layer"], cache["slot"])
+        new_cache = dict(cache, ssm=cache["ssm"].at[at].set(state),
+                         conv=cache["conv"].at[at].set(new_conv))
+    else:
+        new_cache = {"ssm": state[0] if step else state, "conv": new_conv}
 
     y = y + p["D"][None, None, :, None] * xs            # skip connection
     y = y.reshape(B_, L, d_in) * jax.nn.silu(z)
@@ -118,11 +162,19 @@ def mamba2(p, u, cfg: ArchConfig, policy: NumericsPolicy, *, cache=None):
     return linear(p["out_proj"], y, policy, site="ssm"), new_cache
 
 
-def _ssd_chunked(xdt, Bc, Cc, dA, Q: int, policy: NumericsPolicy):
-    """SSD scan. xdt (B,L,nh,p), Bc/Cc (B,L,N), dA (B,L,nh) -> (B,L,nh,p)."""
+def _ssd_chunked(xdt, Bc, Cc, dA, Q: int, policy: NumericsPolicy, h0=None):
+    """SSD scan from state h0 (B,nh,p,N), zero when None.  xdt (B,L,nh,p),
+    Bc/Cc (B,L,N), dA (B,L,nh) -> (y (B,L,nh,p), final state (B,nh,p,N))."""
     B_, L, nh, hp = xdt.shape
     N = Bc.shape[-1]
-    assert L % Q == 0, (L, Q)
+    pad = -L % Q
+    if pad:
+        # Zero inputs and zero log-decay: the padding leaves the state as
+        # the last position left it, and its outputs are dropped.
+        grow = lambda a: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        y, h = _ssd_chunked(grow(xdt), grow(Bc), grow(Cc), grow(dA), Q,
+                            policy, h0)
+        return y[:, :L], h
     c = L // Q
     xc = xdt.reshape(B_, c, Q, nh, hp)
     Bcc = Bc.reshape(B_, c, Q, N)
@@ -152,17 +204,18 @@ def _ssd_chunked(xdt, Bc, Cc, dA, Q: int, policy: NumericsPolicy):
         h = h * seg[:, t][:, :, None, None] + Sc[:, t]
         return h, y
 
-    h0 = jnp.zeros((B_, nh, hp, N), jnp.float32)
-    _, hs = jax.lax.scan(step, h0, jnp.arange(c))
+    if h0 is None:
+        h0 = jnp.zeros((B_, nh, hp, N), jnp.float32)
+    h, hs = jax.lax.scan(step, h0.astype(jnp.float32), jnp.arange(c))
     hs = jnp.moveaxis(hs, 0, 1)                         # (B,c,nh,hp,N) entering
     y_inter = policy.einsum("bcln,bchpn->bclhp", Ccc, hs, site="ssm")
     y_inter = y_inter * jnp.exp(cum)[..., None]
-    return (y_intra + y_inter).reshape(B_, L, nh, hp)
+    return (y_intra + y_inter).reshape(B_, L, nh, hp), h
 
 
 def init_ssm_cache(cfg: ArchConfig, batch: int):
     s, d_in, nheads, conv_ch = _dims(cfg)
     return {
-        "ssm": jnp.zeros((batch, nheads, s.head_dim, s.d_state), jnp.float32),
+        "ssm": jnp.zeros((batch, s.d_state, d_in), jnp.float32),
         "conv": jnp.zeros((batch, s.conv_kernel - 1, conv_ch), jnp.float32),
     }

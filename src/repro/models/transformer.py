@@ -3,15 +3,21 @@
 Homogeneous stacks (dense, moe, ssm, and llama4-style interleave via
 scan_block=2 pairs) run under ``jax.lax.scan`` over stacked layer params
 (with optional remat) — one compiled block regardless of depth.
-Heterogeneous stacks (zamba2 hybrid with a weight-shared attention block
-every k layers) unroll: the arch is small, and unrolling keeps the shared
-block's 6 distinct KV caches exact.
+A stack with ``cfg.layer_types`` (granite-4.0-h: Mamba-2 and attention
+layers in a published order, an MLP in every layer) keeps its parameters
+stacked by kind and scans each run of consecutive same-kind layers.
+zamba2's hybrid (a weight-shared attention block every k layers)
+unrolls: the arch is small, and unrolling keeps the shared block's 6
+distinct KV caches exact.
 
 All matmuls (projections, attention score/value, MoE experts, SSD
 einsums, LM head) route through the NumericsPolicy — the paper's
 technique as a first-class framework feature.
 """
 from __future__ import annotations
+
+import collections
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +56,8 @@ def _init_dense_layer(key, cfg: ArchConfig, use_moe: bool):
 
 def _dense_block(p, x, cfg, policy, cache, window):
     a, cache = attention(p["attn"], rmsnorm(p["n1"], x, cfg.norm_eps), cfg,
-                         policy, cache=cache, window=window)
+                         policy, cache=cache, window=window,
+                         use_rope=cfg.use_rope)
     x = x + a
     h = rmsnorm(p["n2"], x, cfg.norm_eps)
     if "moe" in p:
@@ -66,6 +73,87 @@ def _ssm_block(p, x, cfg, policy, cache):
     return x + y, cache
 
 
+def _init_typed_layer(key, cfg: ArchConfig, kind: str):
+    """One layer of a ``layer_types`` stack: a Mamba-2 or attention mixer
+    and a dense MLP, each behind its RMSNorm."""
+    ks = jax.random.split(key, 2)
+    mixer = ({"mixer": init_mamba2(ks[0], cfg)} if kind == "mamba"
+             else {"attn": init_attention(ks[0], cfg)})
+    return dict(mixer, n1=init_rmsnorm(cfg.d_model),
+                n2=init_rmsnorm(cfg.d_model),
+                ffn=init_ffn(ks[1], cfg.d_model, cfg.d_ff, cfg.act))
+
+
+def _runs(cfg: ArchConfig):
+    """The stack's layers as runs of one kind: (kind, first index among
+    that kind's layers, length), in layer order."""
+    runs, seen = [], collections.Counter()
+    for kind, group in itertools.groupby(cfg.kinds):
+        n = len(list(group))
+        runs.append((kind, seen[kind], n))
+        seen[kind] += n
+    return runs
+
+
+def _typed_stack(params, x, cfg, policy, caches, window, true_len):
+    """A ``layer_types`` stack: layer i is ``x + mixer(n1(x))`` with the
+    mixer of kind ``cfg.kinds[i]``, then ``x + mlp(n2(x))``.  Parameters
+    are stacked by kind; each run of consecutive layers of one kind runs
+    under one ``lax.scan``.
+
+    ``caches`` is None or ``{"mamba": ..., "attention": ...}``: the Mamba
+    layers' state stacked over them (``models/ssm.mamba2``'s stacked form;
+    it rides in the scan's carry with each layer's index, so a layer's
+    state is updated where it lies) and the attention layers' KV caches
+    stacked over them (sliced per layer like a dense stack's)."""
+    mc = None if caches is None else caches.get("mamba")
+    ac = None if caches is None else caches.get("attention")
+    new_ac = []
+    # A layer's weights are read in place by its index in its kind's
+    # stack: a run's slice of the stack would copy every weight of the
+    # run on every call.
+    layer = lambda kind, i: jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+        params["layers"][kind])
+
+    def mamba_layer(carry, li):
+        x, mc = carry
+        lp = layer("mamba", li)
+        c = None if mc is None else dict(mc, layer=li)
+        y, c = mamba2(lp["mixer"], rmsnorm(lp["n1"], x, cfg.norm_eps), cfg,
+                      policy, cache=c, true_len=true_len)
+        x = x + y
+        x = x + ffn(lp["ffn"], rmsnorm(lp["n2"], x, cfg.norm_eps), policy,
+                    cfg.act)
+        return (x, None if c is None else
+                {k: v for k, v in c.items() if k != "layer"}), None
+
+    def attention_layer(x, xs):
+        li, c = xs
+        x, c, _ = _dense_block(layer("attention", li), x, cfg, policy, c,
+                               window)
+        return x, c
+
+    for kind, first, n in _runs(cfg):
+        index = first + jnp.arange(n, dtype=jnp.int32)
+        if kind == "mamba":
+            (x, mc), _ = jax.lax.scan(mamba_layer, (x, mc), index)
+        else:
+            c = None if ac is None else jax.tree.map(
+                lambda a: a[first:first + n], ac)
+            x, c = jax.lax.scan(attention_layer, x, (index, c))
+            new_ac.append(c)
+    if caches is None:
+        return x, None
+    new = {}
+    if mc is not None:
+        new["mamba"] = mc
+    if ac is not None:
+        new["attention"] = (new_ac[0] if len(new_ac) == 1 else jax.tree.map(
+            lambda *a: jnp.concatenate(a), *new_ac))
+    return x, new
+
+
 # ---------------------------------------------------------------- init
 def init_lm(key, cfg: ArchConfig):
     ks = jax.random.split(key, 8)
@@ -76,7 +164,14 @@ def init_lm(key, cfg: ArchConfig):
 
     fam = cfg.family
     lkeys = jax.random.split(ks[2], cfg.n_layers)
-    if fam == "dense":
+    if cfg.layer_types:
+        kinds = cfg.kinds
+        params["layers"] = {
+            kind: jax.vmap(lambda k: _init_typed_layer(k, cfg, kind))(
+                lkeys[jnp.asarray([i for i, t in enumerate(kinds)
+                                   if t == kind])])
+            for kind in sorted(set(kinds))}
+    elif fam == "dense":
         params["layers"] = jax.vmap(
             lambda k: _init_dense_layer(k, cfg, False))(lkeys)
     elif fam == "moe":
@@ -111,7 +206,7 @@ def init_lm(key, cfg: ArchConfig):
 # ---------------------------------------------------------------- forward
 def lm_forward(params, tokens, cfg: ArchConfig, policy: NumericsPolicy, *,
                embeds=None, caches=None, window: int | None = None,
-               train: bool = False, logits_at=None):
+               train: bool = False, logits_at=None, true_len=None):
     """tokens (B, S) [+ optional frontend embeds (B, F, d) prepended].
 
     Returns (logits (B, S_total, vocab), new_caches, aux_loss).  With
@@ -119,6 +214,15 @@ def lm_forward(params, tokens, cfg: ArchConfig, policy: NumericsPolicy, *,
     row ``logits_at[b]`` of each sequence is unembedded: logits are
     (B, 1, vocab).  The row is gathered after the final norm, so its
     bits are the full head's row at that position.
+
+    ``caches`` are ring caches (``init_lm_caches``) or, for the serving
+    engine, paged KV pools with the scheduler's control merged in and,
+    in a ``layer_types`` stack, the Mamba layers' slot state beside them
+    (``init_paged_lm_caches``).  ``true_len`` (B,) is each row's real
+    length in a right-padded block: the Mamba layers of a ``layer_types``
+    stack keep padding out of their state (``models/ssm.mamba2``); an
+    attention layer needs no such mark, since causality keeps padding
+    from every real query.
     """
     window = cfg.sliding_window if window is None else window
     if cfg.fsdp and cfg.unshard_weights:
@@ -141,7 +245,10 @@ def lm_forward(params, tokens, cfg: ArchConfig, policy: NumericsPolicy, *,
     fam = cfg.family
     aux_total = jnp.zeros((), jnp.float32)
 
-    if fam == "hybrid":
+    if cfg.layer_types:
+        x, new_caches = _typed_stack(params, x, cfg, policy, caches, window,
+                                     true_len)
+    elif fam == "hybrid":
         x, new_caches, aux_total = _hybrid_stack(
             params, x, cfg, policy, caches, window)
     elif not cfg.scan_layers:
@@ -252,6 +359,10 @@ def init_lm_caches(cfg: ArchConfig, batch: int, max_len: int):
     def stacked(make, n):
         return jax.tree.map(lambda *a: jnp.stack(a), *[make() for _ in range(n)])
 
+    if cfg.layer_types:
+        raise NotImplementedError(
+            f"{cfg.name}'s layer_types stack is served by the paged engine "
+            f"(init_paged_lm_caches)")
     if fam == "dense":
         return stacked(lambda: init_cache(cfg, batch, max_len), cfg.n_layers)
     if fam == "moe":
@@ -271,28 +382,56 @@ def init_lm_caches(cfg: ArchConfig, batch: int, max_len: int):
     raise ValueError(fam)
 
 
-def init_paged_lm_caches(cfg: ArchConfig, n_pages: int, page_size: int):
+def init_paged_lm_caches(cfg: ArchConfig, n_pages: int, page_size: int,
+                         capacity: int | None = None):
     """Persistent device state of the paged serving cache: one K and one
-    V page pool per layer, stacked over the layer dim so the scan in
-    ``lm_forward`` threads them exactly like ring caches.
+    V page pool per attention layer, stacked over those layers so the
+    scan in ``lm_forward`` threads them exactly like ring caches.
+
+    A ``layer_types`` stack keeps them under ``"attention"`` and, under
+    ``"mamba"``, each Mamba layer's recurrent state for each of the
+    ``capacity`` slots: ``ssm`` (n_mamba, capacity, d_state, nheads *
+    head_dim) and ``conv`` (n_mamba, capacity, conv_kernel - 1,
+    conv_ch), float32.  A slot's state is O(1) in its length, so it
+    needs no pages: the admission prefill writes its slot's rows, each
+    decode tick updates every slot's in place, and a dead slot's rows
+    are garbage until the next admission overwrites them.
 
     Everything else the paged attention path consumes (page table,
     per-slot lengths, liveness) is host-authoritative control state the
     scheduler merges in per step (serve/scheduler.py), so it is NOT part
     of this tree.  Page 0 is the reserved trash page
-    (models/attention._paged_cache_update).  Paged serving covers the
-    families whose decode state is attention KV (dense, and moe with
-    interleave=1); SSM/hybrid recurrent state is O(1) per slot and needs
-    no paging — unsupported here until a scheduler lane carries it.
+    (models/attention._paged_cache_update).  Paged serving covers dense
+    stacks, moe stacks with interleave=1 and ``layer_types`` stacks of
+    Mamba-2 and attention layers; zamba2's shared-block hybrid and pure
+    SSM stacks are not served here.
     """
+    dt = jnp.dtype(cfg.cache_dtype)
+    pools = lambda n: {
+        "pool_k": jnp.zeros((n, n_pages, page_size, cfg.n_kv_heads,
+                             cfg.head_dim), dt),
+        "pool_v": jnp.zeros((n, n_pages, page_size, cfg.n_kv_heads,
+                             cfg.head_dim), dt)}
+    if cfg.layer_types:
+        if capacity is None:
+            raise ValueError(f"{cfg.name} keeps per-slot recurrent state: "
+                             f"give the slot capacity")
+        out = {}
+        n_a = cfg.kinds.count("attention")
+        if n_a:
+            out["attention"] = pools(n_a)
+        n_m = cfg.kinds.count("mamba")
+        if n_m:
+            out["mamba"] = jax.tree.map(
+                lambda a: jnp.zeros((n_m,) + a.shape, a.dtype),
+                init_ssm_cache(cfg, capacity))
+        return out
     fam = cfg.family
     if not (fam == "dense" or (fam == "moe" and cfg.moe.interleave == 1)):
         raise NotImplementedError(
-            f"paged serving caches support dense/moe(interleave=1) "
-            f"stacks; {cfg.name} is family {fam!r}")
-    dt = jnp.dtype(cfg.cache_dtype)
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-    return {"pool_k": jnp.zeros(shape, dt), "pool_v": jnp.zeros(shape, dt)}
+            f"paged serving caches support dense, moe(interleave=1) and "
+            f"layer_types stacks; {cfg.name} is family {fam!r}")
+    return pools(cfg.n_layers)
 
 
 # ---------------------------------------------------------------- loss

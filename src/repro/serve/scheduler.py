@@ -46,7 +46,15 @@ Observation (docs/serving.md, "Observing the engine")
     decode call splits into ``.upload``, ``.launch`` and ``.wait``.
     ``counters`` counts admissions, preemptions, quarantines, prefill
     tokens true and padded, prefills per bucket, decode ticks and rows,
-    traces and busy seconds per tier.
+    traces and busy seconds per tier; with Mamba layers, admissions that
+    wrote a slot's recurrent state and slots whose state a tick updated.
+
+Slot state (``layer_types`` stacks with Mamba-2 layers)
+    Beside the KV pages each lane holds every slot's recurrent and conv
+    state (``models/transformer.init_paged_lm_caches``).  The admission
+    prefill writes its slot's rows, every decode tick updates every
+    slot's rows in place on the donated caches, and a preemption needs
+    no snapshot: the re-admission recomputes the state from the prompt.
 """
 from __future__ import annotations
 
@@ -79,19 +87,35 @@ def _bucket(n: int) -> int:
     return b
 
 
-def _merge_control(caches, ptab, live, start):
+def _merge_control(caches, ptab, live, start, slot=None):
     """Broadcast the per-slot control arrays over the layer dim and merge
     them into the pool tree, so lm_forward's layer scan slices a complete
-    paged cache dict per layer (models/attention._paged_cache_update)."""
+    paged cache dict per layer (models/attention._paged_cache_update).
+    In a ``layer_types`` stack the pools are under ``"attention"``, and an
+    admission prefill's ``slot`` (B,) goes to the Mamba layers' state
+    (models/ssm.mamba2)."""
+    if "pool_k" not in caches:
+        out = dict(caches)
+        if "attention" in caches:
+            out["attention"] = _merge_control(caches["attention"], ptab,
+                                              live, start)
+        if slot is not None and "mamba" in caches:
+            out["mamba"] = dict(caches["mamba"], slot=slot)
+        return out
     L = caches["pool_k"].shape[0]
     bc = lambda a: jnp.broadcast_to(a[None], (L,) + a.shape)
     return dict(caches, ptab=bc(ptab), live=bc(live), start=bc(start))
 
 
+_STATE = {"pool_k", "pool_v", "ssm", "conv"}
+
+
 def _strip_control(caches):
     """Keep only the persistent device state; control is host-authoritative
     and re-uploaded every step, never read back."""
-    return {"pool_k": caches["pool_k"], "pool_v": caches["pool_v"]}
+    return {k: _strip_control(v) if isinstance(v, dict) else v
+            for k, v in caches.items()
+            if isinstance(v, dict) or k in _STATE}
 
 
 def _count_traces(fn, counters, key: str):
@@ -107,9 +131,11 @@ def _count_traces(fn, counters, key: str):
 
 def make_paged_prefill(cfg: ArchConfig, policy: Numerics,
                        window: Optional[int] = None):
-    def paged_prefill(params, tokens, true_len, ptab, caches):
+    def paged_prefill(params, tokens, true_len, ptab, caches, slot=None):
         """tokens (B, P) right-padded, true_len (B,) traced, ptab
-        (B, n_ptab) -> (next_token (B, 1), ok (B,) bool, caches).
+        (B, n_ptab), and in a ``layer_types`` stack the slot (B,) traced
+        whose Mamba state the prefill writes -> (next_token (B, 1),
+        ok (B,) bool, caches).
         ``ok`` is the non-finite-logit sentinel: False marks a request
         whose next-token distribution is poisoned (argmax would be
         garbage) — the scheduler quarantines it instead of emitting.
@@ -119,15 +145,17 @@ def make_paged_prefill(cfg: ArchConfig, policy: Numerics,
         token comes from it), their K/V writes land in
         allocated-but-not-yet-valid positions or the trash page, and
         causal masking keeps real queries from seeing anything at or
-        past their own position.
+        past their own position.  A Mamba layer's state starts from zero
+        and takes no step at padding (its ``dt`` is 0 there).
         """
         B = tokens.shape[0]
         merged = _merge_control(caches, ptab,
                                 jnp.ones((B,), bool),
-                                jnp.zeros((B,), jnp.int32))
+                                jnp.zeros((B,), jnp.int32), slot)
         logits, merged, _ = lm_forward(params, tokens, cfg, policy,
                                        caches=merged, window=window,
-                                       logits_at=true_len - 1)
+                                       logits_at=true_len - 1,
+                                       true_len=true_len)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         ok = jnp.isfinite(logits[:, 0, :]).all(axis=-1)
         return nxt, ok, _strip_control(merged)
@@ -143,7 +171,9 @@ def make_paged_serve_step(cfg: ArchConfig, policy: Numerics,
         slot (fault quarantine, docs/robustness.md).
 
         Dead slots ride along at fixed shape: their writes are routed to
-        the trash page and their outputs discarded by the scheduler.
+        the trash page and their outputs discarded by the scheduler; a
+        Mamba layer updates every slot's state, a dead slot's garbage
+        until an admission overwrites it.
         """
         merged = _merge_control(caches, ptab, live, start)
         logits, merged, _ = lm_forward(params, tokens, cfg, policy,
@@ -268,11 +298,14 @@ class ContinuousBatchingEngine:
                 params, to_shardings(lm_param_pspecs(params, cfg, mesh),
                                      mesh))
         self.counters = collections.Counter()
+        # Mamba layers keep recurrent state per slot beside the KV pages.
+        self.slot_state = "mamba" in cfg.kinds
         self._lanes = {name: _Lane(self, name, pol)
                        for name, pol in tiers.items()}
         with self._ctx():
             for lane in self._lanes.values():
-                caches = init_paged_lm_caches(cfg, self.n_pages, page_size)
+                caches = init_paged_lm_caches(cfg, self.n_pages, page_size,
+                                              capacity)
                 if mesh is not None:
                     from repro.distributed.sharding import (cache_pspecs,
                                                             to_shardings)
@@ -537,9 +570,11 @@ class ContinuousBatchingEngine:
                     args = (jnp.asarray(toks),
                             jnp.asarray([m], dtype=jnp.int32),
                             jnp.asarray(ctrl.ptab[slot:slot + 1]))
+                    state = ((jnp.asarray([slot], dtype=jnp.int32),)
+                             if self.slot_state else ())
                 with obs.span("engine.prefill.launch"):
                     nxt, ok, lane.caches = lane.prefill(self.params, *args,
-                                                        lane.caches)
+                                                        lane.caches, *state)
                 with obs.span("engine.prefill.wait"):
                     nxt, ok = np.asarray(nxt), np.asarray(ok)
                 self.counters[f"busy_s.{lane.name}"] += \
@@ -548,6 +583,8 @@ class ContinuousBatchingEngine:
                                   "prefill.bucket_tokens": P,
                                   "prefill.head_rows": toks.shape[0],
                                   f"prefill.bucket.{P}": 1})
+            if state:
+                self.counters["prefill.state_writes"] += 1
             lane.slot_req[slot] = req
             if not bool(ok[0]):
                 self._release_slot(lane, slot)
@@ -584,6 +621,8 @@ class ContinuousBatchingEngine:
         self.counters.update({"decode.ticks": 1,
                               "decode.rows_run": self.capacity,
                               "decode.rows_live": live})
+        if self.slot_state:
+            self.counters["decode.state_rows"] += self.capacity
         with obs.span("engine.bookkeeping"):
             for slot in range(self.capacity):
                 if not ctrl.live[slot]:

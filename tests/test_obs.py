@@ -102,6 +102,29 @@ def test_busy_seconds_and_trace_counts_are_views_of_the_counters(setup):
                                 "idle": cbe.n_pages - 1}
 
 
+def test_hybrid_engine_counts_state_writes_and_rows():
+    """A Mamba-2 layer's engine under amsim (granite-4.0-h-micro's kind of
+    layer, tiny): one admission of a 20-token prompt (bucket 32, one SSD
+    chunk of 32) and its first tick write one slot's state and update
+    every slot's, through the state-update kernel."""
+    import dataclasses
+
+    cfg = reduced(get_arch("granite-4.0-h-micro"))
+    cfg = dataclasses.replace(cfg, layer_types=("mamba",), n_layers=1,
+                              ssm=dataclasses.replace(cfg.ssm, chunk=256))
+    params = init_lm(jax.random.PRNGKey(3), cfg)
+    cbe = ContinuousBatchingEngine(cfg, AFM16, params, max_len=32,
+                                   capacity=2, page_size=4)
+    cbe.submit(_prompts(cfg, (20,))[0], 4)
+    before = collections.Counter(obs.routes)
+    cbe.step()
+    c = cbe.counters
+    assert c["admissions"] == c["prefill.state_writes"] == 1
+    assert c["decode.ticks"] == 1 and c["decode.state_rows"] == cbe.capacity
+    new = obs.routes - before
+    assert new["ssm.state.kernel"] >= 1 and new["ssm.chunk.32"] >= 1
+
+
 # ------------------------------------------------------------- routes
 def test_moe_route_records_the_grouped_kernel():
     """granite-moe-3b-a800m's layer at its published widths (traced, not
@@ -300,6 +323,33 @@ def test_head_and_untagged_shares_read_the_kernel_tags():
         pytest.approx(10.0)
 
 
+def test_ssm_share_and_state_roofline_read_the_mamba_kernels():
+    """``ssm_share.serve`` reads the kernels tagged site "ssm";
+    ``ssm_state_roofline`` the state kernel's least time (its layer's
+    state read and written, and every other operand, over the HBM
+    bandwidth) over its device time."""
+    from bench.peaks import PEAKS
+    state = T.Op("%_ssm_state_impl.ssm.fwd.3 = (f32[9,64,128,4096]{3,2,1,0}, "
+                 "f32[64,1,4096]{2,1,0}) custom-call(s32[1]{0} %l, "
+                 "f32[9,64,128,4096]{3,2,1,0} %s, f32[64,1,4096]{2,1,0} %x, "
+                 "f32[64,1,4096]{2,1,0} %d, f32[64,1,128]{2,1,0} %b, "
+                 "f32[64,1,128]{2,1,0} %c, bf16[128,128]{1,0} %t), "
+                 'custom_call_target="tpu_custom_call", frontend_attributes='
+                 '{kernel_metadata={\n"pass":"fwd",\n"site":"ssm"\n}}',
+                 0, 1e6)
+    s = _summary([state,
+                  _kernel("_approx_gemm_impl.ssm.fwd.1", ("ssm", "fwd"), 0, 2e6),
+                  _kernel("_approx_gemm_impl.wg.fwd.2", ("wg", "fwd"), 0, 7e6)])
+    assert _read("ssm_share.serve", s) == pytest.approx(30.0)
+    elems = 64 * 128 * 4096
+    nbytes = (8 * elems + 4 + 2 * 64 * 4096 * 4 + 2 * 64 * 128 * 4
+              + 128 * 128 * 2 + 4 * 64 * 4096)
+    reader = harness.load_module(harness.BENCH / "metrics" / "ssm_state_roofline.py")
+    got = reader.read({"summary": s, "counts": {},
+                       "peaks": PEAKS["TPU v5 lite"]})
+    assert got == pytest.approx(100 * nbytes / 819e9 / 1e-3)
+
+
 def test_engine_launch_ms_and_completion_lag_share_read_the_spans():
     ms = 1e6
     host = [("engine.tick", 0, 100 * ms), ("engine.tick", 100 * ms, 100 * ms),
@@ -339,7 +389,8 @@ def test_prefill_pad_share_reads_the_engine_counts_on_its_spans(
 
 @pytest.mark.parametrize("metric", [
     "head_share.serve", "untagged_kernel_share.serve", "engine_launch_ms",
-    "completion_lag_share", "prefill_pad_share"])
+    "completion_lag_share", "prefill_pad_share", "ssm_share.serve",
+    "ssm_state_roofline"])
 def test_new_readers_read_nothing_from_a_program_without_them(metric):
     """Four decode ticks recorded on a TPU v5 lite before the program
     tagged its kernels or spanned its engine."""

@@ -15,6 +15,7 @@ Every launch carries its ``(site, pass)`` tag, in the custom call's
 the benchmark's trace reduction (bench/trace.py) knows the kernel by.
 """
 import collections
+import re
 import sys
 from pathlib import Path
 
@@ -263,13 +264,54 @@ def test_decode_tick_compiles(one_chip, compiled_kernels, arch):
     assert _kernels(text) == want
 
 
+def test_hybrid_decode_tick_updates_the_slot_state_in_place(one_chip,
+                                                           compiled_kernels):
+    """A 64-slot paged decode tick of granite-4.0-h-micro's first six
+    layers (five Mamba-2, one attention) at their published widths, with
+    the whole 100352-wide tied head: every Mamba layer's state goes through
+    the state-update kernel, which writes the donated stacked state in
+    place: the program aliases it to its output, and nothing in it but
+    the parameter, the kernels and tuple reads yields an array of its
+    shape (no copy, no fusion writing a second one)."""
+    import dataclasses
+
+    from repro.configs import get_arch
+    from repro.models.transformer import init_lm, init_paged_lm_caches
+    from repro.serve.scheduler import make_paged_serve_step
+    cfg = dataclasses.replace(get_arch("granite-4.0-h-micro"), n_layers=6)
+    slots, page, per_slot = 64, 16, 24
+    params = jax.eval_shape(lambda k: init_lm(k, cfg), jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: init_paged_lm_caches(
+        cfg, slots * per_slot + 1, page, slots))
+    shaped = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), t)
+    control = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+               (((slots, 1), i32), ((slots,), jnp.bool_), ((slots,), i32),
+                ((slots, per_slot), i32))]
+    compiled = jax.jit(make_paged_serve_step(cfg, AFM16),
+                       donate_argnums=(5,)).lower(
+        shaped(params), *control, shaped(caches)).compile()
+    text = compiled.as_text()
+    kernels = collections.Counter(_kernels(text))
+    assert kernels[("ssm_state_impl", ("ssm", "fwd"))] >= 1
+    assert kernels[("approx_gemm_impl", ("ssm", "fwd"))] >= 2   # in, out
+    assert kernels[("approx_gemm_impl", ("unembed", "fwd"))] >= 1
+    state = caches["mamba"]["ssm"]
+    assert state.shape == (5, slots, 128, 64 * 64)
+    assert compiled.memory_analysis().alias_size_in_bytes >= 4 * state.size
+    makers = re.findall(r"= f32\[5,64,128,4096\]\{[^}]*\} ([\w\-]+)\(", text)
+    assert makers and set(makers) <= {"parameter", "get-tuple-element",
+                                      "custom-call"}, collections.Counter(makers)
+
+
 @pytest.fixture
 def compiled_kernels(monkeypatch):
     """Kernels reached through the policy seam compile for the chip, not
     in interpret mode, while the host's backend is the CPU."""
     import repro.kernels.approx_attention as attention
     import repro.kernels.approx_gemm as gemm
-    for module in (gemm, attention):
+    import repro.kernels.ssm_state as ssm_state
+    for module in (gemm, attention, ssm_state):
         monkeypatch.setattr(module, "resolve_interpret",
                             lambda interpret: False)
 
